@@ -59,8 +59,8 @@ log = logging.getLogger("predictionio_tpu.als")
 # tuning is done against (arXiv:2112.02194)
 _M_TRAIN_STEP = METRICS.histogram(
     "pio_train_step_seconds",
-    "one ALS alternation (user+item half-steps); async dispatch means a "
-    "step observes the previous step's device time")
+    "one ALS alternation (user+item half-steps), timed to the end of "
+    "its device work; an attempt's first step includes the compile")
 # ISSUE 15: one vmapped grid alternation — EVERY trial's user+item
 # half-steps in a single compiled dispatch (workflow/tuning.py divides by
 # the trial count for a per-trial figure)
@@ -753,18 +753,8 @@ def put_layout(layout, mesh, *, vals_dtype=None):
     (reference examples/.../ALSModel.scala:172-179); the caller feeds each
     process the same (deterministically rebuilt) layout."""
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    # block rows shard over EVERY mesh axis, not just "data": the gramian
-    # phase consumes replicated opposite factors, so its work parallelizes
-    # over all devices regardless of how the factor MATRICES are sharded.
-    # With only "data" here, a (4,2) data x model mesh would compute every
-    # block twice (the model pair replicates the gather+einsum — measured
-    # 2x slower than 8x1 on the gather-dominated step, BENCH_r03); the
-    # model axis must carry block work too.
-    row_axes = tuple(a for a in ("data", "model") if a in mesh.axis_names)
-    blk = NamedSharding(mesh, P(None, row_axes or None, None))
-    rep = NamedSharding(mesh, P())
+    blk, rep = _layout_shardings(mesh)
     multi = jax.process_count() > 1
 
     def put(arr, sharding):
@@ -787,6 +777,23 @@ def put_layout(layout, mesh, *, vals_dtype=None):
             e["seg"] = put(m.seg, rep)
         out.append(e)
     return out
+
+
+def _layout_shardings(mesh):
+    """(sharding of a bucket's ids/vals blocks, sharding of its seg ids)
+    — the one place that says how a layout lies on a mesh."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    # block rows shard over EVERY mesh axis, not just "data": the gramian
+    # phase consumes replicated opposite factors, so its work parallelizes
+    # over all devices regardless of how the factor MATRICES are sharded.
+    # With only "data" here, a (4,2) data x model mesh would compute every
+    # block twice (the model pair replicates the gather+einsum — seen as
+    # 2x slower than 8x1 on a forced CPU mesh; not measured on the chip);
+    # the model axis must carry block work too.
+    row_axes = tuple(a for a in ("data", "model") if a in mesh.axis_names)
+    return (NamedSharding(mesh, P(None, row_axes or None, None)),
+            NamedSharding(mesh, P()))
 
 
 def _process_local_slice(arr, sharding):
@@ -967,7 +974,8 @@ def make_train_step(mesh, u_layout, i_layout, *, rank, lambda_=0.1,
                     cg_iters: int | None = None):
     """One full ALS iteration (user half-step + item half-step) over the
     permuted two-sided layout as a single jitted function — the program
-    the multi-chip dry-run compiles, and the inner loop of ``train_als``.
+    ``tests/test_tpu_compile.py`` compiles for v5e, and the inner loop of
+    ``train_als``.
     ``step(u_buckets, i_buckets, u_perm, v_perm) -> (u_perm, v_perm)``
     operates entirely in permuted slot space ([slots_u, R] / [slots_i, R]);
     the incoming factors seed the CG warm start (both are donated — each
@@ -997,9 +1005,10 @@ def make_train_step(mesh, u_layout, i_layout, *, rank, lambda_=0.1,
         # traffic). Without the explicit constraint GSPMD lowers every
         # per-tier row gather from the model-sharded operand as
         # mask+all-reduce over the GATHERED block — traffic proportional
-        # to nnz_padded, per tier, inside lax.map (measured: the 4x2
-        # data x model mesh ran SLOWER than 8x1 data-only, BENCH_r03;
-        # verified by the HLO collective-inventory test in test_als.py).
+        # to nnz_padded, per tier, inside lax.map (on a forced CPU mesh
+        # the 4x2 data x model mesh ran SLOWER than 8x1 data-only; not
+        # measured on the chip; the HLO collective-inventory test in
+        # test_als.py pins the lowering).
         v_full = jax.lax.with_sharding_constraint(v, rep) if model_sharded else v
         u = _solve_side(u_buckets, u_layout, v_full, kw=kw,
                         x0=u_prev if warm else None)
@@ -1093,6 +1102,8 @@ def train_als(ratings: Ratings, config: ALSConfig, mesh=None, *,
                     "axis; training with replicated factors", dict(mesh.shape))
         model_sharded = False
 
+    TRAINING.begin("train", total_iterations=config.iterations)
+    t_layout = time.perf_counter()
     u_lay, i_lay = build_bilinear_layout(
         ratings.user_indices, ratings.item_indices, ratings.ratings, nu, ni,
         tiers=config.tiers, gather_budget=config.gather_budget,
@@ -1110,8 +1121,21 @@ def train_als(ratings: Ratings, config: ALSConfig, mesh=None, *,
     fac = NamedSharding(mesh, P("model" if model_sharded else None, None))
 
     vals_dtype = "bfloat16" if config.compute_dtype == "bfloat16" else None
+    t_upload = time.perf_counter()
     u_bk = put_layout(u_lay, mesh, vals_dtype=vals_dtype)
     i_bk = put_layout(i_lay, mesh, vals_dtype=vals_dtype)
+    jax.block_until_ready((u_bk, i_bk))
+    # what each device holds once the layout is up: a layout that landed
+    # whole on device 0 shows here, not in a slow step later (CPU
+    # devices report no memory statistics)
+    in_use = [(d.memory_stats() or {}).get("bytes_in_use")
+              for d in mesh.local_devices]
+    TRAINING.note("train", layoutSeconds=t_upload - t_layout,
+                  uploadSeconds=time.perf_counter() - t_upload,
+                  deviceBytesInUse=in_use)
+    if len(in_use) > 1:
+        log.info("bytes in use per device after the layout upload: %s",
+                 in_use)
 
     def _to_slots(host_arr, lay):
         """True-row-order host array -> permuted device layout (non-owner
@@ -1218,13 +1242,15 @@ def train_als(ratings: Ratings, config: ALSConfig, mesh=None, *,
     u = None
     carry_u = u_restored if u_restored is not None else u_seed
     conv = _ConvergenceSampler(ratings, config, u_lay, i_lay)
-    TRAINING.begin("train", total_iterations=config.iterations)
     for it in range(start_it, config.iterations):
         # chaos site: a preemption striking mid-training (arm with
         # after=N to let N iterations — and their checkpoints — land)
         FAULTS.fire("train.step")
         t_step = time.perf_counter()
-        u, v = step(u_bk, i_bk, carry_u, v)
+        # timed to the end of the device work (dispatch returns before
+        # it); the sampler below pulls from u and v anyway, so waiting
+        # here delays nothing
+        u, v = jax.block_until_ready(step(u_bk, i_bk, carry_u, v))
         step_s = time.perf_counter() - t_step
         _M_TRAIN_STEP.record(step_s)
         conv.observe(it, u, v, step_s)

@@ -186,9 +186,8 @@ class SeqRecModel:
         """Per-user next-item top-N, one forward pass per <=BATCH_CHUNK
         queries ([B, L] histories stacked, batch padded to a power of two
         so traffic-dependent sizes reuse a handful of compiled shapes;
-        only the last position's [B, vocab] logits leave the device). On
-        remote-dispatch platforms each per-query forward is a full
-        dispatch round trip — this is the serving path the micro-batcher
+        only the last position's [B, vocab] logits leave the device).
+        This is the serving path the micro-batcher
         feeds, and the single home of the seen-mask/top-k dance
         (``recommend_products`` delegates here). Unknown users get []."""
         out: list = [[] for _ in users]
@@ -269,10 +268,9 @@ def train_seq_rec(
 
     # One device dispatch per EPOCH: shuffled batches stage as
     # [n_batches, bs, L] and a jitted lax.scan chains the train steps
-    # on-device with donated state — a per-step host loop pays the
-    # platform's per-call dispatch round trip every step (the two-tower
-    # trainer measured 56.6 ms/step host-loop vs 4.1 ms device-side,
-    # docs/PERF_NOTES.md).
+    # on-device with donated state, so the host pays one dispatch per
+    # epoch instead of one per step (the difference is not measured on
+    # the chip).
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def epoch_scan(p, state, batches):
         def body(carry, batch):

@@ -96,14 +96,12 @@ class TwoTowerModel(RetrievalServingMixin):
 
 @dataclasses.dataclass
 class TwoTowerTrainState:
-    """The data-parallel training unit shared by ``train_two_tower`` and
-    the bench's timed loop — one home so the timed program IS the
-    training program. ``epoch_scan(params, opt_state, u_batches,
-    i_batches) -> (params, opt_state, last_loss)`` chains the train steps
-    of one staged [n_batches, bs] epoch on-device in a single dispatch
-    (a per-step host loop pays the platform's per-call dispatch round
-    trip every step — measured 56.6 ms/step host-loop vs 4.1 ms/step
-    device-side at batch 8192 on v5e, docs/PERF_NOTES.md)."""
+    """The data-parallel training unit of ``train_two_tower``.
+    ``epoch_scan(params, opt_state, u_batches, i_batches) -> (params,
+    opt_state, last_loss)`` chains the train steps of one staged
+    [n_batches, bs] epoch on-device in a single dispatch, so the host
+    pays one dispatch per epoch instead of one per step (the
+    difference is not measured on the chip)."""
 
     towers: tuple  # (user_tower, item_tower)
     params: Any

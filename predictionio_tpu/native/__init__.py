@@ -1,14 +1,19 @@
 """ctypes bindings for the native host-runtime library (pio_native.cpp).
 
 The shared library is compiled on demand with g++ into ``_build/`` next to
-the source and cached by source mtime. Every entry point has a pure-numpy
-fallback at its call site — ``available()`` is False when no compiler is
-present or the build fails, and the framework keeps working.
+the source, under a file name that carries a hash of the source: a copy
+of the tree need not keep mtimes, and a library built from another
+source can then never be the one loaded. Every entry point has a
+pure-numpy fallback at its call site — ``available()`` is False when no
+compiler is present or the build fails, and the framework keeps working
+(`pio train` stamps which of the two it ran with into the instance's
+``backend_conf``).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -45,7 +50,6 @@ logger = logging.getLogger(__name__)
 
 _SRC = Path(__file__).resolve().parent / "pio_native.cpp"
 _BUILD_DIR = _SRC.parent / "_build"
-_LIB_PATH = _BUILD_DIR / "libpio_native.so"
 
 NFIELDS = 11
 JSONL_FIELDS = (
@@ -58,11 +62,17 @@ _lib: ctypes.CDLL | None = None
 _tried = False
 
 
-def _build() -> bool:
+def lib_path() -> Path:
+    """``_build/libpio_native-<hash of pio_native.cpp>.so``."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _BUILD_DIR / f"libpio_native-{digest}.so"
+
+
+def _build(target: Path) -> bool:
     # compile to a per-process temp path and rename into place: concurrent
     # importers (multi-host loaders, pytest-xdist) must never observe a
     # half-written .so, and os.replace is atomic on POSIX
-    tmp = _LIB_PATH.with_suffix(f".so.tmp.{os.getpid()}")
+    tmp = target.with_suffix(f".so.tmp.{os.getpid()}")
     cmd = [
         os.environ.get("CXX", "g++"), "-O3", "-std=c++17", "-fPIC", "-shared",
         "-pthread", str(_SRC), "-o", str(tmp),
@@ -70,11 +80,15 @@ def _build() -> bool:
     try:
         _BUILD_DIR.mkdir(exist_ok=True)
         subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-        os.replace(tmp, _LIB_PATH)
+        os.replace(tmp, target)
     except (OSError, subprocess.SubprocessError) as e:
         logger.warning("pio_native build failed, using numpy fallbacks: %s", e)
         tmp.unlink(missing_ok=True)
         return False
+    # libraries of other sources are never loaded again: drop them
+    for old in _BUILD_DIR.glob("libpio_native*.so"):
+        if old != target:
+            old.unlink(missing_ok=True)
     return True
 
 
@@ -87,28 +101,22 @@ def _load() -> ctypes.CDLL | None:
         if os.environ.get("PIO_NO_NATIVE"):
             return None
         try:
-            src_exists = _SRC.exists()
-            stale = src_exists and (
-                not _LIB_PATH.exists()
-                or _LIB_PATH.stat().st_mtime < _SRC.stat().st_mtime
-            )
-        except OSError:
-            src_exists, stale = False, False
-        if stale and not _build():
-            # never load a library older than its source — a stale binary
-            # could silently diverge from the numpy fallbacks
+            path = lib_path()
+        except OSError:  # no source, so no name to look a library up by
             return None
-        if not src_exists and not _LIB_PATH.exists():
+        # never load a library of another source — a stale binary could
+        # silently diverge from the numpy fallbacks
+        if not path.exists() and not _build(path):
             return None
         try:
-            lib = ctypes.CDLL(str(_LIB_PATH))
+            lib = ctypes.CDLL(str(path))
         except OSError:
-            # the cached lib may be corrupt (e.g. a pre-atomic-rename
-            # partial write); one rebuild attempt before giving up
-            if not _build():
+            # the cached lib may be corrupt (a partial copy, or built for
+            # another machine); one rebuild attempt before giving up
+            if not _build(path):
                 return None
             try:
-                lib = ctypes.CDLL(str(_LIB_PATH))
+                lib = ctypes.CDLL(str(path))
             except OSError as e:
                 logger.warning("pio_native load failed: %s", e)
                 return None

@@ -14,8 +14,8 @@ Design:
 
 - **Hot path is a deque append.** ``record()`` samples, builds one dict
   and appends it to a bounded ring under a lock — no serialization, no
-  I/O. The bench gate (bench.py capture_overhead_bench) pins this:
-  capture on (sample 1.0) must stay within 5% of capture off.
+  I/O. What capture costs a served request is not measured on the
+  chip.
 - **Persistence reuses the WAL.** The ring flushes to an
   ``EventJournal`` (storage/journal.py) — the same CRC-framed segment
   format, torn-tail repair and rotation discipline the ingestion WAL

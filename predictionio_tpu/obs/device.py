@@ -72,6 +72,19 @@ _M_ANALYSIS_UNAVAILABLE = METRICS.counter(
     "or incompatible executable shape) — flagged, never fatal")
 
 
+def device_identity() -> dict:
+    """The device this process holds, as JAX reports it — the three
+    fields every result must carry so a host run is never read as a
+    chip run (`pio train` stamps them into ``backend_conf``,
+    ``/stats.json`` shows them in its ``device`` block)."""
+    import jax
+
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
+
+
 @dataclasses.dataclass
 class LedgerEntry:
     """One executable's accounting record. ``bytes`` fields come from

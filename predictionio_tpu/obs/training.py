@@ -94,6 +94,18 @@ class ConvergenceTracker:
         except Exception:
             pass
 
+    def note(self, source: str, **facts) -> None:
+        """Attach set-up facts of the live attempt (seconds spent before
+        the first iteration, bytes in use per device after upload); they
+        ride its summary. Dropped when no attempt is open."""
+        try:
+            with self._lock:
+                live = self._live.get(source)
+                if live is not None:
+                    live.setdefault("setup", {}).update(facts)
+        except Exception:
+            pass
+
     def finish(self, source: str, status: str = "COMPLETED") -> None:
         """Close the live attempt into the per-source summary list."""
         try:
@@ -150,7 +162,9 @@ def _summarize(live: dict, status: str) -> dict:
     losses = [r["loss"] for r in hist if "loss" in r]
     steps = [r["stepSeconds"] for r in hist if "stepSeconds" in r]
     deltas = [r["deltaNorm"] for r in hist if "deltaNorm" in r]
+    later = sorted(steps[1:])
     return {
+        **live.get("setup", {}),
         "status": status,
         "iterations": live["iterations"],
         "totalIterations": live["totalIterations"],
@@ -158,6 +172,10 @@ def _summarize(live: dict, status: str) -> dict:
         "firstLoss": losses[0] if losses else None,
         "finalDeltaNorm": deltas[-1] if deltas else None,
         "meanStepSeconds": (sum(steps) / len(steps)) if steps else None,
+        # the first step of an attempt traces and compiles (or reads the
+        # compilation cache) before it runs; the rest are steady state
+        "firstStepSeconds": steps[0] if steps else None,
+        "laterStepSeconds": later[len(later) // 2] if later else None,
     }
 
 

@@ -59,7 +59,8 @@ from ..obs.metrics import METRICS
 from ..obs.waterfall import mark_stage
 from ..workflow.faults import FAULTS
 from .retrieval import (EXEC_CACHE, PACKED_IDX_LIMIT, _RETRIEVER_TOKENS,
-                        _dispatch_topk, _query_shapes, DeviceRetriever)
+                        _dispatch_topk, _query_shapes, _resolve_topk_mode,
+                        DeviceRetriever)
 
 __all__ = ["AnnIndex", "AnnRetriever", "build_index", "pick_cells",
            "effective_nprobe", "kmeans_centroids", "DEFAULT_NPROBE",
@@ -507,6 +508,10 @@ class AnnRetriever:
         ix = self.index
         return {
             "mode": "exact_fallback" if ix is None else "ann",
+            # the IVF scan is a plain XLA program on every backend; the
+            # exact fallback scores like any DeviceRetriever
+            "kernel": ("xla" if ix is not None
+                       else _resolve_topk_mode(self._interpret)),
             "exactFallback": ix is None,
             "fallbackReason": self.fallback_reason,
             "nTotal": self.n_total,

@@ -1,13 +1,12 @@
 """Device-resident serving pipeline (ISSUE 16).
 
-PR 11's stage waterfalls showed where a served request's time goes: of
-the p50 66.6 ms batched request only ~1.3 ms was device compute — the
-rest was Python host work around ``_dispatch_topk``: per-user ``dict``
-lookups, a numpy gather of the query factor rows, fresh padding
-allocations, and a host->device upload of the padded query matrix on
-EVERY batch. This module removes that floor by making the query side of
+The legacy path does Python host work around ``_dispatch_topk`` on EVERY
+batch: per-user ``dict`` lookups, a numpy gather of the query factor
+rows, fresh padding allocations, and a host->device upload of the padded
+query matrix. This module removes that work by making the query side of
 serving device-resident, the way the item side already is
-(``DeviceRetriever``):
+(``DeviceRetriever``). What share of a request it was is not measured
+on the chip.
 
 * **Device-resident query table** — the model's user-factor matrix is
   uploaded ONCE into a capacity-padded ``[cap, D_pad]`` device buffer.
@@ -113,6 +112,21 @@ def _capacity(n_rows: int) -> int:
     return ((need + 255) // 256) * 256
 
 
+def _fused_fn(raw, packed: bool):
+    """rows -> gather -> ``raw`` (score + top-k) -> packed ``[b_pad, 2k]``
+    result: the function the fused executable is compiled from, apart so
+    that tests/test_tpu_compile.py compiles the same text for v5e."""
+    import jax.numpy as jnp
+
+    def fn(rows, qtab, items):
+        vals, idx = raw(qtab[rows], items)
+        if not packed:
+            return vals, idx
+        return jnp.concatenate([vals, idx.astype(jnp.float32)], axis=1)
+
+    return fn
+
+
 class _SharedState:
     """Mutable pipeline state shared across copy-on-write ``refresh``
     clones: the staging pools, the overlap/dispatch counters, and the
@@ -194,14 +208,7 @@ class ServingPipeline:
                                 n_total, k_pad, r._tile_n,
                                 r._mode == "interpret")
             packed = n_total < PACKED_IDX_LIMIT
-
-            def fn(rows, qtab, items):
-                vals, idx = raw(qtab[rows], items)
-                if not packed:
-                    return vals, idx
-                return jnp.concatenate(
-                    [vals, idx.astype(jnp.float32)], axis=1)
-
+            fn = _fused_fn(raw, packed)
             jitted = (jax.jit(fn, donate_argnums=(0,)) if self._donate
                       else jax.jit(fn))
             compiled = jitted.lower(

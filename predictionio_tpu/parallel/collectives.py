@@ -19,7 +19,6 @@ from __future__ import annotations
 from typing import Any, Callable, Sequence
 
 __all__ = [
-    "get_shard_map",
     "allreduce_sum",
     "allreduce_mean",
     "allreduce_max",
@@ -31,31 +30,6 @@ __all__ = [
     "axis_index",
     "sharded",
 ]
-
-
-def get_shard_map():
-    """shard_map across JAX versions: moved out of experimental in 0.8,
-    which also renamed check_rep -> check_vma. Returns a callable with the
-    old (check_rep) keyword signature."""
-    import inspect
-
-    import jax
-
-    raw = jax.shard_map if hasattr(jax, "shard_map") else None
-    if raw is None:
-        from jax.experimental.shard_map import shard_map as raw
-
-    params = inspect.signature(raw).parameters
-
-    def shim(fn, *, mesh, in_specs, out_specs, check_rep: bool = False):
-        kw = {}
-        if "check_rep" in params:
-            kw["check_rep"] = check_rep
-        elif "check_vma" in params:
-            kw["check_vma"] = check_rep
-        return raw(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-    return shim
 
 
 def _has_axis(axis_name: str) -> bool:
@@ -163,12 +137,14 @@ def sharded(
     in_specs: Sequence[Any],
     out_specs: Any,
     *,
-    check_rep: bool = False,
+    check_vma: bool = False,
 ):
     """shard_map wrapper: run ``fn`` SPMD over ``mesh`` with explicit
     per-argument PartitionSpecs. The per-device view inside ``fn`` sees
     local shards and may call the collectives above by axis name."""
-    return get_shard_map()(
+    import jax
+
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs,
-        check_rep=check_rep,
+        check_vma=check_vma,
     )

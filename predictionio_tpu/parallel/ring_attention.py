@@ -54,9 +54,8 @@ def _block_attn_bhld(qt, k_blk, v_blk, scale, mask, mm_dtype):
     """One [Lq, Lk] score block in [B, H, L, D] layout -> (scores_max,
     exp-weights @ v, exp-sum): m [B, H, Lq], pv [B, H, Lq, D] f32,
     l [B, H, Lq] f32. Matmuls stay in ``mm_dtype`` with f32 accumulation
-    (``preferred_element_type``); the softmax pieces are f32 — the tuned
-    formulation shared with ``blockwise_attention`` (measured 8x the old
-    [B, L, H, D] f32 einsums on v5e)."""
+    (``preferred_element_type``); the softmax pieces are f32 — the
+    formulation shared with ``blockwise_attention``."""
     import jax.numpy as jnp
 
     f32 = jnp.float32
@@ -136,9 +135,8 @@ def blockwise_attention(q, k, v, *, causal: bool = False, block_size: int = 1024
     pure batched matmuls with no relayout inside the loop, matmuls stay in
     the input dtype with f32 accumulation (``preferred_element_type``),
     and the softmax carries (max / denominator / accumulator) are f32.
-    Measured on v5e at B4 L4096 H8 D64 causal bf16: 24 ms vs 164 ms for
-    the previous [B, L, H, D] f32 formulation — within ~30% of the stock
-    Pallas flash kernel (18 ms), which ``flash_attention`` prefers."""
+    Against the stock Pallas flash kernel, which ``flash_attention``
+    prefers where its shape test admits: not measured on the chip."""
     import jax
     import jax.numpy as jnp
 
@@ -189,26 +187,26 @@ def flash_attention(q, k, v, *, causal: bool = False, block_size: int = 1024):
     Pallas TPU flash kernel (jax.experimental.pallas.ops.tpu) when on TPU
     and the shape fits its tiling, else ``blockwise_attention``. The
     Pallas kernel fuses the whole softmax-accumulate into one Mosaic
-    program (measured 18 ms vs 24 ms blockwise at B4 L4096 H8 D64 causal
-    on v5e); NOTE its ``sm_scale`` defaults to 1.0, so the 1/sqrt(D)
-    scale must be passed explicitly."""
+    program (against blockwise: not measured on the chip); NOTE its
+    ``sm_scale`` defaults to 1.0, so the 1/sqrt(D) scale must be passed
+    explicitly."""
     import jax
 
     B, L, H, D = q.shape
     if jax.default_backend() == "tpu" and L % 128 == 0 and D in (64, 128):
-        try:
-            import jax.numpy as jnp
-            from jax.experimental.pallas.ops.tpu.flash_attention import (
-                flash_attention as _pallas_flash)
+        # the shape test above decides which kernel runs; a compile or
+        # run-time error of the chosen kernel is an error, not a reason
+        # to change kernels behind the caller's back
+        import jax.numpy as jnp
+        from jax.experimental.pallas.ops.tpu.flash_attention import (
+            flash_attention as _pallas_flash)
 
-            qt = jnp.transpose(q, (0, 2, 1, 3))
-            kt = jnp.transpose(k, (0, 2, 1, 3))
-            vt = jnp.transpose(v, (0, 2, 1, 3))
-            out = _pallas_flash(qt, kt, vt, causal=causal,
-                                sm_scale=1.0 / (D**0.5))
-            return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
-        except Exception:  # pragma: no cover - kernel/tiling mismatch
-            pass
+        qt = jnp.transpose(q, (0, 2, 1, 3))
+        kt = jnp.transpose(k, (0, 2, 1, 3))
+        vt = jnp.transpose(v, (0, 2, 1, 3))
+        out = _pallas_flash(qt, kt, vt, causal=causal,
+                            sm_scale=1.0 / (D**0.5))
+        return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
     return blockwise_attention(q, k, v, causal=causal, block_size=block_size)
 
 
@@ -221,19 +219,15 @@ def ring_self_attention(mesh, q, k, v, *, causal: bool = False,
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from .collectives import get_shard_map
-
-    shard_map = get_shard_map()
-
     if seq_axis not in mesh.shape or mesh.shape[seq_axis] == 1:
         # no sequence axis: the tuned single-device path (Pallas on TPU)
         return flash_attention(q, k, v, causal=causal)
     b_ax = batch_axis if (batch_axis and batch_axis in mesh.shape) else None
     spec = P(b_ax, seq_axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=seq_axis, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     # with_sharding_constraint works both eagerly and under jit traces,
     # so the same code path serves the deploy server and compiled train steps

@@ -83,7 +83,7 @@ _SEGMENT_RE = re.compile(r"^journal-(\d{8})\.log$")
 _STEP_RE = re.compile(r"^step_(\d+)$")
 
 # Home entries that are rebuildable scratch, not durable state.
-_EXCLUDE_TOP = ("backups", "xla_cache", "log", "quarantine")
+_EXCLUDE_TOP = ("backups", "log", "quarantine")
 # sqlite scratch siblings: the online backup API folds the WAL into the
 # snapshot, so copying these raw would only tear.
 _SQLITE_SCRATCH = ("-wal", "-shm", "-journal")

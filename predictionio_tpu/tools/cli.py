@@ -309,22 +309,33 @@ def cmd_unregister(args) -> int:
     return 0
 
 
-def _enable_compile_cache() -> None:
-    """Persistent XLA compilation cache under PIO_HOME: re-running train
-    (or deploy's retrain path) with the same shapes skips compilation —
-    the dominant setup cost of the end-to-end `pio train` wall clock
-    (BASELINE.md target 3). Safe to call before or after jax backend
-    init; shared with bench.py's cache by callers that set the same dir."""
-    try:
-        import jax
+def compile_cache_dir() -> str:
+    """Where XLA's persistent compilation cache lives:
+    ``JAX_COMPILATION_CACHE_DIR`` when the operator set it, else
+    ``<checkout>/.xla_cache``. The directory is part of the cache key's
+    lookup, so it is computed from the package's location only — never
+    from ``PIO_HOME``, the cwd, a pid or the time — and every process of
+    a train -> deploy sequence finds what the previous one compiled."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        Path(__file__).resolve().parents[2] / ".xla_cache")
 
-        d = os.environ.get("PIO_XLA_CACHE_DIR") or os.path.join(
-            os.environ.get("PIO_HOME", os.path.expanduser("~/.pio_tpu")),
-            "xla_cache")
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-    except Exception:  # noqa: BLE001 — cache is an optimization, never fatal
-        pass
+
+def _enable_compile_cache() -> None:
+    """Turn on the persistent compilation cache for a device-holding
+    verb. With ``JAX_COMPILATION_CACHE_DIR`` set JAX has already taken
+    the directory from the environment and none is set in code."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+    # JAX's default persists only compiles of 1 s or more. On the v5e
+    # the ten programs `pio deploy` prewarms build in 0.24-0.65 s each
+    # (4.5 s together), so at the default none of them would be kept;
+    # kept, they read back in 2.1 s together, and the ALS step (10 s of
+    # a 13 s first step cold, 2.9 s warm) is kept at any threshold
+    # (chip_smoke.py runs, PR 21; PERF.md). Everything is kept; the cost
+    # is one small file per eager init op.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 def cmd_train(args) -> int:
@@ -1003,9 +1014,9 @@ def _retriever_mesh(n):
 
 def cmd_bench(args) -> int:
     """`pio bench serve --ways 1,8`: sharded-serving sweep in a FRESH
-    subprocess — on CPU the virtual device count must be forced via
-    XLA_FLAGS before jax initializes, which this (already-jax-importing)
-    process cannot do for itself."""
+    subprocess, on whatever platform JAX finds there (the child prints
+    it). Under ``JAX_PLATFORMS=cpu`` the virtual device count is forced
+    via XLA_FLAGS, which must happen before jax initializes."""
     if getattr(args, "bench_command", "serve") == "backup":
         from ..storage.backup import run_backup_bench
 
@@ -1044,8 +1055,7 @@ def cmd_bench(args) -> int:
     if "auto" in ways:
         max_ways = max(max_ways, 8)
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    if env["JAX_PLATFORMS"] == "cpu":
+    if env.get("JAX_PLATFORMS") == "cpu":
         env["XLA_FLAGS"] = (
             env.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={max_ways}"
@@ -2177,7 +2187,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "this directory (view with TensorBoard/XProf)")
     sp.add_argument("--max-retries", type=int, default=2,
                     help="supervised retries for transient failures "
-                         "(preemption/device-lost/OOM); each retry resumes "
+                         "(preemption/device-lost); each retry resumes "
                          "from the latest checkpoint (default 2)")
     sp.add_argument("--retry-backoff-s", type=float, default=1.0,
                     help="base of the jittered exponential retry backoff "
@@ -2279,7 +2289,8 @@ def build_parser() -> argparse.ArgumentParser:
                     help="max queries per micro-batch")
     sp.add_argument("--batch-inflight", type=int, default=8,
                     help="max micro-batches dispatched concurrently "
-                         "(pipelines the per-call dispatch round trip)")
+                         "(overlaps one batch's host work with another's "
+                         "device call)")
     sp.add_argument("--retriever-mesh", default="0",
                     help="shard the serving catalog over this many devices "
                          "(model axis; 0/1 = single-device catalog; 'auto' "
@@ -2963,12 +2974,9 @@ COMMANDS = {
 
 def _apply_platform_override() -> None:
     """``PIO_PLATFORM=cpu`` (or ``tpu``) pins the jax backend before any
-    verb touches the device — the reference's local-mode escape hatch
-    (small/CI runs on the host; an unreachable accelerator would
-    otherwise hang `pio train` inside backend init, which no try/except
-    can interrupt). Both the env var and the config are set: some
-    environments re-point ``JAX_PLATFORMS`` at interpreter startup
-    (sitecustomize), so the env alone is not authoritative."""
+    verb touches the device — the reference's local-mode switch for
+    small/CI runs on the host. The env var is set for child processes
+    and the config for this one, where jax may already be imported."""
     plat = os.environ.get("PIO_PLATFORM")
     if not plat:
         return

@@ -2,8 +2,8 @@
 
 ``pio bench serve`` (tools/cli.py) runs this in a FRESH subprocess so the
 device count can be forced (on CPU, ``--xla_force_host_platform_device_
-count`` must be set before jax initializes); bench.py's sharded-topk
-section drives the same ``sweep()`` for the committed benchmark.
+count`` must be set before jax initializes). The first line printed
+names the platform the sweep ran on.
 
 Each row serves a fixed padded batch through ``ShardedDeviceRetriever``
 after ``prewarm()`` (AOT executables pinned in EXEC_CACHE), so the timed
@@ -28,9 +28,7 @@ __all__ = ["sweep", "ann_sweep", "clustered_items", "format_table", "main",
            "DEFAULT_WAYS", "DEFAULT_BATCH"]
 
 DEFAULT_WAYS = (1, 2, 4, 8)
-# B=128: per-shard score blocks stay cache-resident where the 1-way
-# [B, n_items] block does not — the regime the r5 inversion hid
-# (docs/PERF_NOTES.md "Closing the sharded-serving inversion")
+# B=128 is the micro-batcher's default ceiling (create_server batch_max)
 DEFAULT_BATCH = 128
 
 # The serving histograms' default table doubles per bucket — right for
@@ -120,8 +118,8 @@ def _device_evidence(before: dict | None = None) -> dict:
     """ISSUE 12: the device ledger's compile/HBM stamp for a bench row.
     Without ``before``: the current absolute totals (a baseline).
     With ``before``: the delta since that baseline — what THIS row's
-    retriever cost to compile and holds resident, so the r06 hardware
-    campaign carries device-side evidence alongside qps."""
+    retriever cost to compile and holds resident, so a row carries
+    device-side evidence alongside qps."""
     from ..obs.device import COMPILE_HISTOGRAMS, LEDGER
 
     cur = {
@@ -296,6 +294,11 @@ def main(argv=None) -> int:
                    help="'ann' benches the quantized IVF index against "
                         "exact brute force on a clustered catalog")
     args = p.parse_args(argv)
+    import jax
+
+    devs = jax.devices()
+    print(f"platform: {devs[0].platform} ({devs[0].device_kind}) "
+          f"x{len(devs)}")
     if args.retrieval == "ann":
         rows = ann_sweep(n_items=args.n_items, rank=args.rank,
                          batch=args.batch, k=args.k, iters=args.iters)

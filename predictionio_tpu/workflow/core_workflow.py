@@ -18,10 +18,12 @@ import traceback
 from datetime import datetime, timezone
 from typing import Any, Sequence
 
+from .. import native
 from ..controller.components import PersistentModel
 from ..controller.engine import Engine, TrainResult
 from ..controller.evaluation import Evaluation, MetricEvaluator, MetricEvaluatorResult
 from ..controller.params import EngineParams, params_to_json
+from ..obs.device import device_identity
 from ..obs.training import TRAINING
 from ..storage import EngineInstance, EvaluationInstance, Model, Storage
 from .context import Context
@@ -274,6 +276,12 @@ def run_train(
     meta = Storage.get_metadata()
     if reap_stale_after_s and reap_stale_after_s > 0:
         reap_orphans(meta, stale_after_s=reap_stale_after_s)
+    # what this run trains on, stamped into the record and logged before
+    # any work: a run that found the host where a chip was meant, or a
+    # numpy twin where the native library was meant, says so itself
+    backend_conf = {**device_identity(), "mesh": dict(ctx.mesh.shape),
+                    "native": native.available()}
+    log.info("training backend: %s", backend_conf)
     instance = EngineInstance(
         status="INIT",
         start_time=_now(),
@@ -283,6 +291,7 @@ def run_train(
         engine_factory=engine_factory,
         batch=batch,
         env=env or {},
+        backend_conf=backend_conf,
         data_source_params=_params_field(engine_params.data_source_params),
         preparator_params=_params_field(engine_params.preparator_params),
         algorithms_params=_algo_params_field(engine_params.algorithm_params_list),

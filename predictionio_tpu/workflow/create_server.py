@@ -48,6 +48,7 @@ import asyncio
 import dataclasses
 import hashlib
 import os
+import signal
 import tempfile
 import threading
 import json
@@ -62,7 +63,7 @@ from aiohttp import web
 
 from ..controller.engine import Engine, TrainResult
 from ..controller.params import parse_params
-from ..obs.device import LEDGER
+from ..obs.device import LEDGER, device_identity
 from ..obs.flight import FLIGHT
 from ..obs.http import handle_metrics, make_trace_middleware
 from ..obs.metrics import METRICS
@@ -157,8 +158,7 @@ class Deployed:
     de-sharding a catalog that was sharded because it exceeds one chip's
     HBM. ``retriever_mesh="auto"`` defers the width to the
     ``ops/retrieval.choose_shard_count`` cost model per catalog (1-way
-    where the BENCH_r05 inversion says the merge costs more than the
-    sharding saves).
+    where the model says the merge costs more than the sharding saves).
 
     ``retrieval``: the engine-params ``retrieval: {mode: exact|ann,
     nprobe, quantize, ...}`` block (ISSUE 7). ``mode: "ann"`` attaches
@@ -259,16 +259,16 @@ class Deployed:
                     # XLA program on every backend)
                     attach = None
             if attach is not None:
-                try:
-                    attach(*args, **kwargs)
-                    log.info(
-                        "%s retriever attached to %s",
-                        "ann" if mode == "ann"
-                        else "sharded" if mesh is not None else "device",
-                        type(model).__name__)
-                except Exception:  # pragma: no cover - serving must not die
-                    log.exception("device retriever attach failed; "
-                                  "serving falls back to host scoring")
+                # a failed attach raises: deploy exits non-zero with
+                # nothing bound, /reload fails and the old bundle keeps
+                # serving. Host scoring is what serves where no
+                # retriever is configured, never what an exception picks
+                attach(*args, **kwargs)
+                log.info(
+                    "%s retriever attached to %s",
+                    "ann" if mode == "ann"
+                    else "sharded" if mesh is not None else "device",
+                    type(model).__name__)
             if pipelined and getattr(model, "_retriever", None) is not None:
                 ap = getattr(model, "attach_pipeline", None)
                 # models without a query-factor table (similarity-only)
@@ -278,14 +278,10 @@ class Deployed:
                            None) is None:
                     ap = None
                 if ap is not None:
-                    try:
-                        ap()
-                        log.info("serving pipeline attached to %s (%s)",
-                                 type(model).__name__,
-                                 model._pipeline.stats()["mode"])
-                    except Exception:  # pragma: no cover - must not die
-                        log.exception("serving pipeline attach failed; "
-                                      "falling back to legacy dispatch")
+                    ap()
+                    log.info("serving pipeline attached to %s (%s)",
+                             type(model).__name__,
+                             model._pipeline.stats()["mode"])
         if self.prewarm_batch > 0:
             self._prewarm()
 
@@ -316,14 +312,12 @@ class Deployed:
                 r = getattr(model, attr, None)
                 if r is None or not hasattr(r, "prewarm"):
                     continue
-                try:
-                    warmed = r.prewarm(batch_sizes=sizes)
-                    warmed_keys.extend(warmed or ())
-                    log.info("prewarmed %s.%s shapes %s",
-                             type(model).__name__, attr, warmed)
-                except Exception:  # pragma: no cover - warming is advisory
-                    log.exception("executable prewarm failed; first "
-                                  "queries will compile on demand")
+                # a program that does not compile here will not compile
+                # on the first query either: the failure is the deploy's
+                warmed = r.prewarm(batch_sizes=sizes)
+                warmed_keys.extend(warmed or ())
+                log.info("prewarmed %s.%s shapes %s",
+                         type(model).__name__, attr, warmed)
         if warmed_keys:
             # one digest naming the compiled-program configuration this
             # bundle serves from (the warmed EXEC_CACHE keys carry
@@ -845,16 +839,15 @@ class EngineServer:
         skipped, then flip ready. Lets a replica bind its port and
         answer /health.json (live, not ready) while the AOT compile of
         the batch lattice runs — the fleet router holds hashed traffic
-        until ``ready`` goes true. Idempotent."""
+        until ``ready`` goes true. Idempotent once it has succeeded."""
         if not self._prewarming:
             return
-        try:
-            with self._reload_lock:
-                self.deployed.prewarm_batch = self.batch_max
-                self.deployed._prewarm()
-        finally:
-            self._prewarming = False
-            log.info("deferred prewarm complete; server is ready")
+        # a failed prewarm raises and leaves the server not ready
+        with self._reload_lock:
+            self.deployed.prewarm_batch = self.batch_max
+            self.deployed._prewarm()
+        self._prewarming = False
+        log.info("deferred prewarm complete; server is ready")
 
     def undrain(self) -> None:
         """Re-arm after a drain that did NOT end the process: a failed
@@ -1255,8 +1248,9 @@ class EngineServer:
                          ) -> dict | None:
         """The deployed bundle's retrieval posture: the first attached
         retriever's stats() (AnnRetriever: index cells / nprobe /
-        quantize / build seconds / exact-fallback flag), a plain mode
-        marker for exact device retrievers, None when serving from host
+        quantize / build seconds / exact-fallback flag), mode plus the
+        resolved kernel (native | xla | interpret) for exact device
+        retrievers, None when serving from host
         scoring. Pass the bundle snapshot serving_stats took under the
         reload lock so the block cannot tear against a concurrent swap."""
         bundle = bundle if bundle is not None else self.deployed
@@ -1266,7 +1260,8 @@ class EngineServer:
                 continue
             if hasattr(r, "stats"):
                 return r.stats()
-            return {"mode": "exact", "nTotal": getattr(r, "n_total", None),
+            return {"mode": "exact", "kernel": r.kernel,
+                    "nTotal": getattr(r, "n_total", None),
                     "sharded": type(r).__name__ == "ShardedDeviceRetriever"}
         return None
 
@@ -1414,8 +1409,9 @@ class EngineServer:
             "shadow": self.shadow.stats() if self.shadow else None,
             "feedback": self.feedback.stats() if self.feedback else None,
             # ISSUE 12: the device ledger (HBM by component, compile
-            # times, padding waste) + train/stream convergence
-            "device": LEDGER.snapshot(),
+            # times, padding waste) + train/stream convergence; and which
+            # device this process holds, as JAX reports it
+            "device": {**device_identity(), **LEDGER.snapshot()},
             "train": TRAINING.snapshot(),
         }
 
@@ -1956,6 +1952,10 @@ async def handle_variant_retire(request: web.Request) -> web.Response:
     return web.json_response({"message": "Retired", **entry.snapshot()})
 
 
+def _raise_graceful_exit() -> None:
+    raise web.GracefulExit()
+
+
 async def handle_stop(request: web.Request) -> web.Response:
     server: EngineServer = request.app[SERVER_KEY]
 
@@ -1967,9 +1967,12 @@ async def handle_stop(request: web.Request) -> web.Response:
             await server.drain()
         except Exception:  # noqa: BLE001 — exit regardless
             log.exception("drain failed during /stop; exiting anyway")
-        raise web.GracefulExit()
+        # raised from a loop callback, the way run_app's own signal
+        # handlers do: raised inside this task it would also be stored
+        # as the task's never-retrieved exception and logged at ERROR
+        asyncio.get_running_loop().call_soon(_raise_graceful_exit)
 
-    asyncio.create_task(_stop())
+    server._stop_task = asyncio.create_task(_stop())
     return web.json_response({"message": "Shutting down."})
 
 
@@ -2075,7 +2078,11 @@ def run_engine_server(
     ``prewarm_async`` (ISSUE 17, fleet replicas): bind the port FIRST
     and run the executable prewarm in the background — /health.json
     answers live-but-not-ready until it lands, so a router can track
-    the replica's startup without routing hashed traffic at it."""
+    the replica's startup without routing hashed traffic at it.
+
+    A retriever or pipeline attach that raises, or a prewarm that
+    raises, ends the deploy with a non-zero exit: before anything is
+    bound, or (``prewarm_async``) without ever reporting ready."""
     import errno
 
     logging.basicConfig(level=logging.INFO)
@@ -2085,9 +2092,20 @@ def run_engine_server(
     undeploy_stale("127.0.0.1" if ip in ("0.0.0.0", "::") else ip, port)
     server = EngineServer(engine, instance, defer_prewarm=prewarm_async,
                           **kwargs)
+    prewarm_failed = threading.Event()
     if prewarm_async:
-        threading.Thread(target=server.complete_prewarm,
-                         name="pio-prewarm", daemon=True).start()
+        def _prewarm():
+            try:
+                server.complete_prewarm()
+            except Exception:  # noqa: BLE001 — thread boundary
+                # the port is already bound, so fatal means: never
+                # ready, stop serving, exit non-zero below
+                log.exception("deferred prewarm failed; shutting down")
+                prewarm_failed.set()
+                os.kill(os.getpid(), signal.SIGTERM)
+
+        threading.Thread(target=_prewarm, name="pio-prewarm",
+                         daemon=True).start()
     log.info("Engine server (instance %s) starting on %s:%d", instance.id, ip, port)
     for attempt in range(bind_retries + 1):
         try:
@@ -2095,6 +2113,9 @@ def run_engine_server(
             # app's cleanup hooks
             web.run_app(create_engine_server_app(server), host=ip,
                         port=port, print=None)
+            if prewarm_failed.is_set():
+                raise SystemExit("executable prewarm failed; see the "
+                                 "traceback above")
             return
         except OSError as e:
             if e.errno != errno.EADDRINUSE:

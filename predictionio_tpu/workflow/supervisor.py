@@ -13,9 +13,10 @@ forever and the operator re-runs by hand.
 This module closes that gap with three cooperating pieces:
 
 - ``classify_error``: splits *transient* failures (device-lost /
-  preemption / transient-OOM message patterns, injected chaos faults,
-  and anything wrapped in ``TransientTrainingError``) from *fatal* ones
-  (a ValueError in user code retries forever and never gets better).
+  preemption message patterns, injected chaos faults, and anything
+  wrapped in ``TransientTrainingError``) from *fatal* ones (a ValueError
+  in user code, a chip another process holds, a program that does not
+  fit in HBM: retrying never makes them better).
   ``BaseException``s that aren't ``Exception``s — KeyboardInterrupt,
   SystemExit — are always fatal: the operator asked the run to die.
 
@@ -98,8 +99,25 @@ class TrainBudgetExceeded(RuntimeError):
     """The wall-clock budget expired before the run finished."""
 
 
+#: Message fragments that mark an exception as fatal whatever else it
+#: says, in the v5e runtime's own words (libtpu 0.0.34). A chip belongs
+#: to one process and its HBM to that process alone, so neither a held
+#: chip nor an overflow goes away while this process backs off:
+#:   "Unable to initialize backend 'tpu': ABORTED: Internal error when
+#:    accessing libtpu multi-process lockfile."      (chip held)
+#:   "RESOURCE_EXHAUSTED: Allocation (size=25600000000) would exceed
+#:    memory (size=17179869184) :: ... space=hbm"    (at compile time)
+#:   "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to
+#:    allocate 4.00G. That was not possible. There are 3.75G free."
+_FATAL_PATTERNS = (
+    "unable to initialize backend",
+    "libtpu multi-process lockfile",
+    "already in use",
+    "resource_exhausted",
+)
+
 #: Message fragments that mark an exception as transient — the
-#: device-lost / preemption / capacity vocabulary of TPU & GPU runtimes
+#: device-lost / preemption vocabulary of TPU & GPU runtimes
 #: (compare tensorflow's UnavailableError/AbortedError retry set).
 _TRANSIENT_PATTERNS = (
     "device lost",
@@ -107,10 +125,6 @@ _TRANSIENT_PATTERNS = (
     "device_lost",
     "preempt",            # "preempted", "preemption notice", ...
     "maintenance event",
-    "resource_exhausted",
-    "resource exhausted",
-    "out of memory",
-    "oom",
     "data_loss",
     "unavailable",
     "deadline_exceeded",
@@ -145,6 +159,8 @@ def classify_error(exc: BaseException) -> str:
     if isinstance(exc, (MemoryError, ConnectionError, TimeoutError)):
         return "transient"
     msg = f"{type(exc).__name__}: {exc}".lower()
+    if any(p in msg for p in _FATAL_PATTERNS):
+        return "fatal"
     if any(p in msg for p in _TRANSIENT_PATTERNS):
         return "transient"
     return "fatal"

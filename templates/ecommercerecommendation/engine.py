@@ -253,8 +253,8 @@ class ECommAlgorithm(Algorithm):
     def batch_predict(self, model: ECommModel, queries):
         """One micro-batch: the seen-items lookups dedupe per user via a
         batch-scoped memo (the global constraint read is TTL-cached in
-        _unavailable_items) — VERDICT r3 weak #6: the reference does two
-        sequential store reads per query on this path."""
+        _unavailable_items); the reference does two sequential store
+        reads per query on this path."""
         seen: dict = {}
         return [(i, self._predict_one(model, q, seen)) for i, q in queries]
 

@@ -322,14 +322,14 @@ def test_tier_wise_solve_matches_global(rng, mesh8, monkeypatch):
 
 
 def test_model_sharded_collective_inventory(mesh8):
-    """The compiled model-sharded train step's communication story
-    (VERDICT r3 item 2): the ONLY factor-sized collectives are one
+    """The compiled model-sharded train step's communication story:
+    the ONLY factor-sized collectives are one
     replication all-gather of the opposite factors per half-step (plus
     the solve-output gathers) — no all-to-all, no reduce-scatter, and
     crucially NO all-reduce: GSPMD's fallback for gathers from a
     row-sharded operand is mask+all-reduce over the GATHERED block
     (traffic ~ nnz_padded, per tier, inside lax.map), which is what made
-    the 4x2 mesh slower than 8x1 in BENCH_r03. Committed input shardings
+    the 4x2 mesh slower than 8x1 on a forced CPU mesh. Committed input shardings
     matter — uncommitted inputs let propagation pick different parameter
     placements with worse lowerings."""
     import re

@@ -301,7 +301,7 @@ def test_deployed_auto_mesh_and_ann_attach(rng):
 
 def test_serve_bench_ann_sweep_smoke(rng):
     """tools/serve_bench.ann_sweep emits the exact/ann row pair with a
-    measured recall and the ivf index tag (the shape bench.py parses)."""
+    measured recall and the ivf index tag."""
     from predictionio_tpu.tools.serve_bench import ann_sweep, format_table
 
     rows = ann_sweep(n_items=20_000, rank=16, batch=16, k=10, iters=2)

@@ -10,7 +10,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.parallel import collectives as C
 
-shard_map = C.get_shard_map()
+shard_map = jax.shard_map
 
 
 @pytest.fixture(scope="module")
@@ -40,7 +40,7 @@ def test_allreduce_and_axis_info(mesh1d):
 
     total, mean, size = shard_map(
         f, mesh=mesh1d, in_specs=(P("data"),),
-        out_specs=(P(), P(), P()), check_rep=False,
+        out_specs=(P(), P(), P()), check_vma=False,
     )(x)
     assert float(total) == 24.0
     assert float(mean) == 3.0
@@ -54,7 +54,7 @@ def test_ring_shift_rotates(mesh1d):
         return C.ring_shift(blk, "data")
 
     out = shard_map(f, mesh=mesh1d, in_specs=(P("data"),),
-                    out_specs=P("data"), check_rep=False)(x)
+                    out_specs=P("data"), check_vma=False)(x)
     # device i's block moved to device i+1: global result is a roll
     np.testing.assert_array_equal(np.asarray(out)[:, 0], np.roll(np.arange(8), 1))
 
@@ -66,7 +66,7 @@ def test_allgather_tiled(mesh1d):
         return C.allgather(blk, "data", axis=0)
 
     out = shard_map(f, mesh=mesh1d, in_specs=(P("data"),),
-                    out_specs=P(None), check_rep=False)(x)
+                    out_specs=P(None), check_vma=False)(x)
     np.testing.assert_array_equal(np.asarray(out)[:, 0], np.arange(16))
 
 
@@ -80,7 +80,7 @@ def test_reduce_scatter_matches_psum_shard(mesh1d):
         return C.reduce_scatter(blk[0], "data")
 
     out = shard_map(f, mesh=mesh1d, in_specs=(P("data", None),),
-                    out_specs=P("data"), check_rep=False)(x)
+                    out_specs=P("data"), check_vma=False)(x)
     np.testing.assert_allclose(np.asarray(out), x.sum(axis=0), rtol=1e-5)
 
 
@@ -93,5 +93,5 @@ def test_all_to_all_roundtrip(mesh1d):
         return C.all_to_all(y, "data", split_axis=0, concat_axis=1)
 
     out = shard_map(f, mesh=mesh1d, in_specs=(P("data"),),
-                    out_specs=P("data"), check_rep=False)(x)
+                    out_specs=P("data"), check_vma=False)(x)
     np.testing.assert_allclose(np.asarray(out), x, rtol=1e-6)

@@ -16,10 +16,6 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from functools import partial
 
-from predictionio_tpu.parallel.collectives import get_shard_map
-
-shard_map = get_shard_map()
-
 from predictionio_tpu.models.seq_attention import (
     SeqRecConfig,
     build_sequences,
@@ -31,6 +27,8 @@ from predictionio_tpu.parallel.ring_attention import (
     ring_self_attention,
     ulysses_attention,
 )
+
+shard_map = jax.shard_map
 
 
 def dense_attention(q, k, v, causal=False):
@@ -115,7 +113,7 @@ def test_ulysses_matches_dense(seq_mesh, rng, causal):
     fn = shard_map(
         partial(ulysses_attention, axis_name="seq", causal=causal),
         mesh=seq_mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     sh = NamedSharding(seq_mesh, spec)
     got = fn(*(jax.device_put(x, sh) for x in (q, k, v)))

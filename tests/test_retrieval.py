@@ -163,8 +163,12 @@ def test_sharded_collective_inventory(rng):
     assert not re.search(r"all-reduce(?!-scatter)", hlo), "unexpected all-reduce"
     assert "all-to-all" not in hlo, "unexpected all-to-all"
     assert "reduce-scatter" not in hlo, "unexpected reduce-scatter"
-    gathered = re.findall(r"all-gather\.?\d*\s*=\s*\S*f32\[([\d,]+)\]", hlo)
-    assert gathered, "expected the candidate-merge all-gather"
+    # keyed on the op, not on the instruction's name (the printer of the
+    # installed jaxlib writes `%all_gather.3 = f32[8,256]{0,1} all-gather(`)
+    gathered = re.findall(
+        r"=\s*f32\[([\d,]+)\]\S*\s+all-gather(?:-start)?\(", hlo)
+    assert len(gathered) == 1, (
+        f"expected ONE candidate-merge all-gather, found {gathered}")
     for dims in gathered:
         size = np.prod([int(x) for x in dims.split(",")])
         assert size <= 8 * b_pad * 2 * k_pad * 4, (
@@ -432,16 +436,3 @@ def test_sharded_similarity_retriever_matches_host(rng):
                                [s for _, s in host], rtol=1e-5, atol=1e-6)
     # serialization still strips the device handle
     assert "_sim_retriever" not in m.__getstate__()
-
-
-def test_device_seconds_xla_mode(rng):
-    """topk_device_seconds must spin the XLA call for an xla-mode
-    retriever (the non-TPU serving default) — the kernel-path spin would
-    rebuild the interpret kernel and time the wrong program."""
-    from predictionio_tpu.ops.retrieval import topk_device_seconds
-
-    items = rng.standard_normal((400, 32)).astype(np.float32)
-    r = DeviceRetriever(items)  # CPU backend -> xla mode
-    assert r._mode == "xla"
-    dt = topk_device_seconds(r, 5, iters=4)
-    assert 0 < dt < 60

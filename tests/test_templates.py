@@ -190,7 +190,7 @@ class TestECommerce:
         assert out.itemScores == ()
 
     def test_constraint_ttl_and_batch_dedupe(self, rng, mesh8, monkeypatch):
-        """Serving-plane store traffic (VERDICT r3 weak #6): the global
+        """Serving-plane store traffic: the global
         unavailable-items read is TTL-cached (staleness bounded by
         constraint_ttl_seconds) and a micro-batch dedupes seen-items
         lookups per user."""

@@ -1,0 +1,157 @@
+"""Compile for TPU v5e without a chip.
+
+libtpu ships a compile-only client: ``get_topology_desc(platform="tpu",
+topology_name="v5e:2x2")`` gives four ``TPU v5 lite`` devices that can be
+lowered and compiled against — the real XLA:TPU and Mosaic compilers —
+but never run on. Every program the chip-holding verbs put on the device
+(the Pallas top-k kernel alone and fused into the serving pipeline's
+program, the ALS train step on one and on four devices, the sharded
+retriever, the stock flash-attention kernel) is compiled here, so a
+kernel change Mosaic refuses turns tier-1 red before it costs chip time.
+
+What this cannot show is anything a run shows: results, HBM at run time,
+time. `python chip_smoke.py` on the chip does that.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from predictionio_tpu.models.als import _layout_shardings, make_train_step
+from predictionio_tpu.ops.neighbors import build_bilinear_layout
+from predictionio_tpu.ops.pipeline import _capacity, _fused_fn
+from predictionio_tpu.ops.retrieval import (ShardedDeviceRetriever,
+                                            _pad_items, _query_shapes,
+                                            _raw_call)
+
+#: the smoke's catalog (ML-20M's items at rank 64) and query table
+N_ITEMS, N_USERS, RANK = 26_744, 138_493, 64
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four compile-only v5e devices, or a skip that says why."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure here means: skip
+        pytest.skip(f"no compile-only TPU client in this installation: "
+                    f"{type(e).__name__}: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+def _catalog_shape():
+    """(padded rows, padded lanes, tile) of the smoke's catalog, by the
+    retriever's own rule."""
+    padded, tile_n = _pad_items(np.zeros((N_ITEMS, RANK), np.float32),
+                                N_ITEMS, 512)
+    return (*padded.shape, tile_n)
+
+
+def _compile_fused(dev, b_pad, k_pad):
+    n_pad, d_pad, tile_n = _catalog_shape()
+    cap = _capacity(N_USERS)
+    one = SingleDeviceSharding(dev)
+    raw = _raw_call(b_pad, d_pad, n_pad, N_ITEMS, k_pad, tile_n, False)
+    # as ServingPipeline._exec_fused builds it on a TPU: donating
+    return jax.jit(_fused_fn(raw, True), donate_argnums=(0,)).lower(
+        jax.ShapeDtypeStruct((b_pad,), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((cap, d_pad), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((n_pad, d_pad), jnp.float32, sharding=one),
+    ).compile()
+
+
+def test_fused_pipeline_program_over_the_prewarm_lattice(v5e):
+    """`pio deploy` prewarms b_pad 8..128 at the k_pad of num=10; each is
+    gather + the native Pallas kernel + packing in ONE program."""
+    for b in (1, 8, 16, 32, 64, 128):
+        b_pad, k_pad = _query_shapes(b, 10, N_ITEMS)
+        exe = _compile_fused(v5e[0], b_pad, k_pad)
+        assert "tpu_custom_call" in exe.as_text()  # Mosaic's kernel is in it
+        assert (exe.memory_analysis().output_size_in_bytes
+                >= b_pad * 2 * k_pad * 4)
+
+
+def test_topk_kernel_at_a_large_k(v5e):
+    """A client may ask for hundreds: num=500 pads to k_pad 504, and the
+    kernel's [B, k] accumulator block and merge buffers grow with it."""
+    n_pad, d_pad, tile_n = _catalog_shape()
+    one = SingleDeviceSharding(v5e[0])
+    for b_pad, k_pad in ((8, 504), (128, 504)):
+        jax.jit(_raw_call(b_pad, d_pad, n_pad, N_ITEMS, k_pad, tile_n,
+                          False)).lower(
+            jax.ShapeDtypeStruct((b_pad, d_pad), jnp.float32, sharding=one),
+            jax.ShapeDtypeStruct((n_pad, d_pad), jnp.float32, sharding=one),
+        ).compile()
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_als_train_step(v5e, n_dev):
+    """`make_train_step` over a small layout on the 1x and the 4x1 data
+    mesh `pio train` builds by default (`make_mesh()` takes every device)."""
+    rng = np.random.default_rng(0)
+    nu, ni, n = 512, 256, 8_000
+    u_lay, i_lay = build_bilinear_layout(
+        rng.integers(0, nu, n), rng.integers(0, ni, n),
+        (np.round(rng.random(n) * 9 + 1) / 2).astype(np.float32), nu, ni)
+    mesh = Mesh(np.asarray(v5e[:n_dev]), ("data",))
+    blk, rep = _layout_shardings(mesh)
+
+    def spec(lay):
+        out = []
+        for b, m in zip(lay.buckets, lay.metas):
+            e = {"ids": jax.ShapeDtypeStruct(b.ids.shape, b.ids.dtype,
+                                             sharding=blk),
+                 "vals": jax.ShapeDtypeStruct(b.vals.shape, b.vals.dtype,
+                                              sharding=blk)}
+            if m.seg is not None:
+                e["seg"] = jax.ShapeDtypeStruct(m.seg.shape, m.seg.dtype,
+                                                sharding=rep)
+            out.append(e)
+        return out
+
+    fac = NamedSharding(mesh, P(None, None))
+    make_train_step(mesh, u_lay, i_lay, rank=RANK, lambda_=0.01).lower(
+        spec(u_lay), spec(i_lay),
+        jax.ShapeDtypeStruct((u_lay.slots, RANK), jnp.float32, sharding=fac),
+        jax.ShapeDtypeStruct((i_lay.slots, RANK), jnp.float32, sharding=fac),
+    ).compile()
+
+
+def test_sharded_retriever_four_ways(v5e):
+    """`pio deploy --retriever-mesh 4`: per-shard score + top-k, one
+    all-gather, merge — compiled for the 2x2 host."""
+    mesh = Mesh(np.asarray(v5e), ("model",))
+    ret = object.__new__(ShardedDeviceRetriever)  # no device to put items on
+    ret._mesh, ret._axis, ret._nshards = mesh, "model", 4
+    ret.n_total, ret.dim = N_ITEMS, RANK
+    n_pad = -(-N_ITEMS // (128 * 4)) * (128 * 4)
+    ret._shard_rows = n_pad // 4
+    ret._items = jax.ShapeDtypeStruct((n_pad, 128), jnp.float32)
+    for b in (1, 128):
+        b_pad, k_pad = _query_shapes(b, 10, N_ITEMS)
+        hlo = ret._build(b_pad, min(k_pad, ret._shard_rows), k_pad).as_text()
+        assert hlo.count(" all-gather(") + hlo.count(" all-gather-start(") == 1
+
+
+def test_stock_flash_kernel_at_the_shapes_flash_attention_admits(
+        v5e, monkeypatch):
+    """`flash_attention` takes the stock Pallas kernel on a TPU when
+    L % 128 == 0 and D in (64, 128); both head widths, causal and not."""
+    from predictionio_tpu.parallel.ring_attention import flash_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e[0])
+    for d, causal in ((64, True), (128, False)):
+        x = jax.ShapeDtypeStruct((2, 512, 4, d), jnp.bfloat16, sharding=one)
+        exe = jax.jit(functools.partial(flash_attention, causal=causal)
+                      ).lower(x, x, x).compile()
+        assert "tpu_custom_call" in exe.as_text()

@@ -128,11 +128,32 @@ def test_classifier_fatal_errors():
     assert classify_error(SystemExit(1)) == "fatal"
 
 
+def test_classifier_held_chip_and_hbm_overflow_are_fatal():
+    """The v5e runtime's own messages (libtpu 0.0.34, taken on the chip
+    and from its compiler): a chip another process holds and a program
+    that does not fit in HBM stay that way through any back-off, so they
+    are reported once instead of retried."""
+    assert classify_error(RuntimeError(
+        "Unable to initialize backend 'tpu': ABORTED: Internal error when "
+        "accessing libtpu multi-process lockfile. Run \"$ sudo rm "
+        "/tmp/libtpu_lockfile\". (set JAX_PLATFORMS='' to automatically "
+        "choose an available backend)")) == "fatal"
+    assert classify_error(RuntimeError(
+        "RESOURCE_EXHAUSTED: Allocation (size=25600000000) would exceed "
+        "memory (size=17179869184) :: #allocation9 [shape = "
+        "'f32[80000,80000]{0,1:T(8,128)}', space=hbm]")) == "fatal"
+    assert classify_error(RuntimeError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+        "allocate 4.00G. That was not possible. There are 3.75G free.; "
+        "(0x0x0_HBM0)")) == "fatal"
+    # an UNAVAILABLE wrapped around a held chip is still a held chip
+    assert classify_error(RuntimeError(
+        "UNAVAILABLE: TPU is already in use by pid 7")) == "fatal"
+
+
 def test_classifier_transient_errors():
     assert classify_error(RuntimeError("TPU device lost")) == "transient"
     assert classify_error(RuntimeError("worker preempted by scheduler")) == "transient"
-    assert classify_error(RuntimeError("RESOURCE_EXHAUSTED: out of memory "
-                                       "while trying to allocate")) == "transient"
     assert classify_error(RuntimeError("UNAVAILABLE: socket closed")) == "transient"
     assert classify_error(FaultInjected("train.step")) == "transient"
     assert classify_error(TransientTrainingError("wrapped")) == "transient"
