@@ -46,6 +46,8 @@ import time
 import numpy as np
 
 from ..obs.metrics import METRICS
+from ..obs.startup import STARTUP
+from ..obs.trace import span
 from ..obs.training import TRAINING
 from ..ops.neighbors import build_bilinear_layout
 from ..ops.retrieval import RetrievalServingMixin
@@ -1072,6 +1074,27 @@ class _ConvergenceSampler:
                          step_seconds=step_seconds)
 
 
+#: where each phase of ``train_als`` leaves its seconds in the attempt's
+#: record (``EngineInstance.convergence``); the steps and the probe leave
+#: theirs through ``TRAINING.observe``
+_PHASE_KEYS = {
+    "train.als.layout": "layoutSeconds",
+    "train.als.layout.plan": "layoutPlanSeconds",
+    "train.als.layout.user": "layoutUserSeconds",
+    "train.als.layout.item": "layoutItemSeconds",
+    "train.als.upload": "uploadSeconds",
+    "train.als.init_factors": "initSeconds",
+    "train.als.final_pull": "finalPullSeconds",
+}
+
+
+def _note_phase(name: str, t0: float, t1: float) -> None:
+    """Span sink of ``train_als``'s phases."""
+    key = _PHASE_KEYS.get(name)
+    if key is not None:
+        TRAINING.note("train", **{key: t1 - t0})
+
+
 def train_als(ratings: Ratings, config: ALSConfig, mesh=None, *,
               checkpointer=None, checkpoint_every: int = 0) -> ALSModel:
     """Alternate user/item half-steps for ``config.iterations`` rounds.
@@ -1103,157 +1126,168 @@ def train_als(ratings: Ratings, config: ALSConfig, mesh=None, *,
         model_sharded = False
 
     TRAINING.begin("train", total_iterations=config.iterations)
-    t_layout = time.perf_counter()
-    u_lay, i_lay = build_bilinear_layout(
-        ratings.user_indices, ratings.item_indices, ratings.ratings, nu, ni,
-        tiers=config.tiers, gather_budget=config.gather_budget,
-        seed=config.seed, chunk_cap=config.chunk_cap,
-        align=mesh.shape["model"] if model_sharded else 8,
-    )
-    dropped = u_lay.dropped + i_lay.dropped
-    if dropped:
-        log.info("degree tiers dropped %d entries beyond the last tier", dropped)
-    # factor matrices live in PERMUTED slot order during training
-    # (tier-concatenation order, SideLayout.pos maps true rows to slots);
-    # slot counts are 8-aligned so rows shard evenly over the model axis
-    # when tensor-parallel. Everything host-facing (checkpoints, the
-    # final model) is unpermuted via pos.
-    fac = NamedSharding(mesh, P("model" if model_sharded else None, None))
-
-    vals_dtype = "bfloat16" if config.compute_dtype == "bfloat16" else None
-    t_upload = time.perf_counter()
-    u_bk = put_layout(u_lay, mesh, vals_dtype=vals_dtype)
-    i_bk = put_layout(i_lay, mesh, vals_dtype=vals_dtype)
-    jax.block_until_ready((u_bk, i_bk))
+    if STARTUP.process_to_device_seconds is not None:
+        TRAINING.note("train", processToDeviceSeconds=
+                      STARTUP.process_to_device_seconds)
+    # the phases from here to the return are one chain of spans, each
+    # starting on the clock reading that ended the one before (`t0=`), so
+    # their seconds add up to the whole call with nothing between them
+    with span("train.als.layout", sink=_note_phase) as sp:
+        u_lay, i_lay = build_bilinear_layout(
+            ratings.user_indices, ratings.item_indices, ratings.ratings,
+            nu, ni, tiers=config.tiers, gather_budget=config.gather_budget,
+            seed=config.seed, chunk_cap=config.chunk_cap,
+            align=mesh.shape["model"] if model_sharded else 8,
+            sink=_note_phase,
+        )
+        dropped = u_lay.dropped + i_lay.dropped
+        if dropped:
+            log.info("degree tiers dropped %d entries beyond the last tier",
+                     dropped)
+        # factor matrices live in PERMUTED slot order during training
+        # (tier-concatenation order, SideLayout.pos maps true rows to
+        # slots); slot counts are 8-aligned so rows shard evenly over the
+        # model axis when tensor-parallel. Everything host-facing
+        # (checkpoints, the final model) is unpermuted via pos.
+        fac = NamedSharding(mesh, P("model" if model_sharded else None, None))
+        vals_dtype = ("bfloat16" if config.compute_dtype == "bfloat16"
+                      else None)
+    with span("train.als.upload", sink=_note_phase, t0=sp.t1) as sp:
+        u_bk = put_layout(u_lay, mesh, vals_dtype=vals_dtype)
+        i_bk = put_layout(i_lay, mesh, vals_dtype=vals_dtype)
+        jax.block_until_ready((u_bk, i_bk))
     # what each device holds once the layout is up: a layout that landed
     # whole on device 0 shows here, not in a slow step later (CPU
     # devices report no memory statistics)
     in_use = [(d.memory_stats() or {}).get("bytes_in_use")
               for d in mesh.local_devices]
-    TRAINING.note("train", layoutSeconds=t_upload - t_layout,
-                  uploadSeconds=time.perf_counter() - t_upload,
-                  deviceBytesInUse=in_use)
+    TRAINING.note("train", deviceBytesInUse=in_use)
     if len(in_use) > 1:
         log.info("bytes in use per device after the layout upload: %s",
                  in_use)
 
-    def _to_slots(host_arr, lay):
-        """True-row-order host array -> permuted device layout (non-owner
-        slots stay exactly zero: padded ids gather from them). This is
-        where a restored GLOBAL checkpoint state — possibly reassembled
-        from a different process count's shards — gets re-sliced for the
-        CURRENT mesh: every process holds the same host array and
-        contributes only its device-local slice under multi-process."""
-        perm = np.zeros((lay.slots, rank), np.float32)
-        perm[lay.pos] = np.asarray(host_arr)
-        if jax.process_count() > 1:
-            return jax.make_array_from_process_local_data(
-                fac, _process_local_slice(perm, fac), global_shape=perm.shape)
-        return jax.device_put(perm, fac)
+    with span("train.als.init_factors", sink=_note_phase,
+              t0=sp.t1) as sp:
+        def _to_slots(host_arr, lay):
+            """True-row-order host array -> permuted device layout (non-owner
+            slots stay exactly zero: padded ids gather from them). This is
+            where a restored GLOBAL checkpoint state — possibly reassembled
+            from a different process count's shards — gets re-sliced for the
+            CURRENT mesh: every process holds the same host array and
+            contributes only its device-local slice under multi-process."""
+            perm = np.zeros((lay.slots, rank), np.float32)
+            perm[lay.pos] = np.asarray(host_arr)
+            if jax.process_count() > 1:
+                return jax.make_array_from_process_local_data(
+                    fac, _process_local_slice(perm, fac), global_shape=perm.shape)
+            return jax.device_put(perm, fac)
 
-    # run fingerprint: a checkpoint is only resumable for the exact same
-    # ratings + config — resuming across changed data or hyperparameters
-    # would silently return a model of the wrong run
-    fp = _run_fingerprint(ratings, config)
+        # run fingerprint: a checkpoint is only resumable for the exact same
+        # ratings + config — resuming across changed data or hyperparameters
+        # would silently return a model of the wrong run
+        fp = _run_fingerprint(ratings, config)
 
-    def _same_run(state) -> bool:
-        v_arr, u_arr = state.get("v"), state.get("u")
-        return (state.get("fp") is not None and int(state["fp"]) == fp
-                and v_arr is not None and u_arr is not None
-                and v_arr.shape == (ni, rank) and u_arr.shape == (nu, rank))
+        def _same_run(state) -> bool:
+            v_arr, u_arr = state.get("v"), state.get("u")
+            return (state.get("fp") is not None and int(state["fp"]) == fp
+                    and v_arr is not None and u_arr is not None
+                    and v_arr.shape == (ni, rank) and u_arr.shape == (nu, rank))
 
-    saw_same_run = False
+        saw_same_run = False
 
-    def _resumable(state) -> bool:
-        nonlocal saw_same_run
-        if not _same_run(state):
-            return False
-        saw_same_run = True
-        return int(state["it"]) <= config.iterations
+        def _resumable(state) -> bool:
+            nonlocal saw_same_run
+            if not _same_run(state):
+                return False
+            saw_same_run = True
+            return int(state["it"]) <= config.iterations
 
-    start_it = 0
-    v = None
-    u_restored = None
-    if checkpointer is not None:
-        restored = checkpointer.restore_first_valid(_resumable)
-        if restored is not None:
-            ck_step, state = restored
-            start_it = int(state["it"])
-            # checkpoints hold true-row-order arrays (resumable under any
-            # mesh/layout); re-permute into this run's slot order
-            v = _to_slots(state["v"], i_lay)
-            u_restored = _to_slots(state["u"], u_lay)
-            log.info("resuming ALS from checkpoint step %d (iter %d)",
-                     ck_step, start_it)
-        elif checkpointer.steps():
-            if saw_same_run:
-                # same data+config, just trained past the current target:
-                # those checkpoints stay valid for a later higher-target
-                # run — keep them (retention only prunes steps <= the one
-                # being saved, so this run's fresh saves are safe)
-                log.warning(
-                    "checkpoint steps exist beyond the current iteration "
-                    "target (%d); keeping them and training fresh",
-                    config.iterations)
-            else:
-                # genuinely stale (data/config changed); purge or retention
-                # would prefer them over this run's fresh saves
-                log.warning("no resumable checkpoint (data/config changed); "
-                            "clearing %d stale step(s) and starting fresh",
-                            len(checkpointer.steps()))
-                checkpointer.clear()
-    if v is None:
-        key = jax.random.PRNGKey(config.seed)
-        k_u, k_v = jax.random.split(key)
-        # MLlib-style init: small positive factors (true rows only — the
-        # layout's padding slots must stay exactly zero)
-        v = _to_slots(
-            np.abs(np.asarray(jax.random.normal(k_v, (ni, rank),
-                                                dtype=jnp.float32)))
-            / np.sqrt(rank), i_lay)
-        # the user side starts from the same init scheme purely as the
-        # first sweep's CG warm-start seed (the first half-step solves u
-        # from v, so u's init never enters the math beyond that seed).
-        # Kept separate from u_restored: a seed is not a trained factor,
-        # and the iterations==0 fallback below must not return it.
-        u_seed = _to_slots(
-            np.abs(np.asarray(jax.random.normal(k_u, (nu, rank),
-                                                dtype=jnp.float32)))
-            / np.sqrt(rank), u_lay)
-    else:
-        u_seed = None
+        start_it = 0
+        v = None
+        u_restored = None
+        if checkpointer is not None:
+            restored = checkpointer.restore_first_valid(_resumable)
+            if restored is not None:
+                ck_step, state = restored
+                start_it = int(state["it"])
+                # checkpoints hold true-row-order arrays (resumable under any
+                # mesh/layout); re-permute into this run's slot order
+                v = _to_slots(state["v"], i_lay)
+                u_restored = _to_slots(state["u"], u_lay)
+                log.info("resuming ALS from checkpoint step %d (iter %d)",
+                         ck_step, start_it)
+            elif checkpointer.steps():
+                if saw_same_run:
+                    # same data+config, just trained past the current target:
+                    # those checkpoints stay valid for a later higher-target
+                    # run — keep them (retention only prunes steps <= the one
+                    # being saved, so this run's fresh saves are safe)
+                    log.warning(
+                        "checkpoint steps exist beyond the current iteration "
+                        "target (%d); keeping them and training fresh",
+                        config.iterations)
+                else:
+                    # genuinely stale (data/config changed); purge or retention
+                    # would prefer them over this run's fresh saves
+                    log.warning("no resumable checkpoint (data/config changed); "
+                                "clearing %d stale step(s) and starting fresh",
+                                len(checkpointer.steps()))
+                    checkpointer.clear()
+        if v is None:
+            key = jax.random.PRNGKey(config.seed)
+            k_u, k_v = jax.random.split(key)
+            # MLlib-style init: small positive factors (true rows only — the
+            # layout's padding slots must stay exactly zero)
+            v = _to_slots(
+                np.abs(np.asarray(jax.random.normal(k_v, (ni, rank),
+                                                    dtype=jnp.float32)))
+                / np.sqrt(rank), i_lay)
+            # the user side starts from the same init scheme purely as the
+            # first sweep's CG warm-start seed (the first half-step solves u
+            # from v, so u's init never enters the math beyond that seed).
+            # Kept separate from u_restored: a seed is not a trained factor,
+            # and the iterations==0 fallback below must not return it.
+            u_seed = _to_slots(
+                np.abs(np.asarray(jax.random.normal(k_u, (nu, rank),
+                                                    dtype=jnp.float32)))
+                / np.sqrt(rank), u_lay)
+        else:
+            u_seed = None
 
-    # the warm-start depth (DEFAULT_CG_ITERS_WARM) presumes alternation
-    # corrects the shallower inner solves — true from the accuracy-gated
-    # 3-iteration config up; for 1-2 iteration runs the first sweep's
-    # "warm" seed is still the random init and nothing corrects after it,
-    # so those keep the cold depth
-    cg_iters = config.cg_iters
-    if (cg_iters is None and config.solver == "cg"
-            and not config.implicit_prefs and config.iterations < 3):
-        cg_iters = DEFAULT_CG_ITERS
-    step = make_train_step(
-        mesh, u_lay, i_lay, rank=rank, lambda_=config.lambda_,
-        implicit=config.implicit_prefs, alpha=config.alpha,
-        model_sharded=model_sharded,
-        compute_dtype=config.compute_dtype, solver=config.solver,
-        cg_iters=cg_iters,
-    )
-    u = None
-    carry_u = u_restored if u_restored is not None else u_seed
-    conv = _ConvergenceSampler(ratings, config, u_lay, i_lay)
+        # the warm-start depth (DEFAULT_CG_ITERS_WARM) presumes alternation
+        # corrects the shallower inner solves — true from the accuracy-gated
+        # 3-iteration config up; for 1-2 iteration runs the first sweep's
+        # "warm" seed is still the random init and nothing corrects after it,
+        # so those keep the cold depth
+        cg_iters = config.cg_iters
+        if (cg_iters is None and config.solver == "cg"
+                and not config.implicit_prefs and config.iterations < 3):
+            cg_iters = DEFAULT_CG_ITERS
+        step = make_train_step(
+            mesh, u_lay, i_lay, rank=rank, lambda_=config.lambda_,
+            implicit=config.implicit_prefs, alpha=config.alpha,
+            model_sharded=model_sharded,
+            compute_dtype=config.compute_dtype, solver=config.solver,
+            cg_iters=cg_iters,
+        )
+        u = None
+        carry_u = u_restored if u_restored is not None else u_seed
+        conv = _ConvergenceSampler(ratings, config, u_lay, i_lay)
     for it in range(start_it, config.iterations):
-        # chaos site: a preemption striking mid-training (arm with
-        # after=N to let N iterations — and their checkpoints — land)
-        FAULTS.fire("train.step")
-        t_step = time.perf_counter()
         # timed to the end of the device work (dispatch returns before
         # it); the sampler below pulls from u and v anyway, so waiting
-        # here delays nothing
-        u, v = jax.block_until_ready(step(u_bk, i_bk, carry_u, v))
-        step_s = time.perf_counter() - t_step
+        # here delays nothing. The first step of an attempt traces and
+        # compiles (or reads the compilation cache) before it runs.
+        with span("train.als.first_step" if it == start_it
+                  else "train.als.step", t0=sp.t1, step=it) as sp:
+            # chaos site: a preemption striking mid-training (arm with
+            # after=N to let N iterations — and their checkpoints — land)
+            FAULTS.fire("train.step")
+            u, v = jax.block_until_ready(step(u_bk, i_bk, carry_u, v))
+        step_s = sp.t1 - sp.t0
         _M_TRAIN_STEP.record(step_s)
-        conv.observe(it, u, v, step_s)
+        with span("train.als.observe", t0=sp.t1, iteration=it) as sp:
+            conv.observe(it, u, v, step_s)
         carry_u = u
         done = it + 1
         if (checkpointer is not None and checkpoint_every > 0
@@ -1262,29 +1296,32 @@ def train_als(ratings: Ratings, config: ALSConfig, mesh=None, *,
             # with v_k, so v alone cannot reconstruct it exactly.
             # checkpoints hold true-row-order arrays — they must be
             # resumable under any mesh/layout permutation
-            checkpointer.save(done, {"u": _host_global(u)[u_lay.pos],
-                                     "v": _host_global(v)[i_lay.pos],
-                                     "it": np.int64(done),
-                                     "fp": np.uint64(fp)})
-    if u is None:
-        # checkpoint was already at the final iteration
-        u = u_restored if u_restored is not None else jax.jit(
-            lambda bk, vv: _solve_side(bk, u_lay, vv, kw=dict(
-                lambda_=config.lambda_, implicit=config.implicit_prefs,
-                alpha=config.alpha, rank=rank,
-                compute_dtype=config.compute_dtype, solver=config.solver,
-                cg_iters=_resolve_cg_iters(
-                    config.cg_iters, config.implicit_prefs))))(u_bk, v)
-    u.block_until_ready()
-    log.info("ALS done: %d iters, U %s, V %s", config.iterations, (nu, rank), (ni, rank))
-
-    return ALSModel(
-        user_factors=_host_global(u)[u_lay.pos],
-        item_factors=_host_global(v)[i_lay.pos],
-        user_ids=ratings.user_ids,
-        item_ids=ratings.item_ids,
-        config=config,
-    )
+            with span("train.als.checkpoint", t0=sp.t1,
+                      step_done=done) as sp:
+                checkpointer.save(done, {"u": _host_global(u)[u_lay.pos],
+                                         "v": _host_global(v)[i_lay.pos],
+                                         "it": np.int64(done),
+                                         "fp": np.uint64(fp)})
+    with span("train.als.final_pull", sink=_note_phase, t0=sp.t1):
+        if u is None:
+            # checkpoint was already at the final iteration
+            u = u_restored if u_restored is not None else jax.jit(
+                lambda bk, vv: _solve_side(bk, u_lay, vv, kw=dict(
+                    lambda_=config.lambda_, implicit=config.implicit_prefs,
+                    alpha=config.alpha, rank=rank,
+                    compute_dtype=config.compute_dtype, solver=config.solver,
+                    cg_iters=_resolve_cg_iters(
+                        config.cg_iters, config.implicit_prefs))))(u_bk, v)
+        u.block_until_ready()
+        log.info("ALS done: %d iters, U %s, V %s", config.iterations,
+                 (nu, rank), (ni, rank))
+        return ALSModel(
+            user_factors=_host_global(u)[u_lay.pos],
+            item_factors=_host_global(v)[i_lay.pos],
+            user_ids=ratings.user_ids,
+            item_ids=ratings.item_ids,
+            config=config,
+        )
 
 
 #: ALSConfig fields a grid must share — everything that shapes the layout,

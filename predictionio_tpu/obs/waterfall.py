@@ -26,6 +26,12 @@ module-level :func:`mark_stage`, which resolves the ambient sink from a
 contextvar (copied into ``asyncio.to_thread`` workers, so the fallback
 serve path attributes correctly without plumbing).
 
+The batch-shared stages are marked by :func:`stage_span`: the span
+``pio.serve.<stage>`` (``obs/trace.py``) wraps the block where the
+stage's work happens, so a profiler capture shows it by name on the
+dispatch thread, and the clock reading the span takes at its end is the
+stage's ``mark``.
+
 The batched path is two-phase: the request's own waterfall marks
 ``admission`` at submit and receives ``queue_wait`` when its batch is
 cut; the batch-shared stages (formation, host assembly, device dispatch/
@@ -53,11 +59,13 @@ of the invariant.
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from contextvars import ContextVar
 
 from .metrics import METRICS
+from .trace import span
 
 __all__ = [
     "STAGES",
@@ -66,6 +74,7 @@ __all__ = [
     "Waterfall",
     "BatchClock",
     "mark_stage",
+    "stage_span",
     "set_stage_sink",
     "reset_stage_sink",
     "current_sink",
@@ -255,6 +264,25 @@ def mark_stage(stage: str) -> None:
     sink = _SINK.get()
     if sink is not None:
         sink.mark(stage)
+
+
+def _mark_span_end(name: str, _t0: float, t1: float) -> None:
+    """Span sink: the reading a stage's span took at its end is the
+    stage's mark on the ambient clock."""
+    sink = _SINK.get()
+    if sink is not None:
+        sink.mark(name.rpartition(".")[2], t1)
+
+
+def stage_span(stage: str, **facts) -> span:
+    """The span ``pio.serve.<stage>`` around the block where a stage's
+    work happens, whether or not a request is being attributed; its end
+    is ``mark_stage(stage)``, on the same clock reading. The stage keeps
+    its meaning: all the time since the previous mark, of which the
+    block is the last part. One span a batch, so its log line is
+    debug-level: ``serve.dispatch`` already tells the batch at info."""
+    return span("serve." + stage, sink=_mark_span_end, level=logging.DEBUG,
+                **facts)
 
 
 # ---------------------------------------------------------------------------

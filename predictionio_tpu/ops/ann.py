@@ -56,7 +56,6 @@ import time
 import numpy as np
 
 from ..obs.metrics import METRICS
-from ..obs.waterfall import mark_stage
 from ..workflow.faults import FAULTS
 from .retrieval import (EXEC_CACHE, PACKED_IDX_LIMIT, _RETRIEVER_TOKENS,
                         _dispatch_topk, _query_shapes, _resolve_topk_mode,
@@ -460,9 +459,9 @@ class AnnRetriever:
             return self._exact.topk(queries, k)
         _M_QUERIES.inc(mode="ann")
         # probe planning (nprobe calibration, cell cover) is host-side
-        # assembly work in the stage waterfall; _dispatch_topk then
-        # splits the invoke into dispatch/compute/scatter
-        mark_stage("host_assembly")
+        # assembly work in the stage waterfall: _dispatch_topk's own
+        # host_assembly mark takes all the time since the previous mark,
+        # then splits the invoke into dispatch/compute/scatter
 
         def invoke(qp, k_pad_):
             call, packed = self._build_call(qp.shape[0], k_pad_, eff)

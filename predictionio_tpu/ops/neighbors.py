@@ -30,6 +30,7 @@ import math
 import numpy as np
 
 from .. import native
+from ..obs import trace
 
 __all__ = [
     "NeighborBlocks", "SideLayout", "TierMeta", "build_bilinear_layout",
@@ -323,64 +324,72 @@ def _build_side(plan: _SidePlan, rows, cols_slots, vals, *, zero_other: int,
     tier_of_row = np.zeros(num_rows, np.int32)
     for t, (_tier_d, row_idx) in enumerate(plan.tiers):
         tier_of_row[row_idx] = t + 1
-    tcode = tier_of_row[rows]
-    order_t = _stable_argsort_bounded(tcode, n_tiers + 1)
-    # tier boundaries from the histogram — searchsorted with sorter=
-    # walks the permutation indirection and measured ~6 s at 100M entries
-    bounds = np.zeros(n_tiers + 2, np.int64)
-    np.cumsum(np.bincount(tcode, minlength=n_tiers + 1), out=bounds[1:])
+    with trace.span("train.als.layout.tier_sort", entries=len(rows)):
+        tcode = tier_of_row[rows]
+        order_t = _stable_argsort_bounded(tcode, n_tiers + 1)
+        # tier boundaries from the histogram — searchsorted with sorter=
+        # walks the permutation indirection and measured ~6 s at 100M
+        # entries
+        bounds = np.zeros(n_tiers + 2, np.int64)
+        np.cumsum(np.bincount(tcode, minlength=n_tiers + 1), out=bounds[1:])
 
     remap = np.empty(num_rows, np.int64)
     for t, ((tier_d, row_idx), br) in enumerate(
             zip(plan.tiers, plan.tier_block_rows)):
-        sl = order_t[bounds[t + 1]:bounds[t + 2]]
-        remap[row_idx] = np.arange(len(row_idx))
-        b = build_neighbor_blocks(
-            remap[rows[sl]], cols_slots[sl], vals[sl],
-            len(row_idx), block_rows=br, degree_cap=tier_d,
-            pad_id=zero_other, seed=seed,
-        )
+        with trace.span("train.als.layout.tier_blocks", tier=tier_d,
+                  rows=len(row_idx)):
+            sl = order_t[bounds[t + 1]:bounds[t + 2]]
+            remap[row_idx] = np.arange(len(row_idx))
+            b = build_neighbor_blocks(
+                remap[rows[sl]], cols_slots[sl], vals[sl],
+                len(row_idx), block_rows=br, degree_cap=tier_d,
+                pad_id=zero_other, seed=seed,
+            )
         buckets.append(b)
         metas.append(TierMeta(span=b.padded_rows))
 
     if plan.chunks:
-        hv = order_t[bounds[0]:bounds[1]]  # all chunked-class entries
-        rows_h, cols_h, vals_h = rows[hv], cols_slots[hv], vals[hv]
-        counts = np.bincount(rows_h, minlength=num_rows)
-        order = _stable_argsort_bounded(rows_h, num_rows - 1)
-        starts = np.zeros(num_rows + 1, np.int64)
-        np.cumsum(counts, out=starts[1:])
-        rs = rows_h[order]
-        pos_in = np.arange(len(rows_h), dtype=np.int64) - starts[rs]
-        cols_o, vals_o = cols_h[order], vals_h[order]
-        k_full = np.zeros(num_rows, np.int64)
-        hv_base = np.full(num_rows, -1, np.int64)
-        for cc in plan.chunks:
-            k_full[cc.owners] = cc.k
-            hv_base[cc.owners] = np.concatenate([[0], np.cumsum(cc.k[:-1])])
-            sel = hv_base[rs] >= 0
-            # balanced chunk of each entry: position p of d entries split
-            # into k chunks lands in chunk p*k//d (sizes differ by at most
-            # 1, so every chunk fits this width class)
-            vrow = (hv_base[rs[sel]]
-                    + (pos_in[sel] * k_full[rs[sel]]) // counts[rs[sel]])
-            n_hv = int(cc.k.sum())
-            br = _block_rows_for(cc.width, gather_budget, n_hv)
-            b = build_neighbor_blocks(
-                vrow, cols_o[sel], vals_o[sel], n_hv, block_rows=br,
-                degree_cap=cc.width, pad_id=zero_other, seed=seed,
-            )
-            # seg: block row (chunk) -> owner's local slot, sorted
-            # ascending; block padding rows map to the LAST local slot
-            # (their partial equations are exactly zero, and a trailing
-            # index keeps the sequence sorted for segment_sum's fast path)
-            seg = np.full(b.padded_rows, cc.span - 1, np.int32)
-            seg[:n_hv] = np.repeat(
-                np.arange(len(cc.owners), dtype=np.int32), cc.k)
-            buckets.append(b)
-            metas.append(TierMeta(span=cc.span, seg=seg))
-            k_full[cc.owners] = 0
-            hv_base[cc.owners] = -1
+        # rows heavier than the chunk cap: a second sort of their entries
+        # by row, then one block build a width class
+        with trace.span("train.als.layout.chunked_rows",
+                        classes=len(plan.chunks)):
+            hv = order_t[bounds[0]:bounds[1]]  # all chunked-class entries
+            rows_h, cols_h, vals_h = rows[hv], cols_slots[hv], vals[hv]
+            counts = np.bincount(rows_h, minlength=num_rows)
+            order = _stable_argsort_bounded(rows_h, num_rows - 1)
+            starts = np.zeros(num_rows + 1, np.int64)
+            np.cumsum(counts, out=starts[1:])
+            rs = rows_h[order]
+            pos_in = np.arange(len(rows_h), dtype=np.int64) - starts[rs]
+            cols_o, vals_o = cols_h[order], vals_h[order]
+            k_full = np.zeros(num_rows, np.int64)
+            hv_base = np.full(num_rows, -1, np.int64)
+            for cc in plan.chunks:
+                k_full[cc.owners] = cc.k
+                hv_base[cc.owners] = np.concatenate([[0], np.cumsum(cc.k[:-1])])
+                sel = hv_base[rs] >= 0
+                # balanced chunk of each entry: position p of d entries split
+                # into k chunks lands in chunk p*k//d (sizes differ by at most
+                # 1, so every chunk fits this width class)
+                vrow = (hv_base[rs[sel]]
+                        + (pos_in[sel] * k_full[rs[sel]]) // counts[rs[sel]])
+                n_hv = int(cc.k.sum())
+                br = _block_rows_for(cc.width, gather_budget, n_hv)
+                b = build_neighbor_blocks(
+                    vrow, cols_o[sel], vals_o[sel], n_hv, block_rows=br,
+                    degree_cap=cc.width, pad_id=zero_other, seed=seed,
+                )
+                # seg: block row (chunk) -> owner's local slot, sorted
+                # ascending; block padding rows map to the LAST local slot
+                # (their partial equations are exactly zero, and a trailing
+                # index keeps the sequence sorted for segment_sum's fast path)
+                seg = np.full(b.padded_rows, cc.span - 1, np.int32)
+                seg[:n_hv] = np.repeat(
+                    np.arange(len(cc.owners), dtype=np.int32), cc.k)
+                buckets.append(b)
+                metas.append(TierMeta(span=cc.span, seg=seg))
+                k_full[cc.owners] = 0
+                hv_base[cc.owners] = -1
 
     return SideLayout(buckets=buckets, metas=metas, slots=plan.slots,
                       pos=plan.pos, zero_slot=plan.zero_slot)
@@ -399,6 +408,7 @@ def build_bilinear_layout(
     chunk_cap: int | None = 2048,
     merge_budget: int | str = "auto",
     align: int = 8,
+    sink=None,
 ) -> tuple[SideLayout, SideLayout]:
     """Both sides of the ALS layout, ALX-style density-grouped and
     PERMUTED so the training step needs zero scatters:
@@ -422,22 +432,30 @@ def build_bilinear_layout(
     Replaces the factor-block shuffle MLlib ALS performs every iteration
     (reference examples/.../ALSAlgorithm.scala:96-154): layout is computed
     once on host, then stays device-resident for every iteration.
+
+    ``sink`` is handed the three phases' spans (``train.als.layout.plan``,
+    ``.user``, ``.item``; obs/trace.py), for a caller that keeps their
+    seconds.
     """
-    u_idx = np.asarray(u_idx, np.int64)
-    i_idx = np.asarray(i_idx, np.int64)
-    nnz = len(u_idx)
-    counts_u = np.bincount(u_idx, minlength=num_users) if nnz else np.zeros(num_users, np.int64)
-    counts_i = np.bincount(i_idx, minlength=num_items) if nnz else np.zeros(num_items, np.int64)
-    kw = dict(tiers=tiers, gather_budget=gather_budget, chunk_cap=chunk_cap,
-              merge_budget=merge_budget, nnz=nnz, align=align)
-    plan_u = _plan_side(counts_u, **kw)
-    plan_i = _plan_side(counts_i, **kw)
-    lay_u = _build_side(plan_u, u_idx, plan_i.pos[i_idx], vals,
-                        zero_other=plan_i.zero_slot,
-                        gather_budget=gather_budget, seed=seed)
-    lay_i = _build_side(plan_i, i_idx, plan_u.pos[u_idx], vals,
-                        zero_other=plan_u.zero_slot,
-                        gather_budget=gather_budget, seed=seed)
+    with trace.span("train.als.layout.plan", sink=sink) as s:
+        u_idx = np.asarray(u_idx, np.int64)
+        i_idx = np.asarray(i_idx, np.int64)
+        nnz = len(u_idx)
+        counts_u = np.bincount(u_idx, minlength=num_users) if nnz else np.zeros(num_users, np.int64)
+        counts_i = np.bincount(i_idx, minlength=num_items) if nnz else np.zeros(num_items, np.int64)
+        kw = dict(tiers=tiers, gather_budget=gather_budget,
+                  chunk_cap=chunk_cap, merge_budget=merge_budget, nnz=nnz,
+                  align=align)
+        plan_u = _plan_side(counts_u, **kw)
+        plan_i = _plan_side(counts_i, **kw)
+    with trace.span("train.als.layout.user", sink=sink, t0=s.t1) as s:
+        lay_u = _build_side(plan_u, u_idx, plan_i.pos[i_idx], vals,
+                            zero_other=plan_i.zero_slot,
+                            gather_budget=gather_budget, seed=seed)
+    with trace.span("train.als.layout.item", sink=sink, t0=s.t1):
+        lay_i = _build_side(plan_i, i_idx, plan_u.pos[u_idx], vals,
+                            zero_other=plan_u.zero_slot,
+                            gather_budget=gather_budget, seed=seed)
     return lay_u, lay_i
 
 

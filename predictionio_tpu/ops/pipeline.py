@@ -65,7 +65,9 @@ import numpy as np
 
 from ..obs.device import LEDGER
 from ..obs.metrics import METRICS
-from ..obs.waterfall import mark_stage, stage_sink_active
+from ..obs.startup import STARTUP
+from ..obs.trace import span
+from ..obs.waterfall import stage_span
 from ..workflow.faults import FAULTS
 from .retrieval import (
     EXEC_CACHE,
@@ -133,13 +135,33 @@ class _SharedState:
     locks guarding them. Sharing by reference keeps the metrics and the
     double buffers continuous across delta epochs."""
 
-    def __init__(self):
+    def __init__(self, clock=time.perf_counter):
         self.cond = threading.Condition()
         self.staging: dict[int, list[np.ndarray]] = {}
         self.in_device = 0       # dispatches currently in their device step
         self.dispatches = 0
         self.overlapped = 0
         self.transient = 0       # dispatches that fell back off the pool
+        # how well the device is fed, integrated at each change of
+        # in_device: seconds with nothing in its device step, and the
+        # integral of in_device over time (batch-seconds), since attach
+        self.clock = clock
+        self.t_attach = self.t_last = clock()
+        self.idle_s = 0.0
+        self.depth_s = 0.0
+
+    def advance(self, delta: int = 0) -> float:
+        """Account the time since the last change at the depth it was
+        spent at, then change ``in_device`` by ``delta``. Called with
+        ``cond`` held; one clock read. Returns that reading."""
+        now = self.clock()
+        dt = now - self.t_last
+        if self.in_device == 0:
+            self.idle_s += dt
+        self.depth_s += self.in_device * dt
+        self.t_last = now
+        self.in_device += delta
+        return now
 
 
 class ServingPipeline:
@@ -306,19 +328,22 @@ class ServingPipeline:
         b_pad, k_pad = _query_shapes(b, k_eff, n_total)
         LEDGER.record_padding_waste(b, b_pad)
         st = self._state
-        buf, transient = self._acquire_staging(b_pad)
+        facts = {"rows": b, "b_pad": b_pad}
+        buf, transient = None, True
         try:
-            with st.cond:
-                overlapped = st.in_device > 0
-            self._fill_staging(buf, rows)
-            # the filled buffer is handed to the device step: the
-            # double-buffer swap point (chaos site; a hang here holds
-            # ONE pinned buffer and the watchdog 504s the batch)
-            FAULTS.fire("pipeline.swap")
+            with stage_span("host_assembly", **facts):
+                buf, transient = self._acquire_staging(b_pad)
+                with st.cond:
+                    overlapped = st.in_device > 0
+                self._fill_staging(buf, rows)
+                # the filled buffer is handed to the device step: the
+                # double-buffer swap point (chaos site; a hang here holds
+                # ONE pinned buffer and the watchdog 504s the batch)
+                FAULTS.fire("pipeline.swap")
             if self._fused:
-                out = self._dispatch_fused(buf, b, b_pad, k_eff, k_pad)
+                out = self._dispatch_fused(buf, b, k_eff, k_pad, facts)
             else:
-                out = self._dispatch_gather(buf, b, b_pad, k)
+                out = self._dispatch_gather(buf, b, k, facts)
             with st.cond:
                 st.dispatches += 1
                 st.overlapped += 1 if overlapped else 0
@@ -326,43 +351,41 @@ class ServingPipeline:
             _M_OVERLAP.set(ratio)
             return out
         finally:
-            self._release_staging(b_pad, buf, transient)
+            if buf is not None:
+                self._release_staging(b_pad, buf, transient)
 
-    def _dispatch_fused(self, buf, b, b_pad, k_eff, k_pad):
+    def _dispatch_fused(self, buf, b, k_eff, k_pad, facts):
         import jax
 
-        attributing = stage_sink_active()
-        if attributing:
-            mark_stage("host_assembly")
-        call, is_packed = self._exec_fused(b_pad, k_pad)
         st = self._state
-        with st.cond:
-            st.in_device += 1
+        in_device = False
         try:
-            out = call(buf, self._qtab, self._retriever._items)
-            if self._donate:
-                _M_DONATED.inc()
-            if attributing:
-                mark_stage("device_dispatch")
-            jax.block_until_ready(out)
-            if attributing:
-                mark_stage("device_compute")
+            with stage_span("device_dispatch", **facts):
+                call, is_packed = self._exec_fused(facts["b_pad"], k_pad)
+                with st.cond:
+                    st.advance(+1)
+                in_device = True
+                out = call(buf, self._qtab, self._retriever._items)
+                if self._donate:
+                    _M_DONATED.inc()
+            with stage_span("device_compute", **facts):
+                jax.block_until_ready(out)
         finally:
-            with st.cond:
-                st.in_device -= 1
-        if is_packed:
-            host = np.asarray(out)  # packed: ONE pull
-            vals = host[:b, :k_eff]
-            idx = host[:b, k_pad:k_pad + k_eff].astype(np.int32)
-        else:
-            vals, idx = out
-            vals = np.asarray(vals)[:b, :k_eff]
-            idx = np.asarray(idx)[:b, :k_eff]
-        if attributing:
-            mark_stage("result_scatter")
+            if in_device:
+                with st.cond:
+                    st.advance(-1)
+        with stage_span("result_scatter", **facts):
+            if is_packed:
+                host = np.asarray(out)  # packed: ONE pull
+                vals = host[:b, :k_eff]
+                idx = host[:b, k_pad:k_pad + k_eff].astype(np.int32)
+            else:
+                vals, idx = out
+                vals = np.asarray(vals)[:b, :k_eff]
+                idx = np.asarray(idx)[:b, :k_eff]
         return vals, idx
 
-    def _dispatch_gather(self, buf, b, b_pad, k):
+    def _dispatch_gather(self, buf, b, k, facts):
         """ANN / sharded: gather the query matrix on device, pull it,
         and hand it to the retriever's own compiled programs. The
         gathered rows are bit-identical to the host gather the legacy
@@ -370,10 +393,10 @@ class ServingPipeline:
         policy) are untouched."""
         import jax
 
-        call = self._exec_gather(b_pad)
+        call = self._exec_gather(facts["b_pad"])
         st = self._state
         with st.cond:
-            st.in_device += 1
+            st.advance(+1)
         try:
             qdev = call(buf, self._qtab)
             if self._donate:
@@ -381,9 +404,9 @@ class ServingPipeline:
             jax.block_until_ready(qdev)
         finally:
             with st.cond:
-                st.in_device -= 1
-        # the retriever's _dispatch_topk re-fences the stage waterfall
-        # and re-pads lanes (a no-op: the gather already padded them)
+                st.advance(-1)
+        # the retriever's _dispatch_topk fences the stage waterfall
+        # itself and re-pads lanes (a no-op: the gather already padded)
         return self._retriever.topk(np.asarray(qdev)[:b], k)
 
     # -- lifecycle -----------------------------------------------------
@@ -407,12 +430,16 @@ class ServingPipeline:
                     continue
                 seen.add((b_pad, k_pad))
                 if self._fused:
-                    self._exec_fused(b_pad, k_pad, pin=True)
+                    with span("deploy.prewarm.program", sink=STARTUP.phase,
+                              kind="fused", b_pad=b_pad, k_pad=k_pad):
+                        self._exec_fused(b_pad, k_pad, pin=True)
                     warmed.append(("pipeline", "fused", b_pad, k_pad))
                 elif b_pad not in gathered:
                     # the gather program is k-independent: one per b_pad
                     gathered.add(b_pad)
-                    self._exec_gather(b_pad, pin=True)
+                    with span("deploy.prewarm.program", sink=STARTUP.phase,
+                              kind="gather", b_pad=b_pad):
+                        self._exec_gather(b_pad, pin=True)
                     warmed.append(("pipeline", "gather", b_pad))
                 with self._state.cond:
                     self._state.staging.setdefault(b_pad, [
@@ -459,6 +486,7 @@ class ServingPipeline:
         st = self._state
         with st.cond:
             staged = {int(b): len(p) for b, p in st.staging.items()}
+            now = st.advance()
             return {
                 "mode": "fused" if self._fused else "gather",
                 "rows": self.n_rows,
@@ -469,4 +497,10 @@ class ServingPipeline:
                 "transientStaging": st.transient,
                 "stagingFree": staged,
                 "donation": self._donate,
+                # deviceIdleSeconds + (seconds with a batch in its
+                # device step) = clockSeconds; inDeviceSeconds over
+                # clockSeconds is the mean number of batches in flight
+                "deviceIdleSeconds": st.idle_s,
+                "inDeviceSeconds": st.depth_s,
+                "clockSeconds": now - st.t_attach,
             }

@@ -31,7 +31,9 @@ import numpy as np
 
 from ..obs.device import LEDGER
 from ..obs.metrics import METRICS
-from ..obs.waterfall import mark_stage, stage_sink_active
+from ..obs.startup import STARTUP
+from ..obs.trace import span
+from ..obs.waterfall import stage_sink_active, stage_span
 from ..workflow.faults import FAULTS
 
 __all__ = ["topk_scores", "DeviceRetriever", "ShardedDeviceRetriever",
@@ -424,37 +426,36 @@ def _dispatch_topk(q: np.ndarray, n_total: int, k: int, invoke):
         return (empty_v[0], empty_i[0]) if single else (empty_v, empty_i)
     b_orig = q.shape[0]
     b_pad, k_pad = _query_shapes(q.shape[0], k_eff, n_total)
-    LEDGER.record_padding_waste(b_orig, b_pad)
-    q = _pad_to(q, b_pad, 0)
-    q = _pad_to(q, 128, 1)
-    # Stage waterfall (obs/waterfall.py): when a serve request is being
-    # attributed, split the invoke into dispatch (the call returning an
-    # async device handle) and compute (block_until_ready delta). The
-    # fence is conditional on an active sink so un-attributed callers
-    # (training, bench device-spin) keep the async pipeline untouched.
-    attributing = stage_sink_active()
-    if attributing:
-        mark_stage("host_assembly")
-    out, is_packed = invoke(q, k_pad)
-    if attributing:
-        mark_stage("device_dispatch")
-        try:
-            import jax
+    facts = {"rows": b_orig, "b_pad": b_pad}
+    # Stage spans (obs/waterfall.py): each block is a named event in a
+    # profiler capture, and its end is the stage's mark when a serve
+    # request is being attributed. Only then is the invoke split into
+    # dispatch (the call returning an async device handle) and compute
+    # (block_until_ready): un-attributed callers (training, bench
+    # device-spin) keep the async pipeline untouched.
+    with stage_span("host_assembly", **facts):
+        LEDGER.record_padding_waste(b_orig, b_pad)
+        q = _pad_to(q, b_pad, 0)
+        q = _pad_to(q, 128, 1)
+    with stage_span("device_dispatch", **facts):
+        out, is_packed = invoke(q, k_pad)
+    if stage_sink_active():
+        with stage_span("device_compute", **facts):
+            try:
+                import jax
 
-            jax.block_until_ready(out)
-        except Exception:
-            pass  # numpy results / non-jax invokes: nothing to fence
-        mark_stage("device_compute")
-    if is_packed:
-        host = np.asarray(out)  # packed: ONE pull
-        vals = host[:b_orig, :k_eff]
-        idx = host[:b_orig, k_pad:k_pad + k_eff].astype(np.int32)
-    else:
-        vals, idx = out
-        vals = np.asarray(vals)[:b_orig, :k_eff]
-        idx = np.asarray(idx)[:b_orig, :k_eff]
-    if attributing:
-        mark_stage("result_scatter")
+                jax.block_until_ready(out)
+            except Exception:
+                pass  # numpy results / non-jax invokes: nothing to fence
+    with stage_span("result_scatter", **facts):
+        if is_packed:
+            host = np.asarray(out)  # packed: ONE pull
+            vals = host[:b_orig, :k_eff]
+            idx = host[:b_orig, k_pad:k_pad + k_eff].astype(np.int32)
+        else:
+            vals, idx = out
+            vals = np.asarray(vals)[:b_orig, :k_eff]
+            idx = np.asarray(idx)[:b_orig, :k_eff]
     return (vals[0], idx[0]) if single else (vals, idx)
 
 
@@ -517,10 +518,18 @@ class DeviceRetriever:
         import jax.numpy as jnp
 
         self._mode = _resolve_topk_mode(interpret)
-        it = np.asarray(items, dtype=np.float32)
-        self.n_total, self.dim = it.shape
-        it, self._tile_n = _pad_items(it, self.n_total, tile_n)
-        self._items = jax.device_put(jnp.asarray(it))
+        with span("deploy.attach_retriever.catalog_pad",
+                  sink=STARTUP.phase) as s:
+            it = np.asarray(items, dtype=np.float32)
+            self.n_total, self.dim = it.shape
+            it, self._tile_n = _pad_items(it, self.n_total, tile_n)
+            s["bytes"] = int(it.nbytes)
+        with span("deploy.attach_retriever.catalog_upload",
+                  sink=STARTUP.phase, bytes=int(it.nbytes)):
+            # waited for, so that the upload's seconds stand here and not
+            # in whatever first needs the catalog
+            self._items = jax.block_until_ready(
+                jax.device_put(jnp.asarray(it)))
 
     @property
     def kernel(self) -> str:
@@ -561,15 +570,17 @@ class DeviceRetriever:
                 b_pad, k_pad = _query_shapes(b, k_eff, self.n_total)
                 if (b_pad, k_pad) in warmed:
                     continue
-                if self._mode == "xla":
-                    _build_xla_call(b_pad, self._items.shape[1],
+                with span("deploy.prewarm.program", sink=STARTUP.phase,
+                          kind="topk", b_pad=b_pad, k_pad=k_pad):
+                    if self._mode == "xla":
+                        _build_xla_call(b_pad, self._items.shape[1],
+                                        self._items.shape[0], self.n_total,
+                                        k_pad, pin=True)
+                    else:
+                        _build_call(b_pad, self._items.shape[1],
                                     self._items.shape[0], self.n_total,
-                                    k_pad, pin=True)
-                else:
-                    _build_call(b_pad, self._items.shape[1],
-                                self._items.shape[0], self.n_total, k_pad,
-                                self._tile_n, self._mode == "interpret",
-                                pin=True)
+                                    k_pad, self._tile_n,
+                                    self._mode == "interpret", pin=True)
                 warmed.append((b_pad, k_pad))
         return warmed
 
@@ -795,12 +806,22 @@ class RetrievalServingMixin:
     _retrieval_attr = "item_factors"
     _retrieval_ids_attr = "item_ids"
 
+    def _catalog_ids_inverse(self):
+        """Row -> item id. The id map builds its inverse at first use,
+        which is the first answer a deployed model gives (8-26 s at 15.2
+        million items): that build is ``pio.serve.id_map_inverse``."""
+        ids = getattr(self, self._retrieval_ids_attr)
+        if getattr(ids, "inverse_built", True):
+            return ids.inverse
+        with span("serve.id_map_inverse", sink=STARTUP.phase,
+                  entries=len(ids)):
+            return ids.inverse
+
     def top_n_from_catalog(self, query_vec, num: int) -> list[tuple[str, float]]:
         """[(id, score)] top-N of catalog·query: through the device
         retriever when attached, else a host argpartition. The single
         home of this logic for every retrieval-serving model."""
-        ids = getattr(self, self._retrieval_ids_attr)
-        inv = ids.inverse
+        inv = self._catalog_ids_inverse()
         via_device = self._retriever_topk(query_vec, num, inv)
         if via_device is not None:
             return via_device
@@ -819,8 +840,7 @@ class RetrievalServingMixin:
         q = np.asarray(query_mat, np.float32)
         if q.ndim != 2 or len(q) == 0:
             return []
-        ids = getattr(self, self._retrieval_ids_attr)
-        inv = ids.inverse
+        inv = self._catalog_ids_inverse()
         retriever = getattr(self, "_retriever", None)
         if retriever is not None:
             vals, idx = retriever.topk(q, num)
@@ -865,7 +885,7 @@ class RetrievalServingMixin:
                 return out
             kmax = max(max(nums[j] for j in known), 0)
             vals, idx = pipe.topk_rows(rows[known], kmax)
-            inv = getattr(self, self._retrieval_ids_attr).inverse
+            inv = self._catalog_ids_inverse()
             for j, vr, ir in zip(known.tolist(), vals, idx):
                 rec = [(inv[int(i)], float(v))
                        for v, i in zip(vr, ir) if i >= 0]

@@ -39,6 +39,12 @@ class BiMap(Generic[K, V]):
         self._i = _inverse
 
     @property
+    def inverse_built(self) -> bool:
+        """Whether ``inverse`` is there already or is built at its next
+        use (a dict of as many entries: seconds at millions)."""
+        return self._i is not None
+
+    @property
     def inverse(self) -> "BiMap[V, K]":
         if self._i is None:
             self._i = BiMap({v: k for k, v in self._m.items()}, _inverse=self)

@@ -338,6 +338,17 @@ def _enable_compile_cache() -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
+def _first_look_at_devices() -> None:
+    """Where `pio train` and `pio deploy` first ask JAX for their
+    devices, so that the seconds a process needs from its start to the
+    chip (12-21 s on the v5e) are a number of the program's own:
+    ``pio.process.to_device``."""
+    from ..obs.startup import STARTUP
+
+    seconds = STARTUP.process_to_device()
+    log.info("devices after %.3f s of process", seconds)
+
+
 def cmd_train(args) -> int:
     from ..workflow import Context, WorkflowParams, run_train
 
@@ -355,6 +366,7 @@ def cmd_train(args) -> int:
             num_processes=args.num_processes,
             process_id=args.process_id,
         )
+    _first_look_at_devices()
     engine_dir = Path(args.engine_dir)
     _verify_template_min_version(engine_dir)
     variant = _load_variant(engine_dir, args.engine_json)
@@ -622,9 +634,13 @@ def cmd_deploy(args) -> int:
     if args.variant_of:
         return _deploy_variant(args)
     _enable_compile_cache()
+    _first_look_at_devices()
+    from ..obs.startup import STARTUP
+    from ..obs.trace import span
     from ..workflow.create_server import run_engine_server
 
-    engine_dir, engine, inst = _resolve_engine_instance(args)
+    with span("deploy.resolve_engine", sink=STARTUP.phase):
+        engine_dir, engine, inst = _resolve_engine_instance(args)
     run_engine_server(
         engine,
         inst,

@@ -24,6 +24,8 @@ from ..controller.engine import Engine, TrainResult
 from ..controller.evaluation import Evaluation, MetricEvaluator, MetricEvaluatorResult
 from ..controller.params import EngineParams, params_to_json
 from ..obs.device import device_identity
+from ..obs.startup import STARTUP
+from ..obs.trace import span
 from ..obs.training import TRAINING
 from ..storage import EngineInstance, EvaluationInstance, Model, Storage
 from .context import Context
@@ -328,20 +330,23 @@ def run_train(
                 cur, last_heartbeat=iso, attempt=attempt, **extra))
 
     def _body() -> tuple[int, int]:
-        from .tracing import maybe_profile, phase_report, reset_phases
+        from .tracing import (maybe_profile, phase_report, phase_timer,
+                              reset_phases)
 
         # each supervised attempt re-runs every phase; without the reset
         # a retried run's persisted breakdown would double-count
         reset_phases(ctx)
         with maybe_profile(getattr(ctx, "profile_dir", None)):
             result = engine.train(ctx, engine_params)
-        log.info("training phases: %s", phase_report(ctx))
-        models = _persistable(result, instance_id)
-        blob = serialize_models(models)
+        with phase_timer(ctx, "persist.serialize"):
+            models = _persistable(result, instance_id)
+            blob = serialize_models(models)
         FAULTS.fire("train.persist")
-        Storage.get_models().insert(Model(
-            id=instance_id, models=blob,
-            checksum=Model.compute_checksum(blob)))
+        with phase_timer(ctx, "persist.put"):
+            Storage.get_models().insert(Model(
+                id=instance_id, models=blob,
+                checksum=Model.compute_checksum(blob)))
+        log.info("training phases: %s", phase_report(ctx))
         return len(models), len(blob)
 
     supervisor = TrainSupervisor(
@@ -481,16 +486,20 @@ def prepare_deploy(
     # before the fetch so a fallback-mode deploy quarantines this
     # instance exactly like a corrupt checksum would.
     FAULTS.fire("replica.blob_pull")
-    blob = Storage.get_models().get(instance.id)
+    with span("deploy.blob_read", sink=STARTUP.phase):
+        blob = Storage.get_models().get(instance.id)
     if blob is None:
         raise RuntimeError(f"no model blob for engine instance {instance.id}")
     if blob.checksum:  # pre-integrity blobs have no checksum to check
-        actual = Model.compute_checksum(blob.models)
+        with span("deploy.checksum", sink=STARTUP.phase,
+                  bytes=len(blob.models)):
+            actual = Model.compute_checksum(blob.models)
         if actual != blob.checksum:
             raise ModelIntegrityError(
                 f"model blob for engine instance {instance.id} is corrupt: "
                 f"stored checksum {blob.checksum} != computed {actual}")
-    stored = deserialize_models(blob.models, engine_dir=engine_dir)
+    with span("deploy.deserialize", sink=STARTUP.phase):
+        stored = deserialize_models(blob.models, engine_dir=engine_dir)
 
     models: list[Any] = []
     needs_retrain = any(isinstance(m, RetrainMarker) for m in stored)
@@ -508,7 +517,9 @@ def prepare_deploy(
                 # a library module, or (legacy/scoped) already registered
                 mod = sys.modules.get(m.module) or importlib.import_module(m.module)
             cls = getattr(mod, m.class_name)
-            models.append(cls.load(instance.id, algo.params, ctx))
+            with span("deploy.deserialize", sink=STARTUP.phase,
+                      model=m.class_name):
+                models.append(cls.load(instance.id, algo.params, ctx))
         elif isinstance(m, RetrainMarker):
             assert retrained is not None
             models.append(retrained.models[i])

@@ -70,9 +70,10 @@ from ..obs.metrics import METRICS
 from ..obs.replay import PROVENANCE_HEADER
 from ..obs.training import TRAINING
 from ..obs.slo import SloTracker, default_objectives
-from ..obs.trace import TRACE_HEADER, ensure_request_id, trace_event
+from ..obs.startup import STARTUP
+from ..obs.trace import TRACE_HEADER, ensure_request_id, span, trace_event
 from ..obs.waterfall import (Waterfall, mark_stage, reset_stage_sink,
-                             set_stage_sink, stage_summary)
+                             set_stage_sink, stage_span, stage_summary)
 from ..storage import EngineInstance, Storage
 from .admission import AdmissionController
 from .faults import FAULTS
@@ -217,7 +218,11 @@ class Deployed:
         import jax
 
         try:
-            blob = Storage.get_models().get(self.instance.id)
+            # the blob a second time (prepare_deploy let go of its own),
+            # for its checksum alone
+            with span("deploy.blob_read", sink=STARTUP.phase,
+                      reason="provenance"):
+                blob = Storage.get_models().get(self.instance.id)
             self.blob_sha = getattr(blob, "checksum", None)
         except Exception:  # noqa: BLE001 — provenance is best-effort
             self.blob_sha = None
@@ -263,7 +268,9 @@ class Deployed:
                 # nothing bound, /reload fails and the old bundle keeps
                 # serving. Host scoring is what serves where no
                 # retriever is configured, never what an exception picks
-                attach(*args, **kwargs)
+                with span("deploy.attach_retriever", sink=STARTUP.phase,
+                          model=type(model).__name__):
+                    attach(*args, **kwargs)
                 log.info(
                     "%s retriever attached to %s",
                     "ann" if mode == "ann"
@@ -278,7 +285,9 @@ class Deployed:
                            None) is None:
                     ap = None
                 if ap is not None:
-                    ap()
+                    with span("deploy.attach_pipeline", sink=STARTUP.phase,
+                              model=type(model).__name__):
+                        ap()
                     log.info("serving pipeline attached to %s (%s)",
                              type(model).__name__,
                              model._pipeline.stats()["mode"])
@@ -307,17 +316,20 @@ class Deployed:
                 b *= 2
             sizes = sorted(lattice)
         warmed_keys: list = []
-        for model in self.result.models:
-            for attr in ("_retriever", "_sim_retriever", "_pipeline"):
-                r = getattr(model, attr, None)
-                if r is None or not hasattr(r, "prewarm"):
-                    continue
-                # a program that does not compile here will not compile
-                # on the first query either: the failure is the deploy's
-                warmed = r.prewarm(batch_sizes=sizes)
-                warmed_keys.extend(warmed or ())
-                log.info("prewarmed %s.%s shapes %s",
-                         type(model).__name__, attr, warmed)
+        with span("deploy.prewarm", sink=STARTUP.phase,
+                  batch=self.prewarm_batch):
+            for model in self.result.models:
+                for attr in ("_retriever", "_sim_retriever", "_pipeline"):
+                    r = getattr(model, attr, None)
+                    if r is None or not hasattr(r, "prewarm"):
+                        continue
+                    # a program that does not compile here will not
+                    # compile on the first query either: the failure is
+                    # the deploy's
+                    warmed = r.prewarm(batch_sizes=sizes)
+                    warmed_keys.extend(warmed or ())
+                    log.info("prewarmed %s.%s shapes %s",
+                             type(model).__name__, attr, warmed)
         if warmed_keys:
             # one digest naming the compiled-program configuration this
             # bundle serves from (the warmed EXEC_CACHE keys carry
@@ -978,20 +990,20 @@ class EngineServer:
             per_algo.append(preds)
 
         outcomes: list[tuple[str, Any]] = []
-        for i in range(n):
-            if i in errors:
-                outcomes.append(("err", errors[i]))
-                continue
-            try:
-                preds = [pa[i] for pa in per_algo]
-                served = result.serving.serve(first_qs[i], preds)
-                outcomes.append(("ok", _to_jsonable(served)))
-            except Exception as e:  # noqa: BLE001
-                outcomes.append(("err", e))
         # serving blend + outcome packaging (and, for models with no
         # device retriever, the host predict itself — documented in
         # obs/waterfall.py) is result-scatter work
-        mark_stage("result_scatter")
+        with stage_span("result_scatter", rows=n):
+            for i in range(n):
+                if i in errors:
+                    outcomes.append(("err", errors[i]))
+                    continue
+                try:
+                    preds = [pa[i] for pa in per_algo]
+                    served = result.serving.serve(first_qs[i], preds)
+                    outcomes.append(("ok", _to_jsonable(served)))
+                except Exception as e:  # noqa: BLE001
+                    outcomes.append(("err", e))
 
         dt = time.perf_counter() - t0
         with self._stats_lock:
@@ -1413,6 +1425,9 @@ class EngineServer:
             # device this process holds, as JAX reports it
             "device": {**device_identity(), **LEDGER.snapshot()},
             "train": TRAINING.snapshot(),
+            # what this process spent before it was ready, phase by
+            # phase, with the host memory in use at each phase's end
+            "startup": STARTUP.snapshot(),
         }
 
 
@@ -1489,40 +1504,49 @@ async def handle_query(request: web.Request) -> web.Response:
                      {"message": "Server is draining; not accepting queries."},
                      503)
     try:
-        query_json = await request.json()
-    except (json.JSONDecodeError, UnicodeDecodeError):
+        # the body first (the await), then parse and admit as one
+        # synchronous span: a span never holds an await
+        body_text = await request.text()
+    except UnicodeDecodeError:
         return _done("bad_request", {"message": "Malformed JSON body."}, 400)
-    if not isinstance(query_json, dict):
-        return _done("bad_request",
-                     {"message": "Query must be a JSON object."}, 400)
-    # ISSUE 14: pick the serving variant — forced by header (replay,
-    # debugging; unknown names fail loud) or hashed on the entity id so
-    # a user sticks to one variant between weight changes
-    forced = request.headers.get(VARIANT_HEADER)
-    try:
-        entry, _how = primary.variants.route(
-            entity_key(query_json), forced=forced)
-    except KeyError:
-        return _done("bad_request",
-                     {"message": f"unknown variant {forced!r}"}, 400)
-    server = entry.server
-    if server.admission is not None:
-        # adaptive admission (ISSUE 6): shed at ingress with 429 +
-        # Retry-After before the request can pay the queue just to 504.
-        # Per-variant (ISSUE 14): an overloaded candidate sheds alone.
-        client_key = (request.query.get("accessKey")
-                      or request.headers.get("X-PIO-Access-Key")
-                      or (request.remote or "unknown"))
-        decision = server.admission.decide("serve", key=client_key)
-        server._update_brownout()
-        if not decision.admitted:
-            return _done("shed",
-                         {"message": f"overloaded; retry later "
-                                     f"({decision.reason})"},
-                         429, retry_after_s=decision.retry_after_s)
     # body parsed + admission decided: everything since ingress is the
-    # admission stage; the batcher (or fallback path) owns time from here
-    mark_stage("admission")
+    # admission stage, marked at the span's end; the batcher (or the
+    # fallback path) owns time from there
+    with stage_span("admission"):
+        try:
+            query_json = json.loads(body_text)
+        except json.JSONDecodeError:
+            return _done("bad_request", {"message": "Malformed JSON body."},
+                         400)
+        if not isinstance(query_json, dict):
+            return _done("bad_request",
+                         {"message": "Query must be a JSON object."}, 400)
+        # ISSUE 14: pick the serving variant — forced by header (replay,
+        # debugging; unknown names fail loud) or hashed on the entity id
+        # so a user sticks to one variant between weight changes
+        forced = request.headers.get(VARIANT_HEADER)
+        try:
+            entry, _how = primary.variants.route(
+                entity_key(query_json), forced=forced)
+        except KeyError:
+            return _done("bad_request",
+                         {"message": f"unknown variant {forced!r}"}, 400)
+        server = entry.server
+        if server.admission is not None:
+            # adaptive admission (ISSUE 6): shed at ingress with 429 +
+            # Retry-After before the request can pay the queue just to
+            # 504. Per-variant (ISSUE 14): an overloaded candidate sheds
+            # alone.
+            client_key = (request.query.get("accessKey")
+                          or request.headers.get("X-PIO-Access-Key")
+                          or (request.remote or "unknown"))
+            decision = server.admission.decide("serve", key=client_key)
+            server._update_brownout()
+            if not decision.admitted:
+                return _done("shed",
+                             {"message": f"overloaded; retry later "
+                                         f"({decision.reason})"},
+                             429, retry_after_s=decision.retry_after_s)
     try:
         eff_query = server.brownout_degrade(query_json)
         result = await server.dispatch_query(
@@ -2093,10 +2117,17 @@ def run_engine_server(
     server = EngineServer(engine, instance, defer_prewarm=prewarm_async,
                           **kwargs)
     prewarm_failed = threading.Event()
+    bound = threading.Event()
+
+    def _ready_if_bound_and_warm() -> None:
+        if bound.is_set() and not server.prewarming:
+            STARTUP.mark_ready()
+
     if prewarm_async:
         def _prewarm():
             try:
                 server.complete_prewarm()
+                _ready_if_bound_and_warm()
             except Exception:  # noqa: BLE001 — thread boundary
                 # the port is already bound, so fatal means: never
                 # ready, stop serving, exit non-zero below
@@ -2108,11 +2139,23 @@ def run_engine_server(
                          daemon=True).start()
     log.info("Engine server (instance %s) starting on %s:%d", instance.id, ip, port)
     for attempt in range(bind_retries + 1):
+        # run_app returns when the server stops, so this one span is
+        # entered and left by hand, on this thread: it ends where aiohttp
+        # would print its banner, the one call run_app makes once every
+        # site listens
+        binding = span("deploy.bind", sink=STARTUP.phase, port=port)
+
+        def _bound(*_banner, binding=binding) -> None:
+            binding.__exit__(None, None, None)
+            bound.set()
+            _ready_if_bound_and_warm()
+
         try:
+            binding.__enter__()
             # a fresh app per attempt: a failed bind runs the previous
             # app's cleanup hooks
             web.run_app(create_engine_server_app(server), host=ip,
-                        port=port, print=None)
+                        port=port, print=_bound)
             if prewarm_failed.is_set():
                 raise SystemExit("executable prewarm failed; see the "
                                  "traceback above")
@@ -2120,6 +2163,7 @@ def run_engine_server(
         except OSError as e:
             if e.errno != errno.EADDRINUSE:
                 raise
+            binding.__exit__(OSError, e, None)
             if attempt < bind_retries:
                 # the failed app already ran its shutdown hooks (drain);
                 # re-arm so the retry actually serves

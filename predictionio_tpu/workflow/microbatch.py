@@ -50,7 +50,7 @@ from ..obs.flight import FLIGHT
 from ..obs.metrics import METRICS
 from ..obs.trace import current_request_id, trace_event
 from ..obs.waterfall import (BatchClock, current_sink, reset_stage_sink,
-                             set_stage_sink)
+                             set_stage_sink, stage_span)
 from .faults import FAULTS
 
 log = logging.getLogger("predictionio_tpu.server")
@@ -417,16 +417,18 @@ class MicroBatcher:
         land on the batch clock, not on any one member's waterfall. The
         fault site fires BEFORE the first mark: a hang here shows up as
         stalled before any stage completed (stalledStage=batch_form)."""
-        FAULTS.fire("microbatch.dispatch")
-        token = None
-        if clock is not None:
-            token = set_stage_sink(clock)
-            clock.mark("batch_form")  # batch cut -> worker thread running
-        t0 = time.perf_counter()
+        token = set_stage_sink(clock) if clock is not None else None
         try:
-            return self.batch_fn(queries)
+            # batch cut -> worker thread running; the span is the
+            # worker's first block, the stage also the thread hop
+            with stage_span("batch_form", rows=len(queries)):
+                FAULTS.fire("microbatch.dispatch")
+            t0 = time.perf_counter()
+            try:
+                return self.batch_fn(queries)
+            finally:
+                _M_DEVICE.record(time.perf_counter() - t0)
         finally:
-            _M_DEVICE.record(time.perf_counter() - t0)
             if token is not None:
                 reset_stage_sink(token)
 

@@ -3,12 +3,16 @@
 The reference has no tracing beyond logging — Spark's UI is its implicit
 profiler (SURVEY.md §5). The TPU build surfaces the equivalents natively:
 
-- ``phase_timer``: wall-clock per pipeline phase (read/prepare/train-algo),
-  logged structured and accumulated on the Context so `pio train -v`
-  prints a phase breakdown at the end — the role of Spark's stage view.
-- ``maybe_profile``: wraps a region in ``jax.profiler.trace`` when a
-  trace directory is set (``pio train --profile-dir``); the output loads
-  in TensorBoard/XProf (device timelines, HLO cost analysis).
+- ``phase_timer``: wall-clock per pipeline phase (read/prepare/train-algo,
+  serialize/put), logged structured and accumulated on the Context so
+  `pio train -v` prints a phase breakdown at the end — the role of
+  Spark's stage view. Each phase is an ``obs.trace.span``, so a capture
+  shows it as ``pio.train.<phase>`` beside the device's lines.
+- ``maybe_profile``: captures a region with ``jax.profiler`` when a
+  trace directory is set (``pio train --profile-dir``, ``POST
+  /debug/profile``); the output loads in TensorBoard/XProf (device
+  timelines, HLO cost analysis) with the program's spans on the host's
+  lines.
 """
 
 from __future__ import annotations
@@ -16,7 +20,8 @@ from __future__ import annotations
 import contextlib
 import json
 import logging
-import time
+
+from ..obs.trace import span
 
 log = logging.getLogger("predictionio_tpu.workflow")
 
@@ -43,19 +48,16 @@ def phase_times_json(ctx) -> str:
     return json.dumps([[p, round(dt, 6)] for p, dt in times])
 
 
-@contextlib.contextmanager
-def phase_timer(ctx, phase: str):
+def phase_timer(ctx, phase: str) -> span:
     """Time one pipeline phase; record on ctx.phase_times + log."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
+    def record(_name: str, t0: float, t1: float) -> None:
         times = getattr(ctx, "phase_times", None)
         if times is None:
             times = ctx.phase_times = []
-        times.append((phase, dt))
-        log.info("phase %-24s %8.3fs", phase, dt)
+        times.append((phase, t1 - t0))
+        log.info("phase %-24s %8.3fs", phase, t1 - t0)
+
+    return span("train." + phase, sink=record)
 
 
 def phase_report(ctx) -> str:
@@ -69,14 +71,29 @@ def phase_report(ctx) -> str:
 
 @contextlib.contextmanager
 def maybe_profile(trace_dir: str | None):
-    """jax.profiler.trace when a directory is given; no-op otherwise."""
+    """A jax.profiler capture when a directory is given; no-op otherwise.
+
+    The capture runs with the profiler's Python tracer off
+    (``python_tracer_level`` 0; jax's default, 1, hooks every Python call
+    of every thread, which slows an aiohttp server several times over and
+    names idle gaps by file and line): what the host was doing is told
+    by the program's own spans (``pio.*``, docs/operations.md). The
+    region sits inside one ``pio.profile.window`` annotation, entered
+    once the profiler has started and left before it is stopped, so a
+    reader can cut the profiler's own start and stop stalls away."""
     if not trace_dir:
         yield
         return
     import jax
 
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
     log.info("capturing jax profiler trace -> %s", trace_dir)
-    with jax.profiler.trace(trace_dir):
-        yield
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        with span("profile.window"):
+            yield
+    finally:
+        jax.profiler.stop_trace()
     log.info("profiler trace written to %s (open with TensorBoard/XProf)",
              trace_dir)

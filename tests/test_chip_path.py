@@ -51,12 +51,19 @@ def test_smoke_rehearsal_passes_with_the_parent_off_jax(tmp_path):
     parent runs under a JAX platform that does not exist, so its first
     touch of a JAX backend would end it; the children get `cpu`. With
     JAX_COMPILATION_CACHE_DIR set, train and deploy write their cache
-    there and the checkout's own directory gains nothing."""
+    there and the checkout's own directory gains nothing. The checkout
+    is a copy of its own under tmp_path: other test files, run beside
+    this one, write to the real checkout's `.xla_cache` meanwhile."""
     cache = tmp_path / "placed-cache"
-    before = _listing(CHECKOUT_CACHE)
+    checkout = tmp_path / "checkout"
+    for name in ("predictionio_tpu", "templates"):
+        shutil.copytree(REPO / name, checkout / name,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(REPO / "chip_smoke.py", checkout)
+    own_cache = checkout / ".xla_cache"
     driver = (
         "import os, sys\n"
-        f"sys.path.insert(0, {str(REPO)!r})\n"
+        f"sys.path.insert(0, {str(checkout)!r})\n"
         "import chip_smoke\n"
         "class ChildrenOnCpu(chip_smoke.Smoke):\n"
         "    def __init__(self, args):\n"
@@ -68,7 +75,8 @@ def test_smoke_rehearsal_passes_with_the_parent_off_jax(tmp_path):
         [sys.executable, "-c", driver], capture_output=True, text=True,
         timeout=600, cwd=tmp_path,
         env=_child_env(JAX_PLATFORMS="no-such-platform",
-                       JAX_COMPILATION_CACHE_DIR=str(cache)))
+                       JAX_COMPILATION_CACHE_DIR=str(cache),
+                       PYTHONPATH=str(checkout)))
     assert p.returncode == 0, p.stderr[-4000:]
     result, verdict = map(json.loads, p.stdout.splitlines()[-2:])
     assert "rehearsal" in result  # labelled: never read as a chip run
@@ -82,7 +90,7 @@ def test_smoke_rehearsal_passes_with_the_parent_off_jax(tmp_path):
     assert result["cache"]["dir"] == str(cache)
     assert 1 <= result["cache"]["entries_after_train"] \
         < result["cache"]["entries_after_deploy"]
-    assert _listing(CHECKOUT_CACHE) == before
+    assert _listing(own_cache) == set()
 
 
 def test_smoke_without_a_chip_fails_and_prints_no_result(tmp_path):
