@@ -21,7 +21,8 @@ on the chip.
   composes with the SAME raw scoring program the legacy path compiles
   (``_raw_xla_call`` / the Pallas kernel), into one executable per
   (b_pad, k_pad) lattice point: rows -> gather -> dot -> top_k ->
-  packed ``[b_pad, 2k]`` pull. For ANN / sharded retrievers the gather
+  one packed pull (``retrieval._pack``: values, indices, and under the
+  Pallas kernel its counters). For ANN / sharded retrievers the gather
   program materializes the query matrix on device and hands it to the
   retriever's own compiled programs, so their numerics (and their exact
   fallback policies) are untouched.
@@ -72,10 +73,12 @@ from ..workflow.faults import FAULTS
 from .retrieval import (
     EXEC_CACHE,
     PACKED_IDX_LIMIT,
+    _pack,
     _query_shapes,
     _raw_call,
     _raw_xla_call,
     _RETRIEVER_TOKENS,
+    _unpack,
     DeviceRetriever,
 )
 
@@ -115,16 +118,14 @@ def _capacity(n_rows: int) -> int:
 
 
 def _fused_fn(raw, packed: bool):
-    """rows -> gather -> ``raw`` (score + top-k) -> packed ``[b_pad, 2k]``
-    result: the function the fused executable is compiled from, apart so
-    that tests/test_tpu_compile.py compiles the same text for v5e."""
-    import jax.numpy as jnp
+    """rows -> gather -> ``raw`` (score + top-k) -> the packed result
+    (``retrieval._pack``): the function the fused executable is compiled
+    from, apart so that tests/test_tpu_compile.py compiles the same text
+    for v5e."""
 
     def fn(rows, qtab, items):
-        vals, idx = raw(qtab[rows], items)
-        if not packed:
-            return vals, idx
-        return jnp.concatenate([vals, idx.astype(jnp.float32)], axis=1)
+        out = raw(qtab[rows], items)
+        return _pack(*out) if packed else out
 
     return fn
 
@@ -227,8 +228,7 @@ class ServingPipeline:
                 raw = _raw_xla_call(n_total, k_pad)
             else:
                 raw = _raw_call(b_pad, self._d_pad, int(r._items.shape[0]),
-                                n_total, k_pad, r._tile_n,
-                                r._mode == "interpret")
+                                n_total, k_pad, r._mode == "interpret")
             packed = n_total < PACKED_IDX_LIMIT
             fn = _fused_fn(raw, packed)
             jitted = (jax.jit(fn, donate_argnums=(0,)) if self._donate
@@ -375,14 +375,9 @@ class ServingPipeline:
                 with st.cond:
                     st.advance(-1)
         with stage_span("result_scatter", **facts):
-            if is_packed:
-                host = np.asarray(out)  # packed: ONE pull
-                vals = host[:b, :k_eff]
-                idx = host[:b, k_pad:k_pad + k_eff].astype(np.int32)
-            else:
-                vals, idx = out
-                vals = np.asarray(vals)[:b, :k_eff]
-                idx = np.asarray(idx)[:b, :k_eff]
+            vals, idx, counts = _unpack(out, is_packed, b, k_eff, k_pad)
+        if counts is not None:
+            self._retriever.record_scan(counts)
         return vals, idx
 
     def _dispatch_gather(self, buf, b, k, facts):

@@ -8,10 +8,26 @@ multi/src/main/scala/ALSAlgorithm.scala:146-200 and ALSModel.scala:200-219).
 On TPU the naive form materializes a [B, N] score matrix in HBM and then
 runs top_k over it — 2x the HBM traffic of the matmul itself for large N.
 
-The kernel here streams item tiles through VMEM once: each grid step does
-one [B, D] x [D, T] MXU matmul and merges the tile's scores into a running
-[B, k] accumulator held in the (revisited) output block, so the full score
-matrix never exists. k merge rounds per tile are VPU work over [B, k+T].
+The kernel here streams item tiles through VMEM once, so the full score
+matrix never exists. A grid step takes a tile of catalog rows sized from
+the shapes (`_tile_rows`: 8,192 rows at B 128 and k 16) and scores it one
+sub-tile at a time on the MXU. Each row of the batch keeps its k best
+scores so far, in descending order, in VMEM scratch; a sub-tile's common
+cost is the matmul, one elementwise maximum and one compare of each row's
+best score with that row's own k-th kept value. Only when some row's best
+score is strictly above its own k-th value does the sub-tile merge:
+rounds that move each such row's best remaining score into its kept list,
+as many as the most entrants any row has (about one late in a scan, k in
+the first sub-tile). A zero row (padding slot, unknown user) fills its
+list from the first sub-tile and never opens the gate again. Ties keep
+the lower catalog row. On the v5e a scan of 15.2 M items takes 24.9 ms at
+B 128 (a third of the sub-tiles merge, 1.07 rounds each) and 11.8 ms at
+B 8, where the every-tile merge it replaces took 282.9 and 47.7 (PERF.md,
+PR 25); what is left at B 128 is the six bf16 passes of
+``Precision.HIGHEST``, at B 8 the 7.78 GB of lane-padded catalog. The kernel counts the sub-tiles it
+scanned and merged and the rounds it ran; the counts leave the device in
+the packed result (`_pack`) and reach /stats.json's ``retrieval`` block
+and ``pio_topk_tiles_total`` / ``pio_topk_merge_rounds_total``.
 
 Off-TPU, serving auto-selects a plain-XLA top-k over the same padded
 catalog (`_run_topk_xla` — fast compiled host code with the identical
@@ -46,6 +62,19 @@ _M_EXEC_CACHE = METRICS.counter(
     "pio_exec_cache_total",
     "compiled-executable cache events (hit/miss/evict)",
     labelnames=("event",))
+
+# what the top-k kernel's gate let through, per call, from the counters
+# that ride the packed result (DeviceRetriever.record_scan)
+_M_TILES = METRICS.counter(
+    "pio_topk_tiles_total",
+    "sub-tiles of the catalog the top-k kernel scored (scanned: matmul, "
+    "one maximum, one compare) and those of them in which some row's "
+    "best score beat its k-th kept value (merged)",
+    labelnames=("event",))
+_M_ROUNDS = METRICS.counter(
+    "pio_topk_merge_rounds_total",
+    "extraction rounds the top-k kernel ran inside merging sub-tiles "
+    "(each moves at most one score per row into the kept lists)")
 
 
 def row_normalize(x: np.ndarray) -> np.ndarray:
@@ -195,123 +224,264 @@ def _pad_to(x, mult, axis, value=0.0):
     return np.pad(x, pad, constant_values=value) if isinstance(x, np.ndarray) else None
 
 
-def _topk_kernel(q_ref, items_ref, vals_ref, idx_ref, *, k, tile_n, n_total):
+#: VMEM the kernel sizes its tile for: the double-buffered catalog tile,
+#: one sub-tile's scores and the kept lists, inside Mosaic's 16 MiB scoped
+#: default with room left for the compiler's own temporaries.
+_VMEM_BUDGET = 12 << 20
+
+#: Most catalog rows a grid step takes; `_pad_items` pads a large catalog
+#: to a multiple of it so that every smaller power-of-two tile divides it.
+_TILE_MAX = 8192
+
+#: Scores of one gated sub-tile, [B, chunk]. The chunk narrows as the
+#: batch grows, so the entrants a sub-tile holds (B * k * chunk / rows
+#: scanned) do not grow with B. Swept on the v5e over the serving cells'
+#: catalog (PERF.md, PR 25): at B 128 a scan takes 29.1 ms at 2^16 (twice
+#: the gates), 24.8 at 2^17 with a third of the sub-tiles merging, 23.1 at
+#: 2^18 with half of them merging.
+_CHUNK_ELEMS = 1 << 17
+
+#: Widest sub-tile. A small batch gains nothing from a wider one (B 8:
+#: 11.7 ms a scan at 2,048 columns, 11.4 at 8,192, same sweep), and
+#: Mosaic unrolls the sub-tile's matmul, so its compile time grows with it.
+_CHUNK_MAX = 2048
+
+
+def _tile_rows(B: int, D: int, k: int, N_pad: int) -> tuple[int, int]:
+    """(tile, chunk): catalog rows per grid step and per gated sub-tile,
+    from the shapes alone. The tile is the largest power-of-two multiple
+    of 128 that divides ``N_pad`` and fits the VMEM budget beside the
+    sub-tile's scores and the kept lists (which grow with k, so a large
+    k shrinks the tile); the chunk is `_CHUNK_ELEMS / B`, at least one
+    lane group and at most `_CHUNK_MAX` and the tile."""
+    k_lanes = -(-k // 128) * 128
+    chunk = 128
+    while chunk * 2 * B <= _CHUNK_ELEMS and chunk < _CHUNK_MAX:
+        chunk *= 2
+    # scores, their scratch copy and two temporaries of an extraction
+    # round; kept values and ids as scratch and as (double-buffered)
+    # output blocks; the k-th values broadcast over one lane group
+    fixed = 4 * B * chunk * 4 + 6 * B * k_lanes * 4 + B * 128 * 4
+    tile = 128
+    while (tile < _TILE_MAX and N_pad % (tile * 2) == 0
+           and fixed + 2 * (tile * 2) * D * 4 <= _VMEM_BUDGET):
+        tile *= 2
+    return tile, min(chunk, tile)
+
+
+def _topk_kernel(q_ref, items_ref, vals_ref, idx_ref, cnt_ref,
+                 kv_ref, ki_ref, kth_ref, s_ref, *,
+                 k, tile_n, chunk, n_total, n_pad):
+    """One grid step: score a tile of the catalog, sub-tile by sub-tile,
+    and let a sub-tile touch the kept lists only if some row's best
+    score in it beats that row's own k-th kept value.
+
+    kv_ref / ki_ref: [B, k_lanes] kept values (descending) and their
+    catalog rows, lane-padded so that an insertion is one lane roll;
+    kth_ref: [B, 128] each row's k-th kept value over a lane group (what
+    the gate compares with); s_ref: [B, chunk] the scores of a merging
+    sub-tile, consumed by the extraction rounds; cnt_ref: int32[3] in
+    SMEM, (sub-tiles scanned, sub-tiles merged, extraction rounds)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     j = pl.program_id(0)
+    last = pl.num_programs(0) - 1
+    B = q_ref.shape[0]
+    neg_inf = jnp.float32(-jnp.inf)
 
     @pl.when(j == 0)
     def _():
-        vals_ref[:] = jnp.full(vals_ref.shape, -jnp.inf, vals_ref.dtype)
-        idx_ref[:] = jnp.full(idx_ref.shape, -1, idx_ref.dtype)
+        kv_ref[...] = jnp.full(kv_ref.shape, neg_inf, kv_ref.dtype)
+        ki_ref[...] = jnp.full(ki_ref.shape, -1, ki_ref.dtype)
+        kth_ref[...] = jnp.full(kth_ref.shape, neg_inf, kth_ref.dtype)
+        cnt_ref[0] = n_pad // chunk
+        cnt_ref[1] = 0
+        cnt_ref[2] = 0
 
-    q = q_ref[:]  # [B, D]
-    tile = items_ref[:]  # [T, D]
-    scores = jax.lax.dot_general(
-        q, tile, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,  # full-f32 MXU passes: scores
-        # must rank stably against host-side float32 references
-    )  # [B, T]
-    cand = j * tile_n + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    scores = jnp.where(cand < n_total, scores, -jnp.inf)
+    q = q_ref[...]  # [B, D]
+    col = jax.lax.broadcasted_iota(jnp.int32, (B, chunk), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, kv_ref.shape, 1)
 
-    # threshold skip: a tile whose best score beats no row's current kth
-    # value cannot change the result — only the matmul + max run for it
-    # (with random scores most tiles skip, so the merge loop below is rare)
-    kth = jnp.min(vals_ref[:])
+    def beats_kth(s):
+        """Does any row hold a score above its own k-th kept value?
+        Lane groups are folded elementwise first: one compare of
+        [B, 128] and one reduction to a scalar, no per-row lane reduce."""
+        m = s
+        while m.shape[1] > 128:
+            half = m.shape[1] // 2
+            m = jnp.maximum(m[:, :half], m[:, half:])
+        return jnp.max(jnp.where(m > kth_ref[...], 1.0, 0.0)) > 0.0
 
-    @pl.when(jnp.max(scores) > kth)
+    def extract_round(base):
+        """Move each row's best remaining score of the sub-tile into its
+        kept list, if it beats the row's k-th value: the first column
+        holding the maximum (the lower catalog row wins a tie inside
+        the sub-tile), inserted behind every kept value not below it
+        (an earlier catalog row wins a tie across sub-tiles)."""
+        s = s_ref[...]
+        m = jnp.max(s, axis=1, keepdims=True)  # [B, 1]
+        pick = jnp.min(jnp.where(s == m, col, chunk), axis=1, keepdims=True)
+        s = jnp.where(col == pick, neg_inf, s)
+        s_ref[...] = s
+        kv, ki = kv_ref[...], ki_ref[...]
+        enters = m > kth_ref[:, :1]
+        pos = jnp.sum(jnp.where(kv >= m, 1, 0), axis=1, keepdims=True)
+        new_v = jnp.where(lane < pos, kv, jnp.where(
+            lane == pos, m, pltpu.roll(kv, 1, 1)))
+        new_i = jnp.where(lane < pos, ki, jnp.where(
+            lane == pos, base + pick, pltpu.roll(ki, 1, 1)))
+        kv = jnp.where(enters, new_v, kv)
+        kv_ref[...] = kv
+        ki_ref[...] = jnp.where(enters, new_i, ki)
+        kth = jnp.max(jnp.where(lane == k - 1, kv, neg_inf), axis=1,
+                      keepdims=True)
+        kth_ref[...] = jnp.broadcast_to(kth, kth_ref.shape)
+        return beats_kth(s)
+
+    def sub_tile(c, carry):
+        off = pl.multiple_of(c * chunk, chunk)
+        s = jax.lax.dot_general(
+            q, items_ref[pl.ds(off, chunk), :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,  # full-f32 MXU passes:
+            # scores must rank stably against host-side float32 references
+        )  # [B, chunk]
+        base = j * tile_n + off
+
+        @pl.when(beats_kth(s))
+        def _():
+            s_ref[...] = s
+            merges = True
+            if n_pad > n_total:
+                # The catalog's zero padding scores 0, not -inf. Only a
+                # sub-tile that reaches into it is masked, here where
+                # that is rare, and asks the gate again: padding that
+                # beat a negative k-th value neither enters nor counts.
+                def mask_padding():
+                    s_ref[...] = jnp.where(col < n_total - base, s, neg_inf)
+                    return beats_kth(s_ref[...])
+
+                merges = jax.lax.cond(base + chunk > n_total, mask_padding,
+                                      lambda: jnp.bool_(True))
+            # at most k rounds: a row's k-th value only rises, so a
+            # sub-tile holds at most k entrants for any row
+            rounds = jax.lax.while_loop(
+                lambda c: c[0],
+                lambda c: (extract_round(base), c[1] + 1),
+                (merges, jnp.int32(0)))[1]
+            cnt_ref[1] += jnp.int32(merges)
+            cnt_ref[2] += rounds
+
+        return carry
+
+    jax.lax.fori_loop(0, tile_n // chunk, sub_tile, 0)
+
+    @pl.when(j == last)
     def _():
-        merged_v = jnp.concatenate([vals_ref[:], scores], axis=1)  # [B, k+T]
-        merged_i = jnp.concatenate([idx_ref[:], cand], axis=1)
-
-        B = merged_v.shape[0]
-        col = jax.lax.broadcasted_iota(jnp.int32, merged_v.shape, 1)
-        out_col = jax.lax.broadcasted_iota(jnp.int32, (B, k), 1)
-
-        def extract(t, carry):
-            # registers only — Mosaic forbids unaligned dynamic ref
-            # writes, so the output slot is a one-hot, not pl.ds
-            mv, out_v, out_i = carry
-            m = jnp.max(mv, axis=1)  # [B]
-            sel = mv == m[:, None]
-            # first column holding the max (no cumsum in Mosaic):
-            # min col index among argmax positions
-            pick_col = jnp.min(jnp.where(sel, col, mv.shape[1]), axis=1)
-            chosen = col == pick_col[:, None]
-            pick = jnp.sum(jnp.where(chosen, merged_i, 0), axis=1)
-            pick = jnp.where(jnp.isfinite(m), pick, -1).astype(jnp.int32)
-            slot = out_col == t
-            out_v = jnp.where(slot, m[:, None], out_v)
-            out_i = jnp.where(slot, pick[:, None], out_i)
-            return jnp.where(chosen, -jnp.inf, mv), out_v, out_i
-
-        init = (
-            merged_v,
-            jnp.full((B, k), -jnp.inf, vals_ref.dtype),
-            jnp.full((B, k), -1, idx_ref.dtype),
-        )
-        _, out_v, out_i = jax.lax.fori_loop(0, k, extract, init)
-        vals_ref[:] = out_v
-        idx_ref[:] = out_i
+        vals_ref[...] = kv_ref[:, :k]
+        idx_ref[...] = ki_ref[:, :k]
 
 
-def _raw_call(B, D, N_pad, n_total, k, tile_n, interpret):
-    """The un-jitted fused top-k pallas call — shared by the jitted
+def _raw_call(B, D, N_pad, n_total, k, interpret, *, tile_n=None):
+    """The un-jitted fused top-k pallas call, ``(q, items) -> (values
+    [B, k], rows [B, k], counters int32[3])``: shared by the jitted
     serving entry (`_build_call`) and the serving pipeline's fused
-    program (ops/pipeline.py), which composes it with the row gather."""
+    program (ops/pipeline.py), which composes it with the row gather.
+    ``tile_n`` is for tests that pin one tile size; serving never passes
+    it."""
     import jax
+    import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    grid = (N_pad // tile_n,)
-    kernel = functools.partial(_topk_kernel, k=k, tile_n=tile_n, n_total=n_total)
+    tile, chunk = _tile_rows(B, D, k, N_pad)
+    if tile_n is not None:
+        tile, chunk = tile_n, min(chunk, tile_n)
+    k_lanes = -(-k // 128) * 128
+    kernel = functools.partial(_topk_kernel, k=k, tile_n=tile, chunk=chunk,
+                               n_total=n_total, n_pad=N_pad)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(N_pad // tile,),
         in_specs=[
             pl.BlockSpec((B, D), lambda j: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((tile_n, D), lambda j: (j, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((tile, D), lambda j: (j, 0), memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((B, k), lambda j: (0, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((B, k), lambda j: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, k), jax.numpy.float32),
-            jax.ShapeDtypeStruct((B, k), jax.numpy.int32),
+            jax.ShapeDtypeStruct((B, k), jnp.float32),
+            jax.ShapeDtypeStruct((B, k), jnp.int32),
+            jax.ShapeDtypeStruct((3,), jnp.int32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((B, k_lanes), jnp.float32),
+            pltpu.VMEM((B, k_lanes), jnp.int32),
+            pltpu.VMEM((B, 128), jnp.float32),
+            pltpu.VMEM((B, chunk), jnp.float32),
         ],
         interpret=interpret,
     )
 
 
-def _build_call(B, D, N_pad, n_total, k, tile_n, interpret, *, pin=False):
-    """Compiled kernel + result packing: values and indices leave the
-    device as ONE [B, 2k] f32 buffer, so a served batch costs one
-    blocking device-to-host pull instead of two (what the second pull
-    costs is not measured on the chip). Indices are exact in
-    f32 below 2^24; a larger catalog falls back to the two-buffer path.
-    The executable is AOT-built (jit -> lower -> compile) into
+def _build_call(B, D, N_pad, n_total, k, interpret, *, pin=False):
+    """Compiled kernel + result packing: values, indices and the scan's
+    counters leave the device as ONE [B, 2k + 3] f32 buffer, so a served
+    batch costs one blocking device-to-host pull. Indices are exact in
+    f32 below 2^24; a larger catalog falls back to the several-buffer
+    path. The executable is AOT-built (jit -> lower -> compile) into
     EXEC_CACHE; ``pin=True`` (the deploy path's pre-warm) exempts the
     shape from eviction."""
-    key = ("kernel", B, D, N_pad, n_total, k, tile_n, interpret)
+    key = ("kernel", B, D, N_pad, n_total, k, interpret)
     out = EXEC_CACHE.get_or_build(key, lambda: _aot_with_packing(
-        _raw_call(B, D, N_pad, n_total, k, tile_n, interpret),
+        _raw_call(B, D, N_pad, n_total, k, interpret),
         n_total, B, D, N_pad))
     if pin:
         EXEC_CACHE.pin(key)
     return out
 
 
+def _pack(vals, idx, *counts):
+    """One f32 buffer for one host pull: [B, 2k] values and indices,
+    then the kernel's counters (when the scoring program has any)
+    repeated down the rows. All exact in f32 below 2^24."""
+    import jax.numpy as jnp
+
+    cols = [vals, idx.astype(jnp.float32)]
+    cols += [jnp.broadcast_to(c.astype(jnp.float32), (vals.shape[0], c.size))
+             for c in counts]
+    return jnp.concatenate(cols, axis=1)
+
+
+def _unpack(out, is_packed: bool, b: int, k_eff: int, k_pad: int):
+    """Host side of `_pack`: (values [b, k_eff], indices [b, k_eff],
+    counters or None) from what a scoring program returned, padding
+    rows and columns cut off. Packed: ONE pull."""
+    if is_packed:
+        host = np.asarray(out)
+        vals = host[:b, :k_eff]
+        idx = host[:b, k_pad:k_pad + k_eff].astype(np.int32)
+        counts = host[0, 2 * k_pad:]
+    else:
+        vals, idx, *rest = out
+        vals = np.asarray(vals)[:b, :k_eff]
+        idx = np.asarray(idx)[:b, :k_eff]
+        counts = np.asarray(rest[0]) if rest else ()
+    return vals, idx, (counts if len(counts) else None)
+
+
 def _aot_with_packing(call, n_total: int, B: int, D: int, N_pad: int):
     """The ONE home of the pack/no-pack policy for every single-device
-    top-k builder (kernel and XLA): below PACKED_IDX_LIMIT, values and
-    indices leave the device as one [B, 2k] f32 buffer (one host
-    pull); at/above it, the two-buffer path keeps
-    indices exact. The executable is compiled AHEAD of the first call
+    top-k builder (kernel and XLA): below PACKED_IDX_LIMIT, what the
+    program returns leaves the device as one f32 buffer (`_pack`: one
+    host pull); at/above it, separate buffers keep indices exact. The
+    executable is compiled AHEAD of the first call
     (``jax.jit(...).lower(...).compile()``) so a pre-warmed shape never
     pays tracing or compilation on the serving path. Returns (compiled
     executable, is_packed)."""
@@ -322,8 +492,7 @@ def _aot_with_packing(call, n_total: int, B: int, D: int, N_pad: int):
         fn, is_packed = call, False
     else:
         def fn(q, items):
-            vals, idx = call(q, items)
-            return jnp.concatenate([vals, idx.astype(jnp.float32)], axis=1)
+            return _pack(*call(q, items))
 
         is_packed = True
     compiled = jax.jit(fn).lower(
@@ -386,12 +555,25 @@ def _run_topk_xla(q: np.ndarray, items_dev, n_total: int, k: int):
     return _dispatch_topk(q, n_total, k, invoke)
 
 
-def _pad_items(items: np.ndarray, n_total: int, tile_n: int) -> tuple[np.ndarray, int]:
-    """Feature-pad to the 128-lane width and row-pad to whole tiles;
-    returns (padded items, clamped tile_n)."""
-    it = _pad_to(items, 128, 1)
-    tile_n = min(tile_n, max(128, ((n_total + 127) // 128) * 128))
-    return _pad_to(it, tile_n, 0), tile_n
+def _padded_shape(n: int, d: int) -> tuple[int, int]:
+    """(rows, lanes) of the catalog on the device: features padded to
+    the 128-lane width, rows so that the kernel's tiles divide them: to
+    a multiple of the largest power of two between 128 and `_TILE_MAX`
+    that is at most a sixteenth of the rows (a small catalog is not
+    padded by a large tile's worth)."""
+    quantum = 128
+    while quantum < _TILE_MAX and quantum * 16 <= n:
+        quantum *= 2
+    return -(-n // quantum) * quantum, -(-d // 128) * 128
+
+
+def _pad_items(items: np.ndarray) -> np.ndarray:
+    """The catalog zero-padded to `_padded_shape`: one allocation and
+    one copy into it."""
+    n, d = items.shape
+    out = np.zeros(_padded_shape(n, d), np.float32)
+    out[:n, :d] = items
+    return out
 
 
 def _query_shapes(b: int, k_eff: int, n_total: int) -> tuple[int, int]:
@@ -407,13 +589,15 @@ def _query_shapes(b: int, k_eff: int, n_total: int) -> tuple[int, int]:
     return b_pad, min(((k_eff + 7) // 8) * 8, n_total)
 
 
-def _dispatch_topk(q: np.ndarray, n_total: int, k: int, invoke):
+def _dispatch_topk(q: np.ndarray, n_total: int, k: int, invoke,
+                   on_scan=None):
     """Query-side prep + result un-pad shared by EVERY top-k entry point
     (``topk_scores``, ``DeviceRetriever.topk``, ``ShardedDeviceRetriever
     .topk``) — one home so padding/empty-catalog/pack handling cannot
     drift between them. ``invoke(q_padded, k_pad)`` runs the compiled
-    call and returns either a (vals, idx) tuple or the packed
-    [B, 2*k_pad] f32 buffer (detected here by type)."""
+    call and returns ``(out, is_packed)``: the packed f32 buffer or the
+    separate (vals, idx[, counters]) buffers. ``on_scan`` takes the
+    kernel's counters where the scoring program has any."""
     FAULTS.fire("retrieval.topk")  # chaos site: a hang here IS a hung
     # device call (workflow/faults.py); no-op unless a test armed it
     single = q.ndim == 1
@@ -448,29 +632,24 @@ def _dispatch_topk(q: np.ndarray, n_total: int, k: int, invoke):
             except Exception:
                 pass  # numpy results / non-jax invokes: nothing to fence
     with stage_span("result_scatter", **facts):
-        if is_packed:
-            host = np.asarray(out)  # packed: ONE pull
-            vals = host[:b_orig, :k_eff]
-            idx = host[:b_orig, k_pad:k_pad + k_eff].astype(np.int32)
-        else:
-            vals, idx = out
-            vals = np.asarray(vals)[:b_orig, :k_eff]
-            idx = np.asarray(idx)[:b_orig, :k_eff]
+        vals, idx, counts = _unpack(out, is_packed, b_orig, k_eff, k_pad)
+    if on_scan is not None and counts is not None:
+        on_scan(counts)
     return (vals[0], idx[0]) if single else (vals, idx)
 
 
-def _run_topk(q: np.ndarray, items_dev, n_total: int, k: int, tile_n: int,
-              interpret: bool):
+def _run_topk(q: np.ndarray, items_dev, n_total: int, k: int,
+              interpret: bool, on_scan=None):
     """Single-device entry: fused Pallas kernel behind ``_dispatch_topk``."""
 
     def invoke(qp, k_pad):
         call, is_packed = _build_call(
             qp.shape[0], items_dev.shape[1], items_dev.shape[0], n_total,
-            k_pad, tile_n, interpret,
+            k_pad, interpret,
         )
         return call(qp, items_dev), is_packed
 
-    return _dispatch_topk(q, n_total, k, invoke)
+    return _dispatch_topk(q, n_total, k, invoke, on_scan)
 
 
 def _resolve_topk_mode(interpret) -> str:
@@ -487,7 +666,7 @@ def _resolve_topk_mode(interpret) -> str:
     return "interpret" if interpret else "native"
 
 
-def topk_scores(queries, items, k: int, *, tile_n: int = 512, interpret=None):
+def topk_scores(queries, items, k: int, *, interpret=None):
     """Top-k inner-product retrieval: (values [B, k], indices [B, k]).
 
     queries: [B, D] or [D]; items: [N, D]. Indices of padded/overflow slots
@@ -500,11 +679,10 @@ def topk_scores(queries, items, k: int, *, tile_n: int = 512, interpret=None):
     q = np.asarray(queries, dtype=np.float32)
     it = np.asarray(items, dtype=np.float32)
     n_total = it.shape[0]
-    it, tile_n = _pad_items(it, n_total, tile_n)
-    items_dev = jnp.asarray(it)
+    items_dev = jnp.asarray(_pad_items(it))
     if mode == "xla":
         return _run_topk_xla(q, items_dev, n_total, k)
-    return _run_topk(q, items_dev, n_total, k, tile_n, mode == "interpret")
+    return _run_topk(q, items_dev, n_total, k, mode == "interpret")
 
 
 class DeviceRetriever:
@@ -513,16 +691,18 @@ class DeviceRetriever:
     fused-top-k call (the engine server's /reload double-buffers by
     building a new DeviceRetriever and swapping the reference)."""
 
-    def __init__(self, items: np.ndarray, *, tile_n: int = 512, interpret=None):
+    def __init__(self, items: np.ndarray, *, interpret=None):
         import jax
         import jax.numpy as jnp
 
         self._mode = _resolve_topk_mode(interpret)
+        self._scan_lock = threading.Lock()
+        self._scan = np.zeros(3, np.int64)  # scanned, merged, rounds
         with span("deploy.attach_retriever.catalog_pad",
                   sink=STARTUP.phase) as s:
             it = np.asarray(items, dtype=np.float32)
             self.n_total, self.dim = it.shape
-            it, self._tile_n = _pad_items(it, self.n_total, tile_n)
+            it = _pad_items(it)
             s["bytes"] = int(it.nbytes)
         with span("deploy.attach_retriever.catalog_upload",
                   sink=STARTUP.phase, bytes=int(it.nbytes)):
@@ -546,13 +726,35 @@ class DeviceRetriever:
         off with zero re-pad."""
         return int(self._items.shape[1])
 
+    def record_scan(self, counts) -> None:
+        """Add one kernel call's counters (sub-tiles scanned, sub-tiles
+        merged, extraction rounds) to this retriever's totals and to the
+        registry. Called with what `_unpack` found in the pulled buffer,
+        by ``topk`` and by the serving pipeline's fused dispatch."""
+        scanned, merged, rounds = (int(c) for c in counts)
+        with self._scan_lock:
+            self._scan += (scanned, merged, rounds)
+        _M_TILES.inc(scanned, event="scanned")
+        _M_TILES.inc(merged, event="merged")
+        _M_ROUNDS.inc(rounds)
+
+    def stats(self) -> dict:
+        """/stats.json's ``retrieval`` block. The three counters stay 0
+        where the XLA program scores the catalog: it has no tiles."""
+        with self._scan_lock:
+            scanned, merged, rounds = (int(c) for c in self._scan)
+        return {"mode": "exact", "kernel": self._mode,
+                "nTotal": self.n_total, "sharded": False,
+                "tilesScanned": scanned, "tilesMerged": merged,
+                "mergeRounds": rounds}
+
     def topk(self, queries, k: int):
         """(values [B, k], indices [B, k]) — indices -1 beyond catalog."""
         q = np.asarray(queries, dtype=np.float32)
         if self._mode == "xla":
             return _run_topk_xla(q, self._items, self.n_total, k)
-        return _run_topk(q, self._items, self.n_total, k, self._tile_n,
-                         self._mode == "interpret")
+        return _run_topk(q, self._items, self.n_total, k,
+                         self._mode == "interpret", self.record_scan)
 
     def prewarm(self, batch_sizes=(1,), ks=(10,)) -> list[tuple[int, int]]:
         """AOT-build and PIN the executables for the hot serving shapes,
@@ -579,8 +781,8 @@ class DeviceRetriever:
                     else:
                         _build_call(b_pad, self._items.shape[1],
                                     self._items.shape[0], self.n_total,
-                                    k_pad, self._tile_n,
-                                    self._mode == "interpret", pin=True)
+                                    k_pad, self._mode == "interpret",
+                                    pin=True)
                 warmed.append((b_pad, k_pad))
         return warmed
 

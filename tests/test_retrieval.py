@@ -23,17 +23,217 @@ def exact_topk(q, items, k):
     (3, 1000, 32, 10),     # N not a multiple of the tile
     (8, 512, 64, 512),     # k == N (full ranking)
     (2, 2000, 16, 1),      # k = 1
+    (2, 100, 10, 100),     # k_pad == n_total, not a multiple of 8
+    (8, 5000, 64, 16),     # five 1,024-row tiles, the last ragged
+    (8, 140_000, 16, 10),  # eighteen 8,192-row tiles, the last ragged
+    (128, 3000, 32, 16),   # b_pad 128: 512-column sub-tiles
 ])
 def test_matches_exact(rng, B, N, D, k, interpret):
     q = rng.standard_normal((B, D)).astype(np.float32)
     items = rng.standard_normal((N, D)).astype(np.float32)
-    vals, idx = topk_scores(q, items, k, tile_n=512, interpret=interpret)
+    vals, idx = topk_scores(q, items, k, interpret=interpret)
     want_v, want_i = exact_topk(q, items, k)
     np.testing.assert_allclose(vals, want_v, rtol=1e-5, atol=1e-5)
     # indices may differ on exact ties; compare score-at-index instead
     got_scores = np.take_along_axis(q @ items.T, idx.astype(np.int64), axis=1)
     np.testing.assert_allclose(got_scores, want_v, rtol=1e-5, atol=1e-5)
     assert (idx >= 0).all() and (idx < N).all()
+
+
+def _old_topk_call(B, D, N_pad, n_total, k, tile_n):
+    """The kernel as it stood before ISSUE 25 (merge gated on the best
+    score of ANY row against the lowest kept value of ANY row, k
+    unconditional extraction rounds over [B, k + T]), kept here as the
+    reference the new kernel's answers must equal bit for bit."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    def kernel(q_ref, items_ref, vals_ref, idx_ref):
+        j = pl.program_id(0)
+
+        @pl.when(j == 0)
+        def _():
+            vals_ref[:] = jnp.full(vals_ref.shape, -jnp.inf, vals_ref.dtype)
+            idx_ref[:] = jnp.full(idx_ref.shape, -1, idx_ref.dtype)
+
+        scores = jax.lax.dot_general(
+            q_ref[:], items_ref[:], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
+        cand = j * tile_n + jax.lax.broadcasted_iota(
+            jnp.int32, scores.shape, 1)
+        scores = jnp.where(cand < n_total, scores, -jnp.inf)
+
+        @pl.when(jnp.max(scores) > jnp.min(vals_ref[:]))
+        def _():
+            merged_v = jnp.concatenate([vals_ref[:], scores], axis=1)
+            merged_i = jnp.concatenate([idx_ref[:], cand], axis=1)
+            col = jax.lax.broadcasted_iota(jnp.int32, merged_v.shape, 1)
+            out_col = jax.lax.broadcasted_iota(jnp.int32, (B, k), 1)
+
+            def extract(t, carry):
+                mv, out_v, out_i = carry
+                m = jnp.max(mv, axis=1)
+                pick_col = jnp.min(
+                    jnp.where(mv == m[:, None], col, mv.shape[1]), axis=1)
+                chosen = col == pick_col[:, None]
+                pick = jnp.sum(jnp.where(chosen, merged_i, 0), axis=1)
+                pick = jnp.where(jnp.isfinite(m), pick, -1).astype(jnp.int32)
+                slot = out_col == t
+                return (jnp.where(chosen, -jnp.inf, mv),
+                        jnp.where(slot, m[:, None], out_v),
+                        jnp.where(slot, pick[:, None], out_i))
+
+            _, out_v, out_i = jax.lax.fori_loop(0, k, extract, (
+                merged_v, jnp.full((B, k), -jnp.inf, jnp.float32),
+                jnp.full((B, k), -1, jnp.int32)))
+            vals_ref[:] = out_v
+            idx_ref[:] = out_i
+
+    return pl.pallas_call(
+        kernel, grid=(N_pad // tile_n,),
+        in_specs=[pl.BlockSpec((B, D), lambda j: (0, 0)),
+                  pl.BlockSpec((tile_n, D), lambda j: (j, 0))],
+        out_specs=[pl.BlockSpec((B, k), lambda j: (0, 0)),
+                   pl.BlockSpec((B, k), lambda j: (0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, k), jnp.float32),
+                   jax.ShapeDtypeStruct((B, k), jnp.int32)],
+        interpret=True)
+
+
+def _padded(q, items):
+    """(q, items) as the kernel takes them: 128 lanes, whole tiles."""
+    from predictionio_tpu.ops.retrieval import _pad_items
+
+    qp = np.zeros((q.shape[0], 128), np.float32)
+    qp[:, :q.shape[1]] = q
+    return qp, _pad_items(items)
+
+
+def test_answers_are_the_old_extractions_bit_for_bit(rng):
+    """ISSUE 25 acceptance: at one tile size (512) the per-row gate and
+    the bounded extraction return the values AND the catalog rows the
+    old every-tile merge returned, ties, zero rows and padding
+    included."""
+    from predictionio_tpu.ops.retrieval import _raw_call
+
+    B, N, D, k = 8, 2900, 24, 16
+    items = rng.standard_normal((N, D)).astype(np.float32)
+    items[700:764] = items[100:164]     # exact duplicates across tiles
+    items[1500:1510] = items[1490:1500]  # and inside one
+    q = rng.standard_normal((B, D)).astype(np.float32)
+    q[3] = 0.0  # the sentinel row: every score ties at 0
+    qp, ip = _padded(q, items)
+    assert ip.shape[0] % 512 == 0 and ip.shape[0] > N
+    want_v, want_i = _old_topk_call(B, 128, ip.shape[0], N, k, 512)(qp, ip)
+    got_v, got_i, counts = _raw_call(B, 128, ip.shape[0], N, k, True,
+                                     tile_n=512)(qp, ip)
+    assert np.array_equal(np.asarray(got_v), np.asarray(want_v))
+    assert np.array_equal(np.asarray(got_i), np.asarray(want_i))
+    assert int(counts[0]) == ip.shape[0] // 512
+    assert list(np.asarray(want_i)[3]) == list(range(k))  # lower row wins
+
+
+def _replay_gate(q, items, n_total, k, tile, chunk):
+    """numpy replay of the kernel's gate and extraction, sub-tile by
+    sub-tile: (values, rows, [scanned, merged, rounds])."""
+    B = q.shape[0]
+    scores = (q @ items.T).astype(np.float32)
+    scores[:, n_total:] = -np.inf
+    kv = np.full((B, k), -np.inf, np.float32)
+    ki = np.full((B, k), -1, np.int64)
+    merged = rounds = 0
+    for base in range(0, items.shape[0], chunk):
+        s = scores[:, base:base + chunk].copy()
+        if not (s.max(axis=1) > kv[:, -1]).any():
+            continue
+        merged += 1
+        while True:
+            rounds += 1
+            pick = s.argmax(axis=1)  # the first column holding the max
+            m = s[np.arange(B), pick]
+            for r in np.flatnonzero(m > kv[:, -1]):
+                pos = int((kv[r] >= m[r]).sum())
+                kv[r] = np.insert(kv[r], pos, m[r])[:k]
+                ki[r] = np.insert(ki[r], pos, base + pick[r])[:k]
+            s[np.arange(B), pick] = -np.inf
+            if not (s.max(axis=1) > kv[:, -1]).any():
+                break
+    return kv, ki, [items.shape[0] // chunk, merged, rounds]
+
+
+@pytest.mark.parametrize("B,N,k", [(8, 60_000, 16), (128, 20_000, 16),
+                                   (16, 2048, 8)],
+                         ids=["b8", "b128", "no-padding"])
+def test_counters_equal_a_replay_of_the_gate(rng, B, N, k):
+    """The counters that ride the packed result are what a numpy replay
+    of the per-row gate counts on the same inputs: small integers, so
+    every score is exact whatever the order of the sum, and ties
+    abound. The replay's answers are the kernel's too, and a stable
+    sort's (the lower catalog row wins a tie)."""
+    from predictionio_tpu.ops.retrieval import _raw_call, _tile_rows
+
+    q = rng.integers(-4, 5, (B, 16)).astype(np.float32)
+    q[B // 2] = 0.0
+    items = rng.integers(-4, 5, (N, 16)).astype(np.float32)
+    # long rows first, so that the late sub-tiles hold no entrant
+    items = items[np.argsort(-np.abs(items).sum(axis=1), kind="stable")]
+    qp, ip = _padded(q, items)
+    tile, chunk = _tile_rows(B, 128, k, ip.shape[0])
+    assert ip.shape[0] // tile > 1 or N == 2048
+    got_v, got_i, counts = _raw_call(B, 128, ip.shape[0], N, k, True)(qp, ip)
+    want_v, want_i, want_counts = _replay_gate(qp, ip, N, k, tile, chunk)
+    assert np.array_equal(np.asarray(got_v), want_v)
+    assert np.array_equal(np.asarray(got_i), want_i)
+    assert [int(c) for c in counts] == want_counts
+    sort_v, sort_i = exact_topk(q, items, k)
+    assert np.array_equal(want_i, sort_i)
+    assert np.array_equal(want_v, sort_v)
+    if N > 2048:  # some sub-tile was skipped
+        assert want_counts[1] < want_counts[0]
+    assert want_counts[1] <= want_counts[2] <= k * want_counts[1]
+
+
+def test_zero_rows_among_real_ones_stop_merging(rng):
+    """The sentinel case (padding slots, unknown users): zero rows fill
+    their k slots from the first sub-tile and never open the gate
+    again, so the batch equals the float32 reference and merges in
+    fewer sub-tiles than it scans. /stats.json's retrieval block and
+    the registry carry the counts."""
+    from predictionio_tpu.obs.metrics import METRICS
+
+    items = rng.standard_normal((40_000, 32)).astype(np.float32)
+    # scores shrink along the catalog: real rows stop merging early too,
+    # while the old gate (any row's best score against the LOWEST kept
+    # value of any row, 0 here) would have merged every tile
+    items /= (1.0 + np.arange(len(items), dtype=np.float32) / 500)[:, None]
+    q = rng.standard_normal((5, 32)).astype(np.float32)
+    q[1] = q[4] = 0.0
+    r = DeviceRetriever(items, interpret=True)
+    before = METRICS.snapshot()["counters"]
+    vals, idx = r.topk(q, 10)
+    want_v, want_i = exact_topk(q, items, 10)
+    np.testing.assert_allclose(vals, want_v, rtol=1e-5, atol=1e-5)
+    assert np.array_equal(idx[[1, 4]], want_i[[1, 4]])  # rows 0..9
+    st = r.stats()
+    assert st["mode"] == "exact" and st["kernel"] == "interpret"
+    assert 0 < st["tilesMerged"] < st["tilesScanned"]
+    assert st["tilesMerged"] <= st["mergeRounds"]
+    after = METRICS.snapshot()["counters"]
+
+    def gain(series):
+        return after[series] - before.get(series, 0)
+
+    assert gain('pio_topk_tiles_total{event="scanned"}') == st["tilesScanned"]
+    assert gain('pio_topk_tiles_total{event="merged"}') == st["tilesMerged"]
+    assert gain("pio_topk_merge_rounds_total") == st["mergeRounds"]
+    # a second call adds to the totals; the XLA program has no tiles
+    r.topk(q, 10)
+    assert r.stats()["tilesScanned"] == 2 * st["tilesScanned"]
+    assert DeviceRetriever(items[:500]).stats()["tilesScanned"] == 0
 
 
 @pytest.mark.parametrize("interpret", [True, None],

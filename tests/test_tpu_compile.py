@@ -26,11 +26,15 @@ from predictionio_tpu.models.als import _layout_shardings, make_train_step
 from predictionio_tpu.ops.neighbors import build_bilinear_layout
 from predictionio_tpu.ops.pipeline import _capacity, _fused_fn
 from predictionio_tpu.ops.retrieval import (ShardedDeviceRetriever,
-                                            _pad_items, _query_shapes,
-                                            _raw_call)
+                                            _padded_shape, _query_shapes,
+                                            _raw_call, _tile_rows)
 
 #: the smoke's catalog (ML-20M's items at rank 64) and query table
 N_ITEMS, N_USERS, RANK = 26_744, 138_493, 64
+
+#: the serving cells' catalog and query table (benchmarks/configs/
+#: als-amazon18.json): 7.78 GB on the chip, so shapes only
+CELL_ITEMS, CELL_USERS = 15_200_000, 1_000_000
 
 
 @pytest.fixture(scope="module")
@@ -48,19 +52,21 @@ def v5e():
     return topo.devices
 
 
-def _catalog_shape():
-    """(padded rows, padded lanes, tile) of the smoke's catalog, by the
-    retriever's own rule."""
-    padded, tile_n = _pad_items(np.zeros((N_ITEMS, RANK), np.float32),
-                                N_ITEMS, 512)
-    return (*padded.shape, tile_n)
-
-
-def _compile_fused(dev, b_pad, k_pad):
-    n_pad, d_pad, tile_n = _catalog_shape()
-    cap = _capacity(N_USERS)
+def _compile_kernel(dev, n_items, b_pad, k_pad):
+    n_pad, d_pad = _padded_shape(n_items, RANK)
     one = SingleDeviceSharding(dev)
-    raw = _raw_call(b_pad, d_pad, n_pad, N_ITEMS, k_pad, tile_n, False)
+    return jax.jit(_raw_call(b_pad, d_pad, n_pad, n_items, k_pad,
+                             False)).lower(
+        jax.ShapeDtypeStruct((b_pad, d_pad), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((n_pad, d_pad), jnp.float32, sharding=one),
+    ).compile()
+
+
+def _compile_fused(dev, n_items, n_users, b_pad, k_pad):
+    n_pad, d_pad = _padded_shape(n_items, RANK)
+    cap = _capacity(n_users)
+    one = SingleDeviceSharding(dev)
+    raw = _raw_call(b_pad, d_pad, n_pad, n_items, k_pad, False)
     # as ServingPipeline._exec_fused builds it on a TPU: donating
     return jax.jit(_fused_fn(raw, True), donate_argnums=(0,)).lower(
         jax.ShapeDtypeStruct((b_pad,), jnp.int32, sharding=one),
@@ -69,28 +75,46 @@ def _compile_fused(dev, b_pad, k_pad):
     ).compile()
 
 
+def _assert_one_kernel_and_the_packed_result(exe, b_pad, k_pad):
+    hlo = exe.as_text()
+    # exactly one Mosaic kernel a dispatch: the benchmark's kernel time
+    # and rooflines count `custom-call` operations per call
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
+    # values, rows and the kernel's three counters in one buffer
+    assert (exe.memory_analysis().output_size_in_bytes
+            >= b_pad * (2 * k_pad + 3) * 4)
+
+
 def test_fused_pipeline_program_over_the_prewarm_lattice(v5e):
     """`pio deploy` prewarms b_pad 8..128 at the k_pad of num=10; each is
     gather + the native Pallas kernel + packing in ONE program."""
     for b in (1, 8, 16, 32, 64, 128):
         b_pad, k_pad = _query_shapes(b, 10, N_ITEMS)
-        exe = _compile_fused(v5e[0], b_pad, k_pad)
-        assert "tpu_custom_call" in exe.as_text()  # Mosaic's kernel is in it
-        assert (exe.memory_analysis().output_size_in_bytes
-                >= b_pad * 2 * k_pad * 4)
+        exe = _compile_fused(v5e[0], N_ITEMS, N_USERS, b_pad, k_pad)
+        _assert_one_kernel_and_the_packed_result(exe, b_pad, k_pad)
+
+
+@pytest.mark.parametrize("b_pad", [8, 32, 128])
+def test_topk_kernel_at_the_serving_cells_shape(v5e, b_pad):
+    """15.2 M items padded to 128 lanes, k_pad 16: the kernel alone and
+    inside the fused program, shapes only. What Mosaic refuses at this
+    size (a dynamic loop, an unaligned store, a tile over the VMEM
+    budget) fails here, not on the chip."""
+    n_pad, d_pad = _padded_shape(CELL_ITEMS, RANK)
+    tile, chunk = _tile_rows(b_pad, d_pad, 16, n_pad)
+    assert 2048 <= tile <= 8192 and n_pad % tile == 0 and tile % chunk == 0
+    _compile_kernel(v5e[0], CELL_ITEMS, b_pad, 16)
+    exe = _compile_fused(v5e[0], CELL_ITEMS, CELL_USERS, b_pad, 16)
+    _assert_one_kernel_and_the_packed_result(exe, b_pad, 16)
+    # the catalog, the user table and little else
+    assert exe.memory_analysis().argument_size_in_bytes > 8.3e9
 
 
 def test_topk_kernel_at_a_large_k(v5e):
     """A client may ask for hundreds: num=500 pads to k_pad 504, and the
-    kernel's [B, k] accumulator block and merge buffers grow with it."""
-    n_pad, d_pad, tile_n = _catalog_shape()
-    one = SingleDeviceSharding(v5e[0])
+    kept lists (four lane groups wide) take room beside the tile."""
     for b_pad, k_pad in ((8, 504), (128, 504)):
-        jax.jit(_raw_call(b_pad, d_pad, n_pad, N_ITEMS, k_pad, tile_n,
-                          False)).lower(
-            jax.ShapeDtypeStruct((b_pad, d_pad), jnp.float32, sharding=one),
-            jax.ShapeDtypeStruct((n_pad, d_pad), jnp.float32, sharding=one),
-        ).compile()
+        _compile_kernel(v5e[0], N_ITEMS, b_pad, k_pad)
 
 
 @pytest.mark.parametrize("n_dev", [1, 4])
