@@ -123,7 +123,11 @@ class span:
     ``with span(...) as s: s["rows"] = 7``.
 
     A span wraps a synchronous block on one thread, never an ``await``:
-    the annotation belongs to the thread that entered it."""
+    the annotation belongs to the thread that entered it. The one
+    exception is ``serve.cut_held`` (workflow/microbatch.py), entered and
+    left on the event loop's thread around a wait: the profiler records an
+    event whole when it ends, and the loop's other spans are synchronous
+    blocks, so they lie inside that interval or outside it."""
 
     __slots__ = ("name", "sink", "trace", "level", "facts", "t0", "t1",
                  "_annotation")

@@ -29,8 +29,13 @@ on the chip.
 
 * **Double-buffered staging** — each b_pad lattice point owns two
   pinned int32 staging buffers. Batch N+1's host assembly fills one
-  while batch N's device step holds the other; a third concurrent
-  dispatch (or a hung swap — chaos site ``pipeline.swap``) falls back
+  while batch N's device step holds the other. ``STAGING_DEPTH`` is
+  also how many batches are worth having ahead of the device, and the
+  one constant for it: whoever feeds this pipeline (the micro-batcher
+  does) reads the depth here and can be told, through
+  ``set_step_end_hook``, when a device step ends (where ``in_device``
+  falls). A third concurrent dispatch (a caller that bounds nothing, or
+  a hung swap — chaos site ``pipeline.swap``) falls back
   to a transient buffer, so a wedged handoff degrades through the
   micro-batcher's watchdog without poisoning the pinned pool. The
   BatchClock stage fence (obs/waterfall.py) marks host_assembly /
@@ -61,6 +66,8 @@ from __future__ import annotations
 import logging
 import threading
 import time
+from contextvars import ContextVar
+from typing import Callable
 
 import numpy as np
 
@@ -104,8 +111,33 @@ _M_DONATED = METRICS.counter(
 #: a hung pipeline.swap handoff stall HEALTHY batches behind it.
 STAGING_WAIT_S = 0.002
 
-#: Pinned staging buffers per b_pad lattice point (the double buffer).
+#: Pinned staging buffers per b_pad lattice point (the double buffer):
+#: one for the batch in its device step and one for the batch behind it.
+#: More batches than this ahead of the device only queue there.
 STAGING_DEPTH = 2
+
+_STEP_END: ContextVar[Callable[[], None] | None] = ContextVar(
+    "pio_device_step_end", default=None)
+
+
+def set_step_end_hook(hook: Callable[[], None] | None):
+    """Install ``hook`` for this context (a dispatch worker thread): it
+    is called, on that thread, where a batch's device step ends. ``None``
+    mutes it for a batch that has another step to come. Returns the reset
+    token, like the stage sink's."""
+    return _STEP_END.set(hook)
+
+
+def reset_step_end_hook(token) -> None:
+    _STEP_END.reset(token)
+
+
+def device_step_ended() -> None:
+    """A batch's device step is over on this thread: tell whoever asked
+    (nobody, outside a dispatch that installed a hook)."""
+    hook = _STEP_END.get()
+    if hook is not None:
+        hook()
 
 
 def _capacity(n_rows: int) -> int:
@@ -374,6 +406,7 @@ class ServingPipeline:
             if in_device:
                 with st.cond:
                     st.advance(-1)
+                device_step_ended()
         with stage_span("result_scatter", **facts):
             vals, idx, counts = _unpack(out, is_packed, b, k_eff, k_pad)
         if counts is not None:
@@ -401,8 +434,12 @@ class ServingPipeline:
             with st.cond:
                 st.advance(-1)
         # the retriever's _dispatch_topk fences the stage waterfall
-        # itself and re-pads lanes (a no-op: the gather already padded)
-        return self._retriever.topk(np.asarray(qdev)[:b], k)
+        # itself and re-pads lanes (a no-op: the gather already padded);
+        # its scan, not the gather, is this batch's device step
+        try:
+            return self._retriever.topk(np.asarray(qdev)[:b], k)
+        finally:
+            device_step_ended()
 
     # -- lifecycle -----------------------------------------------------
 

@@ -2304,9 +2304,12 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--batch-max", type=int, default=128,
                     help="max queries per micro-batch")
     sp.add_argument("--batch-inflight", type=int, default=8,
-                    help="max micro-batches dispatched concurrently "
-                         "(overlaps one batch's host work with another's "
-                         "device call)")
+                    help="max batched calls live at once, those in their "
+                         "host work after the device step included "
+                         "(degraded mode halves it). Not the depth of the "
+                         "device's queue: a batch is cut only while fewer "
+                         "than two are short of the end of their device "
+                         "step")
     sp.add_argument("--retriever-mesh", default="0",
                     help="shard the serving catalog over this many devices "
                          "(model axis; 0/1 = single-device catalog; 'auto' "

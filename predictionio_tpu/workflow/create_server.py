@@ -74,6 +74,7 @@ from ..obs.startup import STARTUP
 from ..obs.trace import TRACE_HEADER, ensure_request_id, span, trace_event
 from ..obs.waterfall import (Waterfall, mark_stage, reset_stage_sink,
                              set_stage_sink, stage_span, stage_summary)
+from ..ops.pipeline import reset_step_end_hook, set_step_end_hook
 from ..storage import EngineInstance, Storage
 from .admission import AdmissionController
 from .faults import FAULTS
@@ -976,6 +977,10 @@ class EngineServer:
                 decoded.append((i, q))
             preds: dict[int, Any] = {}
             if decoded:
+                # only the batch's LAST device step may open the
+                # micro-batcher's gate: an earlier algorithm's is muted
+                muted = (set_step_end_hook(None)
+                         if ai < len(result.algorithms) - 1 else None)
                 try:
                     preds = dict(algo.batch_predict(model, decoded))
                 except Exception:  # noqa: BLE001
@@ -987,6 +992,9 @@ class EngineServer:
                             preds[i] = algo.predict(model, q)
                         except Exception as e:  # noqa: BLE001
                             errors[i] = e
+                finally:
+                    if muted is not None:
+                        reset_step_end_hook(muted)
             per_algo.append(preds)
 
         outcomes: list[tuple[str, Any]] = []

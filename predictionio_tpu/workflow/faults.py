@@ -13,7 +13,7 @@ Sites instrumented in this repo:
 
 - ``microbatch.dispatch``   — inside the dispatch worker thread, before
   ``batch_fn`` runs (a hang here is a hung device call holding one of
-  ``max_inflight`` pipeline slots)
+  ``max_inflight`` live calls and a place ahead of the device)
 - ``retrieval.topk``        — the shared top-k entry every retriever
   funnels through (``ops/retrieval._dispatch_topk``)
 - ``server.serve_batch``    — head of ``EngineServer.serve_query_batch``

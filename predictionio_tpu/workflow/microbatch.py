@@ -23,13 +23,29 @@ ceiling); under load the window stretches toward the time it takes
 ``max_batch`` arrivals to accumulate, capped at the ceiling. Arrival
 order is still preserved — only the sleep length changes.
 
-Batches are PIPELINED: up to ``max_inflight`` batches may be dispatched
-concurrently, so batch N+1's host work (decode, staging, result scatter)
-overlaps batch N's device call instead of waiting behind it. How much of
-a request is host work and how much is the device is not measured on the
-chip. Batch FORMATION stays on
-one loop (arrival order and the window are preserved, so single-query p50
-is unchanged); only the serve calls overlap, bounded by a semaphore.
+Batches are PIPELINED, two deep: a batch is cut only while fewer than
+``STAGING_DEPTH`` (the serving pipeline's double buffer,
+``ops/pipeline.py``) cut batches are short of the end of their device
+step, one running on the device and one behind it, so that the device
+never waits for the host and a query never waits behind more scans than
+that. A place is held from the cut, not from the dispatch: the batch's
+host work before its step stands ahead of the device too, and what is
+cut early rides an earlier, emptier scan. Until the gate opens the
+forming batch keeps filling from the queue. The end of a device step
+opens it: the pipeline reports it on the dispatch worker thread
+(``ops.pipeline.set_step_end_hook``), and the batcher is woken on its
+loop. Host work after the step (result scatter, the answers' JSON)
+holds no place ahead of the device and overlaps the next batch's device
+step. A ``batch_fn`` that reports nothing (the legacy serving path,
+host-scored engines, a plain callable) is gated on its whole call: the
+same rule with the only signal there is.
+
+``max_inflight`` is not the depth of that queue. It is the gate's other
+count: the calls that may be live at once, those still in their host
+work after the step included, so that a slow scatter cannot pile worker
+threads up without bound; degraded mode halves it. Batch
+FORMATION stays on one loop (arrival order and the window are preserved,
+so single-query p50 is unchanged); only the serve calls overlap.
 Completions may land out of order; each query's future resolves
 individually, so callers never observe reordering.
 
@@ -48,9 +64,11 @@ from typing import Any, Callable, Sequence
 
 from ..obs.flight import FLIGHT
 from ..obs.metrics import METRICS
-from ..obs.trace import current_request_id, trace_event
+from ..obs.trace import current_request_id, span, trace_event
 from ..obs.waterfall import (BatchClock, current_sink, reset_stage_sink,
                              set_stage_sink, stage_span)
+from ..ops.pipeline import (STAGING_DEPTH, reset_step_end_hook,
+                            set_step_end_hook)
 from .faults import FAULTS
 
 log = logging.getLogger("predictionio_tpu.server")
@@ -66,6 +84,10 @@ _M_QUEUE_WAIT = METRICS.histogram(
 _M_WINDOW = METRICS.histogram(
     "pio_microbatch_window_seconds",
     "coalescing window chosen per formed batch (adaptive: EWMA-scaled)")
+_M_CUT_HELD = METRICS.histogram(
+    "pio_microbatch_cut_held_seconds",
+    "time the formation loop waited, with queries queued, for the gate "
+    "to open (one observation per wait)")
 _M_DISPATCH = METRICS.histogram(
     "pio_microbatch_dispatch_seconds",
     "wall time of one batched dispatch (thread hop + device call)")
@@ -100,9 +122,10 @@ class DeadlineExceeded(RuntimeError):
 
 class DispatchTimeout(RuntimeError):
     """A dispatched batch exceeded the stuck-dispatch watchdog timeout.
-    Its semaphore slot is reclaimed (the hung worker thread is tracked as
-    a zombie), its queries 504, and the on_watchdog hook fires so the
-    server can flip into degraded mode."""
+    Its place ahead of the device and its count against ``max_inflight``
+    are given back (the hung worker thread is tracked as a zombie), its
+    queries 504, and the on_watchdog hook fires so the server can flip
+    into degraded mode."""
 
 
 class MicroBatcher:
@@ -127,10 +150,10 @@ class MicroBatcher:
         self.max_inflight = max(1, max_inflight)
         self.adaptive = adaptive
         #: stuck-dispatch watchdog: a batch_fn call exceeding this wall
-        #: time has its futures failed (DispatchTimeout) and its
-        #: semaphore slot reclaimed; the thread keeps running as a
+        #: time has its futures failed (DispatchTimeout) and its places
+        #: in the gate given back; the thread keeps running as a
         #: tracked zombie (to_thread work cannot be interrupted). None
-        #: disables (pre-watchdog behavior: a hang wedges a slot forever).
+        #: disables (pre-watchdog behavior: a hang wedges a place forever).
         self.dispatch_timeout_s = dispatch_timeout_s
         #: called (no args, on the event loop) after each watchdog trip —
         #: the engine server hooks degraded mode here
@@ -146,13 +169,18 @@ class MicroBatcher:
         self._pending: list[tuple] = []
         self._wake: asyncio.Event | None = None
         self._task: asyncio.Task | None = None
-        self._sem: asyncio.Semaphore | None = None
         self._inflight: set[asyncio.Task] = set()
-        self._live = 0  # dispatches currently holding a semaphore slot
+        # the gate: a batch is cut only while _cut_allowed()
+        self._gate: asyncio.Event | None = None  # set when it opens
+        self._ahead = 0  # cut batches short of their device step's end
+        self._live = 0  # cut batches whose call has not returned
+        self._open = True
+        self._opened_at = float("-inf")  # monotonic; last closed -> open
         self._zombies = 0  # hung batch_fn threads the watchdog abandoned
         self._closing = False
         # observability: how well batching + pipelining are working
         self.batches = 0
+        self.cuts_held = 0  # of them, those the gate held (see _launch)
         self.batched_queries = 0
         self.max_seen_batch = 0
         self.peak_inflight = 0
@@ -167,7 +195,7 @@ class MicroBatcher:
     def _ensure_started(self) -> None:
         if self._task is None or self._task.done():
             self._wake = asyncio.Event()
-            self._sem = asyncio.Semaphore(self.max_inflight)
+            self._gate = asyncio.Event()
             self._task = asyncio.create_task(self._run())
 
     async def submit(self, query: Any, *, deadline: float | None = None) -> Any:
@@ -222,6 +250,29 @@ class MicroBatcher:
         self._wake.set()
         return await fut
 
+    def _depth(self) -> int:
+        """Batches that are served at once: a dispatch's wall time spans
+        this many device steps, so the queue drains by one batch every
+        ``_ewma_dispatch_s`` over it."""
+        return min(STAGING_DEPTH, self.max_inflight)
+
+    def _cut_allowed(self) -> bool:
+        """The gate is open: a batch formed now would be cut now."""
+        return (self._ahead < STAGING_DEPTH
+                and self._live < self.max_inflight)
+
+    def _gate_moved(self) -> None:
+        """After every change to what the gate counts, on the loop: an
+        opening wakes the formation loop and is stamped (a batch whose
+        oldest query was queued before that instant was held by the
+        gate)."""
+        is_open = self._cut_allowed()
+        if is_open and not self._open:
+            self._opened_at = time.monotonic()
+            if self._gate is not None:
+                self._gate.set()
+        self._open = is_open
+
     def _estimate_sojourn_s(self) -> float:
         """Expected queue wait for a query enqueued now: the number of
         pipeline waves the queued-ahead batches need, times the EWMA
@@ -231,9 +282,10 @@ class MicroBatcher:
         if self._ewma_dispatch_s is None or len(self._pending) < self.max_batch:
             return 0.0
         batches_ahead = len(self._pending) // self.max_batch
-        waves = (batches_ahead + self.max_inflight - 1) // self.max_inflight
-        # + partial wave when every pipeline slot is already busy
-        if self._live >= self.max_inflight:
+        depth = self._depth()
+        waves = (batches_ahead + depth - 1) // depth
+        # + partial wave when the next batch has to wait for its cut
+        if not self._cut_allowed():
             waves += 1
         return waves * self._ewma_dispatch_s
 
@@ -243,7 +295,7 @@ class MicroBatcher:
         admission controller sizes Retry-After from this."""
         if self._ewma_dispatch_s is None or self._ewma_dispatch_s <= 0:
             return None
-        return self.max_batch * self.max_inflight / self._ewma_dispatch_s
+        return self.max_batch * self._depth() / self._ewma_dispatch_s
 
     def _note_arrival(self, now: float) -> None:
         if self._last_arrival is not None:
@@ -256,7 +308,7 @@ class MicroBatcher:
     def _choose_window(self, now: float) -> float:
         """Window for the batch about to form: 0 when waiting can't help
         (batch already full, no rate history, or arrivals slower than the
-        ceiling with pipeline slots free), else the time ``need`` more
+        ceiling with the gate open), else the time ``need`` more
         arrivals are expected to take, capped at the ``window_s`` ceiling."""
         if not self.adaptive:
             return self.window_s
@@ -267,9 +319,9 @@ class MicroBatcher:
         if self._last_arrival is not None:
             # a fresh idle gap overrides a stale burst-rate estimate
             iv = max(iv, now - self._last_arrival)
-        if iv >= self.window_s and self._live < self.max_inflight:
-            # a window can't fill a batch at this rate; with the pipeline
-            # saturated waiting is free, otherwise dispatch now
+        if iv >= self.window_s and self._cut_allowed():
+            # a window can't fill a batch at this rate; with the gate
+            # closed waiting is free, otherwise dispatch now
             return 0.0
         w = min(self.window_s, need * iv)
         # Deadline headroom clamp (ISSUE 16 satellite): when EVERY
@@ -286,13 +338,11 @@ class MicroBatcher:
         return w
 
     def set_max_inflight(self, n: int) -> None:
-        """Resize the dispatch pipeline (degraded mode shrinks it, recovery
-        restores it). Takes effect on the next batch formation: each formed
-        batch captures the semaphore generation it acquired from, so
-        straddling dispatches release the slot they actually hold."""
+        """Resize the bound on calls live at once (degraded mode shrinks
+        it, recovery restores it; at 1 the calls run one at a time).
+        Takes effect on the next cut; calls already live run on."""
         self.max_inflight = max(1, n)
-        if self._sem is not None:
-            self._sem = asyncio.Semaphore(self.max_inflight)
+        self._gate_moved()
 
     async def close(self) -> None:
         """Hard stop: cancel the worker, let in-flight batches finish,
@@ -324,12 +374,8 @@ class MicroBatcher:
                 while self._pending:
                     batch = self._pending[: self.max_batch]
                     del self._pending[: len(batch)]
-                    sem = self._sem
-                    assert sem is not None  # pending implies started
-                    await sem.acquire()
-                    task = asyncio.create_task(self._dispatch(batch, sem))
-                    self._inflight.add(task)
-                    task.add_done_callback(self._inflight.discard)
+                    await self._admit()
+                    self._launch(batch)
             # let dispatched batches finish — their queries already left
             # the queue and their callers are awaiting results; to_thread
             # work cannot be interrupted anyway
@@ -374,7 +420,7 @@ class MicroBatcher:
     async def _run(self) -> None:
         """Batch-formation loop: serializes windowing + arrival order,
         hands each formed batch to a concurrent dispatch task."""
-        assert self._wake is not None and self._sem is not None
+        assert self._wake is not None
         while True:
             await self._wake.wait()
             w = self._choose_window(time.monotonic())
@@ -385,28 +431,61 @@ class MicroBatcher:
                 await asyncio.sleep(w)
             # expired queries 504 here, before a slot is spent on them
             self._sweep_expired(time.monotonic())
-            # bound in-flight BEFORE taking queries off the queue, so a
-            # saturated pipeline backpressures into max_pending/503 land
-            # instead of stripping the queue into waiting tasks. Capture
-            # THIS generation's semaphore: set_max_inflight (degraded
-            # mode) swaps self._sem mid-run, and a straddling dispatch
-            # must release the slot it actually acquired.
-            sem = self._sem
-            await sem.acquire()
-            self._sweep_expired(time.monotonic())  # slot waits take time
+            # wait for the gate BEFORE taking queries off the queue: the
+            # forming batch keeps filling meanwhile, and a saturated
+            # pipeline backpressures into max_pending/503 land instead of
+            # stripping the queue into waiting tasks
+            await self._admit()
+            self._sweep_expired(time.monotonic())  # the wait takes time
             batch = self._pending[: self.max_batch]
             del self._pending[: len(batch)]
             if not self._pending:
                 self._wake.clear()
-            if not batch:
-                sem.release()
-                continue
-            task = asyncio.create_task(self._dispatch(batch, sem))
-            self._inflight.add(task)
-            task.add_done_callback(self._inflight.discard)
+            if batch:
+                self._launch(batch)
+
+    async def _admit(self) -> None:
+        """Wait until a batch may be cut. The end of a device step (or
+        of a call) opens the gate and wakes this loop; nothing polls."""
+        if self._cut_allowed():
+            return
+        assert self._gate is not None
+        # the one span that crosses an await: every other span on the
+        # loop's thread is a synchronous block, so it lies inside this
+        # interval or outside it
+        with span("serve.cut_held", level=logging.DEBUG,
+                  pending=len(self._pending)) as wait:
+            while not self._cut_allowed():
+                self._gate.clear()
+                await self._gate.wait()
+        _M_CUT_HELD.record(wait.t1 - wait.t0)
+
+    def _launch(self, batch: list[tuple]) -> None:
+        """Cut: the batch takes its place ahead of the device, counts as
+        live, and its dispatch task starts. The place is given back once,
+        by whichever comes first: the device step's end, the call's end,
+        the watchdog. The cut was held if the gate was closed at any time
+        since the batch's oldest query was queued."""
+        held = len(batch[0]) > 3 and batch[0][3] < self._opened_at
+        self._ahead += 1
+        self._live += 1
+        self.peak_inflight = max(self.peak_inflight, self._live)
+        self._gate_moved()
+        released = False
+
+        def release() -> None:  # on the loop
+            nonlocal released
+            if not released:
+                released = True
+                self._ahead -= 1
+                self._gate_moved()
+
+        task = asyncio.create_task(self._dispatch(batch, release, held))
+        self._inflight.add(task)
+        task.add_done_callback(self._inflight.discard)
 
     def _call_batch_fn(self, queries: list, clock: BatchClock | None = None,
-                       ) -> list:
+                       step_end: Callable[[], None] | None = None) -> list:
         """Runs in the dispatch worker thread; the chaos harness's hang/
         error/slow site for 'a device call wedged' lives here so an
         injected hang occupies the thread exactly like a real one.
@@ -416,7 +495,10 @@ class MicroBatcher:
         private context copy) so serve_query_batch/_dispatch_topk marks
         land on the batch clock, not on any one member's waterfall. The
         fault site fires BEFORE the first mark: a hang here shows up as
-        stalled before any stage completed (stalledStage=batch_form)."""
+        stalled before any stage completed (stalledStage=batch_form).
+        ``step_end`` is what the serving pipeline calls on this thread
+        where the batch's device step ends."""
+        hook = set_step_end_hook(step_end)
         token = set_stage_sink(clock) if clock is not None else None
         try:
             # batch cut -> worker thread running; the span is the
@@ -431,6 +513,7 @@ class MicroBatcher:
         finally:
             if token is not None:
                 reset_stage_sink(token)
+            reset_step_end_hook(hook)
 
     def _zombie_done(self, task: asyncio.Task) -> None:
         self._zombies -= 1
@@ -444,14 +527,23 @@ class MicroBatcher:
                      "(%d zombie(s) left)", self._zombies)
 
     async def _dispatch(self, batch: list[tuple[Any, asyncio.Future, Any]],
-                        sem: asyncio.Semaphore) -> None:
-        """Serve ONE formed batch; owns (and releases) one slot of the
-        semaphore it was formed under. With dispatch_timeout_s set, a
-        batch_fn call that outlives the watchdog has its futures failed
-        (504) and its slot reclaimed; the un-interruptible worker thread
-        is tracked as a zombie until it returns."""
-        self._live += 1
-        self.peak_inflight = max(self.peak_inflight, self._live)
+                        release: Callable[[], None], held: bool) -> None:
+        """Serve ONE formed batch; gives back what ``_launch`` took: the
+        place ahead of the device (``release``) from the end of the
+        device step at the earliest to the end of this call at the
+        latest, the count against ``max_inflight`` at the end of this
+        call. With dispatch_timeout_s set, a batch_fn call that outlives
+        the watchdog has its futures failed (504) and both given back;
+        the un-interruptible worker thread is tracked as a zombie until
+        it returns."""
+        loop = asyncio.get_running_loop()
+
+        def step_end() -> None:  # on the worker thread
+            try:
+                loop.call_soon_threadsafe(release)
+            except RuntimeError:
+                pass  # the loop is closed: no batch is left to cut
+
         t_start = time.monotonic()
         traces = [t[4] for t in batch if len(t) > 4 and t[4]]
         wfs = [t[5] for t in batch if len(t) > 5 and t[5] is not None]
@@ -465,7 +557,8 @@ class MicroBatcher:
         try:
             queries = [t[0] for t in batch]
             inner = asyncio.ensure_future(
-                asyncio.to_thread(self._call_batch_fn, queries, clock))
+                asyncio.to_thread(self._call_batch_fn, queries, clock,
+                                  step_end))
             try:
                 if self.dispatch_timeout_s is not None:
                     # shield: on timeout the outer wait is cancelled but
@@ -525,6 +618,7 @@ class MicroBatcher:
                 for wf in wfs:
                     wf.merge_batch(clock)
             self.batches += 1
+            self.cuts_held += held
             self.batched_queries += len(batch)
             self.max_seen_batch = max(self.max_seen_batch, len(batch))
             dispatch_s = time.monotonic() - t_start
@@ -543,11 +637,13 @@ class MicroBatcher:
                     fut.set_exception(payload)
         finally:
             self._live -= 1
-            sem.release()
+            release()
+            self._gate_moved()
 
     def stats(self) -> dict:
         return {
             "batches": self.batches,
+            "cutsHeld": self.cuts_held,
             "batchedQueries": self.batched_queries,
             "avgBatchSize": (self.batched_queries / self.batches) if self.batches else 0.0,
             "maxBatchSize": self.max_seen_batch,
@@ -557,6 +653,7 @@ class MicroBatcher:
             "windowCeilingMs": self.window_s * 1e3,
             "lastWindowMs": self.last_window_s * 1e3,
             "inflight": self._live,
+            "aheadOfDevice": self._ahead,
             "occupancy": self._live / self.max_inflight,
             "arrivalIntervalMs": (self._ewma_iv * 1e3
                                   if self._ewma_iv is not None else None),

@@ -10,11 +10,32 @@ import asyncio
 import numpy as np
 import pytest
 
+from predictionio_tpu.ops.pipeline import STAGING_DEPTH, device_step_ended
 from predictionio_tpu.workflow.microbatch import MicroBatcher
 
 
 def run(coro):
     return asyncio.new_event_loop().run_until_complete(coro)
+
+
+class _Peak:
+    """``with peak:`` around a batch_fn's body: the most calls that were
+    inside it at once."""
+
+    def __init__(self):
+        import threading
+
+        self._lock = threading.Lock()
+        self.live = self.peak = 0
+
+    def __enter__(self):
+        with self._lock:
+            self.live += 1
+            self.peak = max(self.peak, self.live)
+
+    def __exit__(self, *exc):
+        with self._lock:
+            self.live -= 1
 
 
 class TestMicroBatcher:
@@ -95,70 +116,49 @@ class TestMicroBatcher:
         assert s["batchedQueries"] == 8
         assert s["avgBatchSize"] >= 1.0
 
-    def test_pipelines_batches_concurrently(self):
-        """With a slow batch_fn (simulating the ~65 ms dispatch round
-        trip) and max_inflight > 1, batch N+1 must dispatch while batch N
-        is still in the air — wall clock ~= ceil(B / inflight) * RTT, not
-        B * RTT."""
-        import threading
+    @pytest.mark.parametrize("signals,max_inflight,want_peak", [
+        # a plain callable: the whole call is its device step, so two
+        # calls overlap and never a third, whatever the thread bound
+        (False, 4, STAGING_DEPTH),
+        # a batch_fn that reports its device step's end: the host work
+        # after it holds no place in the gate, so calls overlap up to the
+        # thread bound
+        (True, 4, 4),
+        (True, 3, 3),
+        # the thread bound below the gate's depth: it binds alone
+        (False, 1, 1),
+    ])
+    def test_calls_overlap_up_to_gate_and_thread_bound(
+            self, signals, max_inflight, want_peak):
+        """Batch N+1 dispatches while batch N is in the air, and no more
+        batch_fn calls run at once than the gate and ``max_inflight``
+        allow (was: test_pipelines_batches_concurrently,
+        test_inflight_bounded)."""
         import time
 
-        live = 0
-        peak = 0
-        lock = threading.Lock()
+        calls = _Peak()
 
         def slow_batch(queries):
-            nonlocal live, peak
-            with lock:
-                live += 1
-                peak = max(peak, live)
-            time.sleep(0.05)  # the "round trip"
-            with lock:
-                live -= 1
+            with calls:
+                time.sleep(0.01)  # the "device step"
+                if signals:
+                    device_step_ended()
+                time.sleep(0.04)  # host work after it
             return [("ok", q) for q in queries]
 
         async def main():
             mb = MicroBatcher(slow_batch, max_batch=2, window_s=0.0,
-                              max_inflight=4)
-            t0 = time.perf_counter()
+                              max_inflight=max_inflight)
             out = await asyncio.gather(*[mb.submit(i) for i in range(16)])
-            dt = time.perf_counter() - t0
+            s = mb.stats()
             await mb.close()
-            return out, dt
+            return out, s
 
-        out, dt = run(main())
+        out, s = run(main())
         assert out == list(range(16))
-        # 8 batches of 2 at 50 ms each: serial ~0.4 s, 4-deep pipeline ~0.1 s
-        assert peak >= 3, f"batches never overlapped (peak inflight {peak})"
-        assert dt < 0.3, f"pipelining did not cut wall time ({dt:.3f}s)"
-
-    def test_inflight_bounded(self):
-        """No more than max_inflight batch_fn calls run at once."""
-        import threading
-        import time
-
-        live = 0
-        peak = 0
-        lock = threading.Lock()
-
-        def slow_batch(queries):
-            nonlocal live, peak
-            with lock:
-                live += 1
-                peak = max(peak, live)
-            time.sleep(0.02)
-            with lock:
-                live -= 1
-            return [("ok", q) for q in queries]
-
-        async def main():
-            mb = MicroBatcher(slow_batch, max_batch=1, window_s=0.0,
-                              max_inflight=2)
-            await asyncio.gather(*[mb.submit(i) for i in range(10)])
-            await mb.close()
-
-        run(main())
-        assert peak <= 2, f"inflight bound violated (peak {peak})"
+        assert calls.peak == want_peak
+        assert s["peakInflight"] == want_peak
+        assert s["aheadOfDevice"] == 0 and s["inflight"] == 0
 
     def test_out_of_order_completion_resolves_correct_futures(self):
         """Batch completions landing out of order must still resolve each
@@ -239,6 +239,367 @@ class TestMicroBatcher:
             return await t
 
         assert run(main()) == 7
+
+
+
+class _Steps:
+    """A batch_fn whose device step is a controllable event: call ``i``
+    stays in its device step until ``end_step(i)``, then (if it
+    ``signals``) reports the step's end, then stays in its host work
+    until ``end_call(i)``."""
+
+    def __init__(self, signals: bool = True, hold_calls: bool = False):
+        import threading
+
+        self.signals = signals
+        self.hold_calls = hold_calls
+        self.lock = threading.Lock()
+        self.calls: list[list] = []
+        self._steps: list = []
+        self._ends: list = []
+        self._Event = threading.Event
+
+    def __call__(self, queries):
+        step, end = self._Event(), self._Event()
+        with self.lock:
+            self.calls.append(list(queries))
+            self._steps.append(step)
+            self._ends.append(end)
+        assert step.wait(10), "test never ended this device step"
+        if self.signals:
+            device_step_ended()
+        if self.hold_calls:
+            assert end.wait(10), "test never ended this call"
+        return [("ok", q) for q in queries]
+
+    def end_step(self, i):
+        self._steps[i].set()
+
+    def end_call(self, i):
+        self._ends[i].set()
+
+    def end_all(self):
+        with self.lock:
+            for ev in self._steps + self._ends:
+                ev.set()
+
+    async def cut(self, n, timeout_s=5.0):
+        """Wait until ``n`` batches have been cut and reached batch_fn."""
+        import time
+
+        t_end = time.monotonic() + timeout_s
+        while len(self.calls) < n:
+            assert time.monotonic() < t_end, \
+                f"{len(self.calls)} batches cut, waited for {n}"
+            await asyncio.sleep(0.002)
+
+
+class TestDeviceGate:
+    """ISSUE 28: a batch is cut only while fewer than STAGING_DEPTH
+    cut batches are short of the end of their device step."""
+
+    def test_no_third_cut_and_arrivals_join_the_next_batch(self):
+        """(a) + (f): with two device steps outstanding no third batch
+        is cut; what arrives meanwhile lands in the ONE batch cut when a
+        step ends, and that cut counts as held."""
+        fn = _Steps()
+
+        async def main():
+            mb = MicroBatcher(fn, max_batch=64, window_s=0.0,
+                              max_inflight=8)
+            tasks = [asyncio.create_task(mb.submit(0))]
+            await fn.cut(1)
+            tasks.append(asyncio.create_task(mb.submit(1)))
+            await fn.cut(2)
+            for q in (2, 3, 4):
+                tasks.append(asyncio.create_task(mb.submit(q)))
+                await asyncio.sleep(0.01)
+            await asyncio.sleep(0.05)
+            assert len(fn.calls) == 2, "a third batch was cut"
+            s = mb.stats()
+            # the gate is shut, and the thread bound's reading (what the
+            # admission controller is given) says two of eight
+            assert s["aheadOfDevice"] == 2 and s["occupancy"] == 0.25
+            assert len(mb._pending) == 3
+            fn.end_step(0)  # the running step ends: the gate opens
+            await fn.cut(3)
+            assert fn.calls[2] == [2, 3, 4]
+            fn.end_all()
+            out = await asyncio.gather(*tasks)
+            s = mb.stats()
+            await mb.close()
+            return out, s
+
+        try:
+            out, s = run(main())
+        finally:
+            fn.end_all()
+        assert out == [0, 1, 2, 3, 4]
+        assert s["batches"] == 3 and s["cutsHeld"] == 1
+        assert s["aheadOfDevice"] == 0
+
+    def test_lone_query_on_idle_batcher_is_cut_at_once(self):
+        """(b): the gate is open on an idle server, adaptive or not."""
+        fn = _Steps()
+
+        async def main(adaptive):
+            mb = MicroBatcher(fn, window_s=5.0 if adaptive else 0.0,
+                              adaptive=adaptive)
+            n = len(fn.calls)
+            t = asyncio.create_task(mb.submit("q"))
+            await fn.cut(n + 1, timeout_s=1.0)  # cut with nothing ended
+            fn.end_all()
+            assert await t == "q"
+            s = mb.stats()
+            await mb.close()
+            return s
+
+        for adaptive in (False, True):
+            s = run(main(adaptive))
+            assert s["cutsHeld"] == 0 and s["batches"] == 1
+
+    def test_gate_opens_on_step_end_not_call_end_without_polling(self):
+        """(c): two calls whose device steps have ended but whose host
+        work (a slow result_scatter) has not hold no place: later
+        batches are cut past them. And while the gate is closed the
+        formation loop sleeps on ONE wait: the step's end wakes it."""
+        fn = _Steps(hold_calls=True)
+
+        class CountingEvent(asyncio.Event):
+            waits = 0
+
+            async def wait(self):
+                self.waits += 1
+                return await super().wait()
+
+        async def main():
+            mb = MicroBatcher(fn, max_batch=64, window_s=0.0,
+                              max_inflight=8)
+            tasks = [asyncio.create_task(mb.submit(0))]
+            await fn.cut(1)
+            gate = mb._gate = CountingEvent()
+            tasks.append(asyncio.create_task(mb.submit(1)))
+            await fn.cut(2)
+            tasks.append(asyncio.create_task(mb.submit(2)))
+            await asyncio.sleep(0.1)  # gate closed all this while
+            assert len(fn.calls) == 2 and gate.waits == 1
+            fn.end_step(0)
+            fn.end_step(1)
+            await fn.cut(3)  # cut though calls 0 and 1 have not returned
+            assert gate.waits == 1
+            s = mb.stats()
+            assert s["inflight"] == 3 and s["aheadOfDevice"] == 1
+            tasks.append(asyncio.create_task(mb.submit(3)))
+            await fn.cut(4)  # and a fourth: only batch 2 is ahead
+            assert mb.stats()["inflight"] == 4
+            fn.end_all()
+            out = await asyncio.gather(*tasks)
+            await mb.close()
+            return out
+
+        try:
+            assert run(main()) == [0, 1, 2, 3]
+        finally:
+            fn.end_all()
+
+    def test_plain_callable_is_gated_on_the_whole_call(self):
+        """(d): with no signal from inside, the call's end is the
+        device step's end."""
+        fn = _Steps(signals=False, hold_calls=True)
+
+        async def main():
+            mb = MicroBatcher(fn, max_batch=64, window_s=0.0,
+                              max_inflight=8)
+            tasks = []
+            for q in (0, 1):
+                tasks.append(asyncio.create_task(mb.submit(q)))
+                await fn.cut(q + 1)
+            tasks.append(asyncio.create_task(mb.submit(2)))
+            fn.end_step(0)
+            fn.end_step(1)  # "device steps" over, but nothing said so
+            await asyncio.sleep(0.05)
+            assert len(fn.calls) == 2 and mb.stats()["aheadOfDevice"] == 2
+            fn.end_call(1)  # a call returns: its place is free
+            await fn.cut(3)
+            fn.end_all()
+            out = await asyncio.gather(*tasks)
+            s = mb.stats()
+            await mb.close()
+            return out, s
+
+        try:
+            out, s = run(main())
+        finally:
+            fn.end_all()
+        assert out == [0, 1, 2] and s["cutsHeld"] == 1
+
+    def test_hung_batches_trip_watchdog_and_free_the_gate(self):
+        """(e): two hung device steps close the gate; the watchdog 504s
+        them and frees both places, so the held batch is served; the
+        thread bound still halves and restores."""
+        from predictionio_tpu.workflow.microbatch import DispatchTimeout
+
+        fn = _Steps()
+
+        async def main():
+            trips = []
+            mb = MicroBatcher(fn, max_batch=64, window_s=0.0,
+                              max_inflight=4, dispatch_timeout_s=0.2,
+                              on_watchdog=lambda: trips.append(1))
+            hung = []
+            for q in (0, 1):
+                hung.append(asyncio.create_task(mb.submit(q)))
+                await fn.cut(q + 1)
+            held = asyncio.create_task(mb.submit(2))
+            await asyncio.sleep(0.05)
+            assert len(fn.calls) == 2  # held behind the two hung steps
+            got = await asyncio.gather(*hung, return_exceptions=True)
+            assert all(isinstance(e, DispatchTimeout) for e in got)
+            await fn.cut(3)  # the watchdog gave the places back
+            fn.end_step(2)
+            assert await asyncio.wait_for(held, 5) == 2
+            s = mb.stats()
+            assert s["watchdogTrips"] == 2 and trips == [1, 1]
+            assert s["zombieDispatches"] == 2 and s["aheadOfDevice"] == 0
+            mb.set_max_inflight(max(1, mb.max_inflight // 2))
+            assert mb.stats()["maxInflight"] == 2
+            mb.set_max_inflight(4)
+            assert mb.stats()["maxInflight"] == 4
+            fn.end_all()  # the zombies return; their late signal is moot
+            for _ in range(200):
+                if mb.stats()["zombieDispatches"] == 0:
+                    break
+                await asyncio.sleep(0.01)
+            s = mb.stats()
+            assert s["zombieDispatches"] == 0 and s["aheadOfDevice"] == 0
+            await mb.close()
+
+        try:
+            run(main())
+        finally:
+            fn.end_all()
+
+    def test_thread_bound_binds_when_host_work_after_the_step_piles_up(self):
+        """The gate's other count, at its default of 8: calls whose
+        device step is over but whose host work is not hold no place
+        ahead of the device, so only ``max_inflight`` keeps a slow
+        scatter from piling worker threads up. The ninth batch's cut
+        waits for a call to END, and counts as held."""
+        fn = _Steps(hold_calls=True)
+
+        async def main():
+            mb = MicroBatcher(fn, max_batch=1, window_s=0.0)
+            assert mb.max_inflight == 8
+            tasks = [asyncio.create_task(mb.submit(q)) for q in range(10)]
+            for i in range(8):
+                await fn.cut(i + 1)
+                fn.end_step(i)  # the step is over; the call lingers
+            await asyncio.sleep(0.05)
+            s = mb.stats()
+            assert len(fn.calls) == 8, "a ninth call went live"
+            assert s["inflight"] == 8 and s["aheadOfDevice"] == 0
+            assert s["occupancy"] == 1.0
+            fn.end_call(3)  # one call returns: one more batch is cut
+            await fn.cut(9)
+            await asyncio.sleep(0.05)
+            assert len(fn.calls) == 9
+            fn.end_all()
+            await fn.cut(10)
+            fn.end_all()
+            out = await asyncio.gather(*tasks)
+            s = mb.stats()
+            await mb.close()
+            return out, s
+
+        try:
+            out, s = run(main())
+        finally:
+            fn.end_all()
+        assert out == list(range(10))
+        # all ten were queued at once: every cut after the first two
+        # waited, six for a step's end and two for a call's
+        assert s["peakInflight"] == 8 and s["cutsHeld"] == 8
+
+    def test_cuts_count_as_held_by_their_queries_not_by_the_loops_turn(self):
+        """Two device steps end before the formation loop runs again (a
+        busy loop thread): it then cuts two batches in one turn and only
+        waits for the first. Both were held, because the oldest query of
+        each was queued while the gate was shut; a query that arrives
+        after the gate opened is not."""
+        import time
+
+        fn = _Steps()
+
+        async def main():
+            mb = MicroBatcher(fn, max_batch=1, window_s=0.0)
+            tasks = []
+            for q in (0, 1):
+                tasks.append(asyncio.create_task(mb.submit(q)))
+                await fn.cut(q + 1)
+            tasks += [asyncio.create_task(mb.submit(q)) for q in (2, 3)]
+            await asyncio.sleep(0.02)
+            assert len(fn.calls) == 2 and len(mb._pending) == 2
+            fn.end_step(0)
+            fn.end_step(1)
+            time.sleep(0.1)  # the loop is busy: both ends are queued
+            await fn.cut(4)
+            assert mb.stats()["aheadOfDevice"] == 2
+            fn.end_all()
+            await asyncio.gather(*tasks)
+            assert mb.stats()["cutsHeld"] == 2
+            tasks.append(asyncio.create_task(mb.submit(4)))  # gate open
+            await fn.cut(5)
+            fn.end_all()
+            out = await asyncio.gather(*tasks)
+            s = mb.stats()
+            await mb.close()
+            return out, s
+
+        try:
+            out, s = run(main())
+        finally:
+            fn.end_all()
+        assert out == [0, 1, 2, 3, 4]
+        assert s["batches"] == 5 and s["cutsHeld"] == 2
+
+    def test_drain_flushes_through_the_gate(self):
+        """drain() answers everything queued, two batches at a time."""
+        import time
+
+        calls = _Peak()
+
+        def slow_batch(queries):
+            with calls:
+                time.sleep(0.01)
+            return [("ok", q) for q in queries]
+
+        async def main():
+            mb = MicroBatcher(slow_batch, max_batch=1, window_s=5.0,
+                              max_inflight=8)
+            tasks = [asyncio.create_task(mb.submit(i)) for i in range(6)]
+            await asyncio.sleep(0.01)  # queued inside the long window
+            await mb.drain()
+            return await asyncio.gather(*tasks)
+
+        assert run(main()) == list(range(6))
+        assert calls.peak <= STAGING_DEPTH
+
+    def test_estimates_follow_the_gate_not_the_thread_bound(self):
+        """The CoDel sojourn and the admission controller's drain rate
+        count STAGING_DEPTH batches served at once, not
+        max_inflight."""
+        mb = MicroBatcher(lambda qs: [("ok", q) for q in qs],
+                          max_batch=4, max_inflight=8)
+        assert mb.drain_rate_per_s() is None
+        mb._ewma_dispatch_s = 0.02
+        assert mb.drain_rate_per_s() == pytest.approx(
+            4 * STAGING_DEPTH / 0.02)
+        mb._pending = [(i, None) for i in range(16)]  # 4 batches queued
+        assert mb._estimate_sojourn_s() == pytest.approx(2 * 0.02)
+        mb._ahead = STAGING_DEPTH  # gate closed: one more wave
+        assert mb._estimate_sojourn_s() == pytest.approx(3 * 0.02)
+        mb.set_max_inflight(1)  # degraded to one call at a time
+        assert mb.drain_rate_per_s() == pytest.approx(4 / 0.02)
 
 
 class TestAdaptiveWindow:
@@ -570,3 +931,67 @@ class TestShardedServingConcurrency:
 
         with ThreadPoolExecutor(max_workers=6) as ex:
             assert all(ex.map(hammer, range(6)))
+
+
+class _StepAlgorithm:
+    """Mixed into the sample algorithm: its batch_predict has a device
+    step, and says when it ends."""
+
+    order: list = []
+
+    def batch_predict(self, model, queries):
+        self.order.append(self.params.id)
+        device_step_ended()
+        return super().batch_predict(model, queries)
+
+
+def make_two_step_engine():
+    from predictionio_tpu.controller import Engine
+    from predictionio_tpu.testing.sample_engine import (
+        SampleAlgorithm, SampleDataSource, SamplePreparator, SampleQuery,
+        SampleServing)
+
+    class StepAlgorithm(_StepAlgorithm, SampleAlgorithm):
+        query_class = SampleQuery
+
+    return Engine(
+        data_source_classes=SampleDataSource,
+        preparator_classes=SamplePreparator,
+        algorithm_classes={"step": StepAlgorithm},
+        serving_classes=SampleServing,
+    )
+
+
+def test_only_a_batchs_last_device_step_opens_the_gate():
+    """An engine with two algorithms runs two device steps a batch: the
+    first one's end is muted, so the batcher's place is given back where
+    the batch's LAST step ends."""
+    from predictionio_tpu.controller import EngineParams
+    from predictionio_tpu.ops.pipeline import (reset_step_end_hook,
+                                               set_step_end_hook)
+    from predictionio_tpu.storage import Storage
+    from predictionio_tpu.testing.sample_engine import (
+        SampleAlgoParams, SampleDataSourceParams)
+    from predictionio_tpu.workflow import Context, run_train
+    from predictionio_tpu.workflow.create_server import EngineServer
+
+    engine = make_two_step_engine()
+    ep = EngineParams(
+        data_source_params=("", SampleDataSourceParams(id=0)),
+        algorithm_params_list=(("step", SampleAlgoParams(id=1)),
+                               ("step", SampleAlgoParams(id=2))),
+    )
+    iid = run_train(engine, ep, Context(),
+                    engine_factory="tests.test_microbatch:"
+                                   "make_two_step_engine")
+    server = EngineServer(engine,
+                          Storage.get_metadata().engine_instance_get(iid))
+    order = _StepAlgorithm.order
+    order.clear()
+    token = set_step_end_hook(lambda: order.append("gate"))
+    try:
+        out = server.serve_query_batch([{"q": 3}, {"q": 4}])
+    finally:
+        reset_step_end_hook(token)
+    assert [tag for tag, _ in out] == ["ok", "ok"]
+    assert order == [1, 2, "gate"]

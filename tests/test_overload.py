@@ -319,6 +319,50 @@ def _admission_server(**kw) -> EngineServer:
     return EngineServer(engine, inst, **kw)
 
 
+def test_two_batches_ahead_of_the_device_is_not_overload():
+    """ISSUE 28: at a light Poisson load whose batches overlap two deep
+    (the gate's normal working state: it holds most cuts) an
+    ``--admission`` server stays in ``normal``: queries stay on the
+    batcher and keep their full ``num``. The controller's ``inflight``
+    signal is the thread bound's reading, not the gate's fill."""
+    import random
+
+    server = _admission_server(batch_max=8)
+    assert server.batcher.max_inflight == 8
+    server.admission.sample_interval_s = 0.0  # judge on every request
+    modes: list[str] = []
+    set_mode = server._set_mode
+    server._set_mode = lambda m: (modes.append(m), set_mode(m))[1]
+    ahead: list[int] = []
+    FAULTS.inject("microbatch.dispatch", "slow", delay_s=0.03)
+    st = ServerThread(lambda: create_engine_server_app(server))
+    rnd = random.Random(28)
+
+    def caller():
+        for _ in range(25):
+            time.sleep(rnd.expovariate(1 / 0.02))  # ~50/s a caller
+            r = requests.post(st.url + "/queries.json", json={"q": 2},
+                              timeout=10)
+            assert r.status_code == 200
+            ahead.append(server.batcher.stats()["aheadOfDevice"])
+
+    try:
+        threads = [threading.Thread(target=caller) for _ in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        s = server.batcher.stats()
+        assert s["batchedQueries"] == 100  # none left for the fallback
+        assert max(ahead) == 2 and s["cutsHeld"] > 0  # they did overlap
+        assert modes == [] and server.mode == "normal"
+        assert server.admission.stats()["signals"]["inflight"] <= 0.5
+        assert server.brownout_degrade({"q": 2, "num": 50})["num"] == 50
+    finally:
+        FAULTS.clear()
+        st.stop()
+
+
 def test_mode_state_machine_unifies_brownout_and_degraded():
     server = _admission_server()
     adm = server.admission

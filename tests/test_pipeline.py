@@ -272,3 +272,113 @@ def test_hung_swap_holds_one_buffer_never_wedges_pool(rng):
     assert pipe.stats()["stagingFree"][b_pad] == STAGING_DEPTH
     pipe.topk_rows(rows, 5)  # and the pool serves pinned again
     assert pipe.stats()["stagingFree"][b_pad] == STAGING_DEPTH
+
+
+# ---------------------------------------------------------------------------
+# the micro-batcher's gate (ISSUE 28): one depth, and the signal that opens it
+
+
+def test_staging_depth_is_the_batchers_gate_depth():
+    """One constant, owned down here: the batcher reads the pipeline's
+    depth (it defines none of its own), and the pipeline imports nothing
+    from the workflow layer but the chaos hook."""
+    import ast
+    import inspect
+
+    from predictionio_tpu.ops import pipeline
+    from predictionio_tpu.workflow import microbatch
+
+    assert microbatch.STAGING_DEPTH is pipeline.STAGING_DEPTH == 2
+    assert not hasattr(microbatch, "DEVICE_QUEUE_DEPTH")
+    imported = {node.module for node in ast.walk(
+        ast.parse(inspect.getsource(pipeline)))
+        if isinstance(node, ast.ImportFrom) and node.level == 2}
+    assert {m for m in imported if m.startswith("workflow")} == {
+        "workflow.faults"}
+
+
+class _OwnPrograms:
+    """A retriever with scoring programs of its own, as the pipeline
+    sees one (ANN, sharded): served in gather mode."""
+
+    def __init__(self, ret):
+        self._ret = ret
+        self.n_total, self.lane_dim = ret.n_total, ret.lane_dim
+
+    def topk(self, q, k):
+        return self._ret.topk(q, k)
+
+
+@pytest.mark.parametrize("mode", ["fused", "gather"])
+def test_device_step_end_is_signalled_once_where_in_device_falls(rng, mode):
+    """``topk_rows`` tells the hook its caller installed that its device
+    step is over: once a dispatch, on the calling thread, with the step
+    out of the ``in_device`` count, before the result is scattered back;
+    a muted hook (a batch with another step to come) hears nothing, and
+    the reset gives the outer hook back."""
+    from predictionio_tpu.ops.pipeline import (reset_step_end_hook,
+                                               set_step_end_hook)
+
+    users, ret, pipe = _fixture(rng)
+    if mode == "gather":
+        pipe = ServingPipeline(users, _OwnPrograms(ret))
+    assert pipe.stats()["mode"] == mode
+    rows = np.array([4, 9], np.int32)
+    seen = []
+
+    def hook():
+        seen.append((threading.get_ident(), pipe._state.in_device))
+
+    token = set_step_end_hook(hook)
+    try:
+        muted = set_step_end_hook(None)
+        pipe.topk_rows(rows, 5)
+        assert seen == []
+        reset_step_end_hook(muted)
+        vals, idx = pipe.topk_rows(rows, 5)
+        assert len(seen) == 1  # signalled before topk_rows returned
+    finally:
+        reset_step_end_hook(token)
+    assert seen == [(threading.get_ident(), 0)]
+    assert np.array_equal(vals, ret.topk(users[rows], 5)[0])
+    pipe.topk_rows(rows, 5)  # no hook installed: nothing to call
+    assert len(seen) == 1
+
+
+def test_batcher_gate_opens_at_the_pipelines_step_end_not_the_calls(rng):
+    """End to end on the CPU: a batch_fn that serves through the
+    pipeline and then lingers in its host work gives its place ahead of
+    the device back when the device step ends."""
+    import asyncio
+
+    from predictionio_tpu.workflow.microbatch import MicroBatcher
+
+    users, ret, pipe = _fixture(rng)
+    linger = threading.Event()
+
+    def batch_fn(queries):
+        vals, _ = pipe.topk_rows(np.asarray(queries, np.int32), 3)
+        assert linger.wait(10)  # a slow result_scatter
+        return [("ok", float(v[0])) for v in vals]
+
+    async def main():
+        mb = MicroBatcher(batch_fn, max_batch=8, window_s=0.0,
+                          max_inflight=8)
+        tasks = [asyncio.create_task(mb.submit(u)) for u in (1, 2)]
+        for _ in range(500):
+            s = mb.stats()
+            if s["inflight"] >= 1 and s["aheadOfDevice"] == 0:
+                break
+            await asyncio.sleep(0.01)
+        assert s["inflight"] >= 1 and s["aheadOfDevice"] == 0
+        linger.set()
+        out = await asyncio.gather(*tasks)
+        await mb.close()
+        return out
+
+    try:
+        out = asyncio.new_event_loop().run_until_complete(main())
+    finally:
+        linger.set()
+    expect = ret.topk(users[[1, 2]], 3)[0][:, 0]
+    assert out == [float(v) for v in expect]

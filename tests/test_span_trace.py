@@ -328,9 +328,9 @@ def test_als_phases_are_one_chain_and_their_keys_reach_the_record(
 
     monkeypatch.setattr(trace.span, "__exit__", recording_exit)
     TRAINING.reset_source("train")
+    ratings = _tiny_ratings(rng)  # made outside the timed call
     t_before = time.perf_counter()
-    als.train_als(_tiny_ratings(rng), als.ALSConfig(rank=4, iterations=3,
-                                                    seed=2))
+    als.train_als(ratings, als.ALSConfig(rank=4, iterations=3, seed=2))
     t_after = time.perf_counter()
     TRAINING.finish("train")
     (attempt,) = TRAINING.summaries("train")
@@ -362,3 +362,61 @@ def test_als_phases_are_one_chain_and_their_keys_reach_the_record(
         assert "processToDeviceSeconds" not in attempt
     else:
         assert attempt["processToDeviceSeconds"] > 0
+
+
+def test_capture_shows_the_closed_gate_as_one_named_interval(tmp_path):
+    """ISSUE 28: the time a formed batch's cut waits for a place ahead
+    of the device is ``pio.serve.cut_held``, the one span that crosses an
+    ``await``: in a capture it is one event as long as the wait, and a
+    synchronous span taken on the loop's thread meanwhile lies inside
+    it."""
+    import asyncio
+    import threading
+
+    from predictionio_tpu.workflow.microbatch import MicroBatcher
+    from predictionio_tpu.workflow.tracing import maybe_profile
+
+    steps = [threading.Event() for _ in range(3)]
+    calls = []
+
+    def batch_fn(queries):
+        calls.append(queries)
+        assert steps[len(calls) - 1].wait(10)
+        return [("ok", q) for q in queries]
+
+    async def main():
+        mb = MicroBatcher(batch_fn, window_s=0.0)
+        tasks = []
+        for q in (0, 1):  # two device steps outstanding: the gate closes
+            tasks.append(asyncio.create_task(mb.submit(q)))
+            while len(calls) <= q:
+                await asyncio.sleep(0.002)
+        tasks.append(asyncio.create_task(mb.submit(2)))
+        await asyncio.sleep(0.03)
+        with span("test.on_the_loop"):
+            time.sleep(0.001)
+        await asyncio.sleep(0.03)
+        steps[0].set()
+        while len(calls) < 3:
+            await asyncio.sleep(0.002)
+        for ev in steps:
+            ev.set()
+        out = await asyncio.gather(*tasks)
+        held = mb.stats()["cutsHeld"]
+        await mb.close()
+        return out, held
+
+    try:
+        with maybe_profile(str(tmp_path / "trace")):
+            out, held = asyncio.new_event_loop().run_until_complete(main())
+    finally:
+        for ev in steps:
+            ev.set()
+    assert out == [0, 1, 2] and held == 1
+    events = _host_events(tmp_path / "trace")
+    (gate,) = [(t, t + d) for n, _s, t, d in events
+               if n == "pio.serve.cut_held"]
+    assert gate[1] - gate[0] >= 0.05e9  # the whole wait, not a marker
+    (inner,) = [(t, t + d) for n, _s, t, d in events
+                if n == "pio.test.on_the_loop"]
+    assert gate[0] <= inner[0] and inner[1] <= gate[1]
