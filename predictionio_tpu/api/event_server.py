@@ -52,7 +52,7 @@ from ..storage.event import _dt_from_wire
 from ..storage.events_base import StorageError, TableNotInitialized
 from ..storage.journal import JournalFull
 from ..workflow.admission import AdmissionController
-from ..workflow.faults import FAULTS
+from ..faults import FAULTS
 from .ingest import DurableIngestor
 from .stats import Stats
 from .webhooks import ConnectorException, FormConnector, JsonConnector, get_connector
@@ -197,7 +197,7 @@ async def _insert_one(
     events = Storage.get_events()
     try:
         # chaos site: arm a StorageError here to exercise the real
-        # 500/stats path without a broken backend (workflow/faults.py)
+        # 500/stats path without a broken backend (faults.py)
         await FAULTS.afire("eventserver.insert")
         event_id = await asyncio.to_thread(
             events.insert, event, auth.app_id, auth.channel_id

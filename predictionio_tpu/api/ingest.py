@@ -44,7 +44,7 @@ Chaos sites: ``eventserver.drain`` fires before every backend push
 (async, all partitions) and ``eventserver.drain_partition`` right after
 it; additionally a partition-targeted ``eventserver.drain_partition.p<k>``
 site fires per drainer so a single partition can be wedged in tests
-while its siblings stay healthy (workflow/faults.py).
+while its siblings stay healthy (faults.py).
 
 ``start()`` replays undrained records of every partition from a previous
 process before the server starts accepting traffic (reachable backend),
@@ -69,7 +69,7 @@ from ..storage.journal import JournalFull, PartitionedJournal
 from ..storage.partition import entity_key, hash64
 from ..obs.breaker import breaker_set as _breaker_set
 from ..workflow.admission import backpressure_retry_after_s
-from ..workflow.faults import FAULTS
+from ..faults import FAULTS
 
 log = logging.getLogger("predictionio_tpu.eventserver")
 
@@ -367,7 +367,7 @@ class DurableIngestor:
             # new alias `eventserver.drain_partition`) for a
             # deterministic all-partition backend outage, or on the
             # partition-targeted twin to wedge ONE drainer while its
-            # siblings stay healthy (workflow/faults.py)
+            # siblings stay healthy (faults.py)
             await FAULTS.afire("eventserver.drain")
             await FAULTS.afire("eventserver.drain_partition")
             await FAULTS.afire(f"eventserver.drain_partition.p{p}")

@@ -53,10 +53,6 @@ Sites instrumented in this repo:
   (sync site; an ``error`` proves the fail-OPEN path — overload
   control must never become the outage, so a broken controller admits
   and counts ``decision="error_open"``)
-- ``loadgen.slow_device``   — inside the ``pio bench serve`` load
-  generator's timed loop (``tools/serve_bench.sweep``), before each
-  device top-k call; arm ``slow`` to model a degraded device under
-  generated load and watch the latency histogram move
 - ``retrieval.ann_build``   — head of the ANN index construction at
   deploy/reload time (``ops/ann.AnnRetriever``; sync site; an
   ``error`` proves a failed k-means/index build degrades the deploy
@@ -157,7 +153,7 @@ import asyncio
 import threading
 import time
 
-from ..obs.metrics import METRICS
+from .obs.metrics import METRICS
 
 __all__ = ["FaultInjected", "FaultSpec", "FaultInjector", "FAULTS", "SITES"]
 
@@ -179,7 +175,6 @@ SITES: tuple[str, ...] = (
     "train.step",
     "train.persist",
     "admission.decide",
-    "loadgen.slow_device",
     "retrieval.ann_build",
     "checkpoint.shard_write",
     "checkpoint.manifest_commit",
@@ -204,7 +199,7 @@ SITES: tuple[str, ...] = (
 #: firing instead of a missing family
 _M_FAULTS = METRICS.counter(
     "faults_injected_total",
-    "fault-injection firings by site (workflow/faults.py)",
+    "fault-injection firings by site (faults.py)",
     labelnames=("site",))
 for _site in SITES:
     _M_FAULTS.labels(site=_site).inc(0)

@@ -53,7 +53,7 @@ from ..ops.neighbors import build_bilinear_layout
 from ..ops.retrieval import RetrievalServingMixin
 from ..storage.bimap import BiMap
 from ..storage.frame import Ratings
-from ..workflow.faults import FAULTS
+from ..faults import FAULTS
 
 log = logging.getLogger("predictionio_tpu.als")
 

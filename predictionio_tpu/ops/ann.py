@@ -56,7 +56,7 @@ import time
 import numpy as np
 
 from ..obs.metrics import METRICS
-from ..workflow.faults import FAULTS
+from ..faults import FAULTS
 from .retrieval import (EXEC_CACHE, PACKED_IDX_LIMIT, _RETRIEVER_TOKENS,
                         _dispatch_topk, _query_shapes, _resolve_topk_mode,
                         DeviceRetriever)

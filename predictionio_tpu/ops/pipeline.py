@@ -1,24 +1,26 @@
 """Device-resident serving pipeline (ISSUE 16).
 
-The legacy path does Python host work around ``_dispatch_topk`` on EVERY
-batch: per-user ``dict`` lookups, a numpy gather of the query factor
-rows, fresh padding allocations, and a host->device upload of the padded
-query matrix. This module removes that work by making the query side of
-serving device-resident, the way the item side already is
-(``DeviceRetriever``). What share of a request it was is not measured
-on the chip.
+A model served through its retriever alone (``RetrievalServingMixin.
+batch_recommend`` without a pipeline: models with no query table, the
+degraded per-query path, library callers) does Python host work around
+``_dispatch_topk`` on EVERY batch: per-user ``dict`` lookups, a numpy
+gather of the query factor rows, fresh padding allocations, and a
+host->device upload of the padded query matrix. This module removes that
+work by making the query side of serving device-resident, the way the
+item side already is (``DeviceRetriever``). What share of a request it
+was is not measured on the chip.
 
 * **Device-resident query table** — the model's user-factor matrix is
   uploaded ONCE into a capacity-padded ``[cap, D_pad]`` device buffer.
   The hot path ships only a tiny ``int32[b_pad]`` row-index vector; the
   compiled program gathers the factor rows on device. Row ``cap - 1``
   is a permanent zero sentinel: padding slots and unknown users gather
-  it, which reproduces bit-for-bit the zero-row padding the legacy path
-  builds with ``np.pad`` — the PR 13 bitwise replay gate holds across
+  it, which reproduces bit-for-bit the zero-row padding the retriever-only
+  path builds with ``np.pad`` — the PR 13 bitwise replay gate holds across
   the rewrite.
 
 * **Fused dispatch** — for an exact single-device retriever the gather
-  composes with the SAME raw scoring program the legacy path compiles
+  composes with the SAME raw scoring program the retriever compiles
   (``_raw_xla_call`` / the Pallas kernel), into one executable per
   (b_pad, k_pad) lattice point: rows -> gather -> dot -> top_k ->
   one packed pull (``retrieval._pack``: values, indices, and under the
@@ -40,7 +42,7 @@ on the chip.
   micro-batcher's watchdog without poisoning the pinned pool. The
   BatchClock stage fence (obs/waterfall.py) marks host_assembly /
   device_dispatch / device_compute / result_scatter exactly like the
-  legacy path, so the waterfall proves the overlap.
+  retriever-only path, so the waterfall proves the overlap.
 
 * **Buffer donation** — on backends with real buffer aliasing
   (tpu/gpu) the staging argument is donated (``donate_argnums``, the
@@ -76,7 +78,7 @@ from ..obs.metrics import METRICS
 from ..obs.startup import STARTUP
 from ..obs.trace import span
 from ..obs.waterfall import stage_span
-from ..workflow.faults import FAULTS
+from ..faults import FAULTS
 from .retrieval import (
     EXEC_CACHE,
     PACKED_IDX_LIMIT,
@@ -244,7 +246,7 @@ class ServingPipeline:
 
     def _exec_fused(self, b_pad: int, k_pad: int, *, pin: bool = False):
         """(compiled, is_packed) for rows -> gather -> score -> top_k.
-        Composes the SAME raw scoring program the legacy path compiles,
+        Composes the SAME raw scoring program the retriever compiles,
         so a gathered batch scores bit-for-bit like a host-assembled
         one (the parity tests pin this)."""
         r = self._retriever
@@ -338,7 +340,7 @@ class ServingPipeline:
     def _fill_staging(self, buf: np.ndarray, rows: np.ndarray) -> None:
         """Host assembly: row ids into the staging buffer, out-of-table
         ids (unknown users, padding slots) redirected to the zero
-        sentinel — the device-side equivalent of the legacy zero-pad."""
+        sentinel — the device-side equivalent of the host zero-pad."""
         b = rows.shape[0]
         np.copyto(buf[:b], np.where(
             (rows >= 0) & (rows < self.n_rows), rows, self._sentinel))
@@ -416,9 +418,9 @@ class ServingPipeline:
     def _dispatch_gather(self, buf, b, k, facts):
         """ANN / sharded: gather the query matrix on device, pull it,
         and hand it to the retriever's own compiled programs. The
-        gathered rows are bit-identical to the host gather the legacy
-        path does, so the retriever's numerics (and its exact-fallback
-        policy) are untouched."""
+        gathered rows are bit-identical to the host gather the
+        retriever-only path does, so the retriever's numerics (and its
+        exact-fallback policy) are untouched."""
         import jax
 
         call = self._exec_gather(facts["b_pad"])

@@ -50,7 +50,7 @@ from ..obs.metrics import METRICS
 from ..obs.startup import STARTUP
 from ..obs.trace import span
 from ..obs.waterfall import stage_sink_active, stage_span
-from ..workflow.faults import FAULTS
+from ..faults import FAULTS
 
 __all__ = ["topk_scores", "DeviceRetriever", "ShardedDeviceRetriever",
            "RetrievalServingMixin", "row_normalize", "ExecutableCache",
@@ -599,7 +599,7 @@ def _dispatch_topk(q: np.ndarray, n_total: int, k: int, invoke,
     separate (vals, idx[, counters]) buffers. ``on_scan`` takes the
     kernel's counters where the scoring program has any."""
     FAULTS.fire("retrieval.topk")  # chaos site: a hang here IS a hung
-    # device call (workflow/faults.py); no-op unless a test armed it
+    # device call (faults.py); no-op unless a test armed it
     single = q.ndim == 1
     if single:
         q = q[None, :]
@@ -1074,8 +1074,10 @@ class RetrievalServingMixin:
         this shrinks to ONE vectorized id->row translation: the factor
         gather, padding and scoring all run in the pipeline's compiled
         device programs. The compacted row batch and the trim dance are
-        identical to the legacy path, so results are bit-for-bit the
-        same (the capture/replay parity tests pin it)."""
+        identical to the retriever branch below (what serves a model
+        with no pipeline attached, and the tests' reference), so results
+        are bit-for-bit the same (the capture/replay parity tests pin
+        it)."""
         uids = getattr(self, self._query_ids_attr)
         qmat = getattr(self, self._query_attr)
         out: list = [[] for _ in users]

@@ -16,5 +16,4 @@ from .mesh import (  # noqa: F401
     init_distributed,
     local_device_count,
 )
-from . import collectives  # noqa: F401
 from . import ring_attention  # noqa: F401

@@ -22,9 +22,6 @@ from .event import (
 )
 from .events_base import ANY, EventBackend, EventQuery, StorageError, TableNotInitialized
 from .frame import EventFrame, Ratings
-# NOTE: .journal is intentionally NOT imported here — it fires chaos
-# sites through workflow.faults, and workflow imports this package.
-# Import it as `predictionio_tpu.storage.journal` (the api layer does).
 from .memory import MemoryEvents
 from .partition import entity_key, hash64, iter_host_shard, partition_events, shard_of
 from .metadata import (

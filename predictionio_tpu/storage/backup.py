@@ -35,7 +35,7 @@ they can never run concurrently against the same home.
 
 Chaos sites: ``backup.copy`` fires before each file enters a backup,
 ``restore.apply`` before each file is materialized into the target —
-both registered in workflow/faults.py SITES.
+both registered in faults.py SITES.
 """
 from __future__ import annotations
 
@@ -53,7 +53,7 @@ from pathlib import Path
 from typing import Iterable
 
 from ..obs.metrics import METRICS
-from ..workflow.faults import FAULTS
+from ..faults import FAULTS
 from .journal import iter_journal_records
 
 __all__ = [

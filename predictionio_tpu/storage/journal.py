@@ -38,7 +38,7 @@ Design (the classic single-writer log, cf. HLog / Kafka segment logs):
 Chaos sites: ``journal.append`` fires at the head of every append,
 ``journal.fsync`` before every fsync, and ``journal.partition_append``
 at the head of every routed ``PartitionedJournal.append``
-(workflow/faults.py), so disk-level failures are provable in tests
+(faults.py), so disk-level failures are provable in tests
 without a broken disk.
 
 Thread-safety: one lock around all mutation; appends come from the event
@@ -74,7 +74,7 @@ import zlib
 from pathlib import Path
 
 from ..obs.metrics import METRICS
-from ..workflow.faults import FAULTS
+from ..faults import FAULTS
 
 # ISSUE 5: journal durability costs, scrapeable (the stats() dict keeps
 # its raw-counter shape; these add the latency distributions)

@@ -12,7 +12,7 @@ console/Console.scala:128-1245). Same verb set, no JVM/spark-submit spawning
   pio eval <Evaluation> [<EngineParamsGenerator>]
   pio deploy [--port 8000] [--feedback] [--event-server-url ...]
   pio batchpredict --input queries.jsonl --output predictions.jsonl
-  pio bench serve [--ways 1,2,4,8]
+  pio bench backup [--files N] [--size-kb N] [--rounds N] [--json]
   pio undeploy [--port 8000]
   pio eventserver [--port 7070] [--stats] [--journal-dir D]
                   [--journal-fsync always|batch|never] [--journal-max-mb N]
@@ -666,7 +666,6 @@ def cmd_deploy(args) -> int:
         engine_dir=engine_dir,
         retriever_mesh=_retriever_mesh(args.retriever_mesh),
         retrieval=_retrieval_params(engine_dir, args),
-        instrumentation=not args.no_instrumentation,
         slo_latency_ms=args.slo_latency_ms,
         flight_capacity=args.flight_capacity,
         flight_dump_dir=args.flight_dir,
@@ -676,7 +675,6 @@ def cmd_deploy(args) -> int:
         capture_max_mb=args.capture_max_mb,
         shadow_target=args.shadow_target,
         shadow_sample=args.shadow_sample,
-        serving_pipeline=args.serving_pipeline,
         prewarm_async=args.prewarm_async,
     )
     return 0
@@ -1029,62 +1027,22 @@ def _retriever_mesh(n):
 
 
 def cmd_bench(args) -> int:
-    """`pio bench serve --ways 1,8`: sharded-serving sweep in a FRESH
-    subprocess, on whatever platform JAX finds there (the child prints
-    it). Under ``JAX_PLATFORMS=cpu`` the virtual device count is forced
-    via XLA_FLAGS, which must happen before jax initializes."""
-    if getattr(args, "bench_command", "serve") == "backup":
-        from ..storage.backup import run_backup_bench
+    """`pio bench backup`: synthetic backup throughput (one full backup,
+    then incrementals over an unchanged home)."""
+    from ..storage.backup import run_backup_bench
 
-        rep = run_backup_bench(files=args.files, size_kb=args.size_kb,
-                               rounds=args.rounds)
-        if args.json:
-            _ok(json.dumps(rep, indent=2, sort_keys=True))
-            return 0
-        _ok(f"backup bench: {rep['files']} files x {rep['sizeKb']}KB")
-        for r in rep["rounds"]:
-            kind = "full" if r["round"] == 0 else "incremental"
-            _ok(f"  round {r['round']} ({kind}): {r['seconds']}s, "
-                f"{r['mbWritten']}MB written ({r['mbPerS']}MB/s), "
-                f"{r['dedupedFiles']} files deduped")
+    rep = run_backup_bench(files=args.files, size_kb=args.size_kb,
+                           rounds=args.rounds)
+    if args.json:
+        _ok(json.dumps(rep, indent=2, sort_keys=True))
         return 0
-    import subprocess
-
-    ways: list = []
-    for w in args.ways.split(","):
-        w = w.strip()
-        if not w:
-            continue
-        if w.lower() == "auto":
-            # the child resolves "auto" via choose_shard_count once it
-            # knows the device count; force the full 8-device mesh so
-            # the cost model has real widths to pick from
-            ways.append("auto")
-        else:
-            try:
-                ways.append(int(w))
-            except ValueError:
-                _die(f"--ways entries must be integers or 'auto', got {w!r}")
-    if not ways:
-        _die("--ways must name at least one mesh width, e.g. 1,8")
-    max_ways = max([w for w in ways if isinstance(w, int)] or [1])
-    if "auto" in ways:
-        max_ways = max(max_ways, 8)
-    env = dict(os.environ)
-    if env.get("JAX_PLATFORMS") == "cpu":
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={max_ways}"
-        ).strip()
-    repo_root = str(Path(__file__).resolve().parents[2])
-    env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, "-m", "predictionio_tpu.tools.serve_bench",
-           "--ways", ",".join(map(str, ways)),
-           "--batch", str(args.batch), "--k", str(args.k),
-           "--iters", str(args.iters), "--n-items", str(args.n_items),
-           "--rank", str(args.rank),
-           "--retrieval", args.retrieval]
-    return subprocess.run(cmd, env=env).returncode
+    _ok(f"backup bench: {rep['files']} files x {rep['sizeKb']}KB")
+    for r in rep["rounds"]:
+        kind = "full" if r["round"] == 0 else "incremental"
+        _ok(f"  round {r['round']} ({kind}): {r['seconds']}s, "
+            f"{r['mbWritten']}MB written ({r['mbPerS']}MB/s), "
+            f"{r['dedupedFiles']} files deduped")
+    return 0
 
 
 def cmd_undeploy(args) -> int:
@@ -2321,15 +2279,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "serves from the quantized IVF index (exact "
                          "fallback below its min-items floor), 'exact' "
                          "forces brute-force scoring")
-    sp.add_argument("--serving-pipeline", choices=["pipelined", "legacy"],
-                    default="pipelined",
-                    help="'pipelined' (default) serves through the "
-                         "device-resident dispatch pipeline: the user "
-                         "factor table lives on device, requests ship "
-                         "int32 row indices, and the full pad-bucket "
-                         "batch lattice is precompiled at deploy time; "
-                         "'legacy' keeps the pre-pipeline host dispatch "
-                         "(per-batch gather/pad/upload) for comparison")
     sp.add_argument("--deadline-ms", type=float, default=0.0,
                     help="default end-to-end deadline per query in ms "
                          "(expired queries answer 504; 0 disables; the "
@@ -2362,9 +2311,6 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--brownout-topk", type=int, default=10,
                     help="top-k clamp applied to queries while the "
                          "server is in brownout")
-    sp.add_argument("--no-instrumentation", action="store_true",
-                    help="disable per-request stage waterfalls (SLO "
-                         "accounting and aggregate histograms stay on)")
     sp.add_argument("--slo-latency-ms", type=float, default=0.0,
                     help="latency-SLO threshold in ms (bad = slower); "
                          "0 uses --deadline-ms, else 250")
@@ -2548,22 +2494,6 @@ def build_parser() -> argparse.ArgumentParser:
 
     sp = sub.add_parser("bench")
     b_sub = sp.add_subparsers(dest="bench_command", required=True)
-    x = b_sub.add_parser("serve",
-                         help="sharded-serving QPS/p50 sweep across mesh "
-                              "widths (fresh subprocess; CPU devices "
-                              "forced to max(--ways))")
-    x.add_argument("--ways", default="1,2,4,8",
-                   help="comma-separated mesh widths; 'auto' adds the "
-                        "width the catalog-size cost model would pick")
-    x.add_argument("--batch", type=int, default=128)
-    x.add_argument("--k", type=int, default=10)
-    x.add_argument("--iters", type=int, default=12)
-    x.add_argument("--n-items", type=int, default=65_536)
-    x.add_argument("--rank", type=int, default=64)
-    x.add_argument("--retrieval", choices=["exact", "ann"], default="exact",
-                   help="retrieval mode to bench: exact brute-force "
-                        "scoring or the quantized ANN index (reports "
-                        "recall@k against exact)")
     x = b_sub.add_parser("backup",
                          help="synthetic backup throughput: one full "
                               "backup then incrementals over an "

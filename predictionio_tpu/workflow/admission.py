@@ -43,8 +43,8 @@ import threading
 import time
 from dataclasses import dataclass
 
+from ..faults import FAULTS
 from ..obs.metrics import METRICS, Histogram
-from .faults import FAULTS
 
 __all__ = [
     "AdmissionController",

@@ -42,6 +42,7 @@ from typing import Any
 
 import numpy as np
 
+from ..faults import FAULTS
 from ..obs.metrics import METRICS
 
 log = logging.getLogger("predictionio_tpu.workflow")
@@ -488,7 +489,6 @@ class ShardedTrainCheckpointer:
 
     # -- barrier -----------------------------------------------------------
     def _sync(self, tag: str) -> None:
-        from .faults import FAULTS
         from .supervisor import TransientTrainingError, BarrierTimeoutError
 
         # chaos site: the sync point where a dead peer surfaces — arming
@@ -511,8 +511,6 @@ class ShardedTrainCheckpointer:
         full global training state on every process — matrix-valued keys
         (ndim >= 2) are row-sharded by ``host_row_range``, scalars are
         replicated into every shard and read back from shard 0."""
-        from .faults import FAULTS
-
         step_dir = self._step_dir(step)
         step_dir.mkdir(parents=True, exist_ok=True)
         arrays = {k: np.asarray(v) for k, v in state.items()}
@@ -553,7 +551,6 @@ class ShardedTrainCheckpointer:
 
     def _commit_manifest(self, step: int, step_dir: Path,
                          arrays: dict) -> None:
-        from .faults import FAULTS
         from ..parallel.mesh import host_row_range
         from .supervisor import HostLostError
 

@@ -23,13 +23,13 @@ from ..controller.components import PersistentModel
 from ..controller.engine import Engine, TrainResult
 from ..controller.evaluation import Evaluation, MetricEvaluator, MetricEvaluatorResult
 from ..controller.params import EngineParams, params_to_json
+from ..faults import FAULTS
 from ..obs.device import device_identity
 from ..obs.startup import STARTUP
 from ..obs.trace import span
 from ..obs.training import TRAINING
 from ..storage import EngineInstance, EvaluationInstance, Model, Storage
 from .context import Context
-from .faults import FAULTS
 from .supervisor import DEFAULT_STALE_AFTER_S, TrainSupervisor, reap_orphans
 from .serialization import (
     PersistentModelManifest,

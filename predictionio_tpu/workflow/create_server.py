@@ -63,6 +63,7 @@ from aiohttp import web
 
 from ..controller.engine import Engine, TrainResult
 from ..controller.params import parse_params
+from ..faults import FAULTS
 from ..obs.device import LEDGER, device_identity
 from ..obs.flight import FLIGHT
 from ..obs.http import handle_metrics, make_trace_middleware
@@ -77,7 +78,6 @@ from ..obs.waterfall import (Waterfall, mark_stage, reset_stage_sink,
 from ..ops.pipeline import reset_step_end_hook, set_step_end_hook
 from ..storage import EngineInstance, Storage
 from .admission import AdmissionController
-from .faults import FAULTS
 from .feedback import FeedbackPublisher
 from .microbatch import DeadlineExceeded, DispatchTimeout, ServerBusy
 from .context import Context
@@ -175,14 +175,6 @@ class Deployed:
     retriever_axis: str = "model"
     prewarm_batch: int = 0  # pre-compile executables for this batch ceiling
     retrieval: dict | None = None
-    #: ISSUE 16: "pipelined" (default) serves through the device-resident
-    #: ServingPipeline — the query factor table lives on device, requests
-    #: ship int32 row indices, and exact 1-way serving attaches the
-    #: compiled retriever on EVERY backend (the XLA program off-TPU).
-    #: "legacy" preserves the pre-16 behavior exactly (host gather +
-    #: per-batch upload; host scoring for exact 1-way off TPU) for the
-    #: bench comparison and as an operational escape hatch.
-    serving_pipeline: str = "pipelined"
     # ISSUE 13 provenance facts, stamped at rehydration time: the model
     # blob's content hash (storage metadata checksum) and a digest over
     # the executable-cache keys this bundle compiled — together they
@@ -216,8 +208,6 @@ class Deployed:
         # XLA elsewhere). Building the retriever on the NEW bundle before
         # the swap is the double-buffered /reload: the old bundle keeps
         # serving until this one is fully on-device.
-        import jax
-
         try:
             # the blob a second time (prepare_deploy let go of its own),
             # for its checksum alone
@@ -229,15 +219,6 @@ class Deployed:
             self.blob_sha = None
 
         mode = str((self.retrieval or {}).get("mode", "exact")).lower()
-        pipelined = str(self.serving_pipeline).lower() != "legacy"
-        # retrieval: {"device": true} forces the compiled exact retriever
-        # off-TPU even on the legacy path — the knob the parity harness
-        # uses so a legacy capture and a pipelined replay score through
-        # the same executable family (additive: default unchanged)
-        force_device = bool((self.retrieval or {}).get("device"))
-        if (jax.default_backend() != "tpu" and self.retriever_mesh is None
-                and mode != "ann" and not pipelined and not force_device):
-            return
         for model in self.result.models:
             mesh = None
             if mode == "ann":
@@ -257,13 +238,6 @@ class Deployed:
             else:
                 attach = getattr(model, "attach_retriever", None)
                 args, kwargs = (), {}
-                if (jax.default_backend() != "tpu" and not pipelined
-                        and not force_device):
-                    # auto resolved to 1-way on a non-TPU backend: host
-                    # scoring is the exact single-device path there
-                    # (legacy; the pipeline serves through the compiled
-                    # XLA program on every backend)
-                    attach = None
             if attach is not None:
                 # a failed attach raises: deploy exits non-zero with
                 # nothing bound, /reload fails and the old bundle keeps
@@ -277,7 +251,7 @@ class Deployed:
                     "ann" if mode == "ann"
                     else "sharded" if mesh is not None else "device",
                     type(model).__name__)
-            if pipelined and getattr(model, "_retriever", None) is not None:
+            if getattr(model, "_retriever", None) is not None:
                 ap = getattr(model, "attach_pipeline", None)
                 # models without a query-factor table (similarity-only)
                 # have no query side to make device-resident: skip, the
@@ -297,25 +271,20 @@ class Deployed:
 
     def _prewarm(self):
         """AOT-compile the hot serving shapes at DEPLOY time so the first
-        real query (and the first full micro-batch) never pays a compile.
-        The micro-batcher produces two hot shapes: a lone query (pad 1)
-        and a full window (pad ``prewarm_batch``); both are pinned in the
-        executable cache (ops/retrieval.py EXEC_CACHE).
-
-        Pipelined serving (ISSUE 16) precompiles the FULL pad-bucketed
-        batch lattice instead — every power-of-two bucket up to the
-        micro-batcher's ceiling — so an adaptive window that dispatches
-        a partial batch never hits a cold executable; the pipeline's
-        prewarm also allocates the pinned staging pairs and accounts
-        them in the device ledger."""
-        sizes = sorted({1, self.prewarm_batch})
-        if str(self.serving_pipeline).lower() != "legacy":
-            lattice = {1, self.prewarm_batch}
-            b = 8
-            while b < self.prewarm_batch:
-                lattice.add(b)
-                b *= 2
-            sizes = sorted(lattice)
+        real query (and the first full micro-batch) never pays a compile:
+        the FULL pad-bucketed batch lattice — a lone query (pad 1) and
+        every power-of-two bucket up to the micro-batcher's ceiling — so
+        an adaptive window that dispatches a partial batch never hits a
+        cold executable. All are pinned in the executable cache
+        (ops/retrieval.py EXEC_CACHE); the pipeline's prewarm also
+        allocates the pinned staging pairs and accounts them in the
+        device ledger."""
+        lattice = {1, self.prewarm_batch}
+        b = 8
+        while b < self.prewarm_batch:
+            lattice.add(b)
+            b *= 2
+        sizes = sorted(lattice)
         warmed_keys: list = []
         with span("deploy.prewarm", sink=STARTUP.phase,
                   batch=self.prewarm_batch):
@@ -383,7 +352,6 @@ class EngineServer:
         brownout_topk: int = 10,
         retrieval: dict | None = None,
         patch_table_max: int = 100_000,
-        instrumentation: bool = True,
         slo_latency_ms: float = 0.0,
         flight_capacity: int = 256,
         flight_dump_dir: str | None = None,
@@ -394,7 +362,6 @@ class EngineServer:
         shadow_target: str | None = None,
         shadow_sample: float = 1.0,
         variant_id: str = "default",
-        serving_pipeline: str = "pipelined",
         defer_prewarm: bool = False,
     ):
         self.engine = engine
@@ -410,8 +377,6 @@ class EngineServer:
         #: their blob was corrupt or unloadable — surfaced in
         #: /health.json and /stats.json so operators see the quarantine
         self.deploy_skips: list[dict] = []
-        self.serving_pipeline = (str(serving_pipeline).lower()
-                                 if serving_pipeline else "pipelined")
         # ISSUE 17: readiness vs liveness. While True the server is
         # LIVE (answers queries, compiling on demand) but NOT READY —
         # /health.json reports ready=false so a fleet router withholds
@@ -425,15 +390,13 @@ class EngineServer:
             self.deployed = Deployed(
                 inst, result,
                 retriever_mesh=retriever_mesh, retriever_axis=retriever_axis,
-                prewarm_batch=prewarm_batch, retrieval=retrieval,
-                serving_pipeline=self.serving_pipeline)
+                prewarm_batch=prewarm_batch, retrieval=retrieval)
         else:  # explicitly pinned instance: fail loud, never substitute
             self.deployed = Deployed(
                 instance,
                 prepare_deploy(engine, instance, self.ctx, engine_dir=engine_dir),
                 retriever_mesh=retriever_mesh, retriever_axis=retriever_axis,
-                prewarm_batch=prewarm_batch, retrieval=retrieval,
-                serving_pipeline=self.serving_pipeline)
+                prewarm_batch=prewarm_batch, retrieval=retrieval)
         self.feedback_url = feedback_url
         self.access_key = access_key
         # lifecycle-owned feedback publisher: one shared session, tracked
@@ -522,11 +485,6 @@ class EngineServer:
                 rate_limit_qps=rate_limit_qps,
                 rate_limit_burst=rate_limit_burst,
             )
-        # ISSUE 11: latency attribution. Per-request stage waterfalls +
-        # flight-recorder capture are always-on by default; the switch
-        # exists ONLY so the bench overhead gate can measure the
-        # instrumentation-off baseline it compares against.
-        self.instrumentation = instrumentation
         # SLO engine: latency objective defaults to the request deadline
         # (a request slower than its deadline was worthless), 250 ms when
         # no deadline is configured; availability is always three nines.
@@ -1085,8 +1043,7 @@ class EngineServer:
                          prewarm_batch=self.batch_max,
                          # /reload preserves the ANN configuration (and
                          # rebuilds the index over the fresh factors)
-                         retrieval=self.deployed.retrieval,
-                         serving_pipeline=self.deployed.serving_pipeline)
+                         retrieval=self.deployed.retrieval)
         # ISSUE 10: reconcile outstanding delta patches before the swap.
         # Deltas for users the fresh instance trained are superseded
         # (training saw their journaled events) and are discarded; deltas
@@ -1171,7 +1128,8 @@ class EngineServer:
                     clone._pipeline = pipe.refresh(factors)
                 except Exception:  # noqa: BLE001 — serving must not die
                     log.exception("pipeline refresh failed; detaching "
-                                  "(legacy dispatch until next reload)")
+                                  "(retriever-only dispatch until next "
+                                  "reload)")
                     clone._pipeline = None
             new_models[mi] = clone
             applied.update(u for u, _ in appends)
@@ -1285,19 +1243,16 @@ class EngineServer:
                     "sharded": type(r).__name__ == "ShardedDeviceRetriever"}
         return None
 
-    def _pipeline_stats(self, bundle: "Deployed | None" = None,
-                        ) -> dict | None:
-        """The configured dispatch path plus the first attached
-        ServingPipeline's stats() (ISSUE 16; overlap ratio, staging
-        pool, table capacity) — stats absent when nothing attached."""
+    def _pipeline_stats(self, bundle: "Deployed | None" = None) -> dict:
+        """The first attached ServingPipeline's stats() (ISSUE 16;
+        overlap ratio, staging pool, table capacity) — an empty block
+        when nothing attached."""
         bundle = bundle if bundle is not None else self.deployed
-        block = {"servingPipeline": bundle.serving_pipeline}
         for model in bundle.result.models:
             p = getattr(model, "_pipeline", None)
             if p is not None:
-                block.update(p.stats())
-                break
-        return block
+                return p.stats()
+        return {}
 
     def variant_stats(self) -> dict:
         """The per-variant slice of serving_stats (ISSUE 14): what is
@@ -1401,7 +1356,7 @@ class EngineServer:
             # (cells / nprobe / quantize / build seconds / fallback)
             "retrieval": self._retrieval_stats(bundle),
             # ISSUE 16: device-resident dispatch posture (overlap ratio,
-            # staging pool, capacity); None on the legacy path
+            # staging pool, capacity); empty where no model has a pipeline
             "pipeline": self._pipeline_stats(bundle),
             "admission": (self.admission.stats()
                           if self.admission is not None else None),
@@ -1458,10 +1413,8 @@ async def handle_query(request: web.Request) -> web.Response:
     # stage sink so the FALLBACK path's to_thread worker (which copies
     # this context) marks straight onto it; the batched path's shared
     # stages ride the dispatch BatchClock and merge in at completion.
-    wf = sink_token = None
-    if primary.instrumentation:
-        wf = Waterfall(rid=rid)
-        sink_token = set_stage_sink(wf)
+    wf = Waterfall(rid=rid)
+    sink_token = set_stage_sink(wf)
     # the EFFECTIVE query (post brownout clamp) — what capture persists
     # and replay re-issues, so replay against a normal-mode server is
     # still deterministic
@@ -1474,17 +1427,15 @@ async def handle_query(request: web.Request) -> web.Response:
         _M_QUERIES.inc(status=status_label)
         # per-variant outcome series rides the primary's router table
         primary.variants.count_query(server.variant_id, status_label)
-        # SLO accounting is always on (independent of the waterfall
-        # switch): latency objective sees the client-observed wall;
-        # availability counts server-side failures (5xx) as bad
+        # SLO accounting: latency objective sees the client-observed
+        # wall; availability counts server-side failures (5xx) as bad
         server.slo.observe(wall, ok=status < 500)
-        if wf is not None:
-            reset_stage_sink(sink_token)
-            wf.finish(status_label)
-            wf.meta["http"] = status
-            wf.meta["mode"] = server.mode
-            wf.meta["variant"] = server.variant_id
-            server.flight.record(wf.to_dict())
+        reset_stage_sink(sink_token)
+        wf.finish(status_label)
+        wf.meta["http"] = status
+        wf.meta["mode"] = server.mode
+        wf.meta["variant"] = server.variant_id
+        server.flight.record(wf.to_dict())
         trace_event("serve.ingress", status=status_label,
                     http=status, ms=round((time.perf_counter() - t0) * 1e3, 3))
         headers = {TRACE_HEADER: rid}
@@ -1902,7 +1853,6 @@ async def handle_variant_register(request: web.Request) -> web.Response:
                 body.get("patchTableMax", primary.patch_table_max)),
             retrieval=(body.get("retrieval")
                        if isinstance(body.get("retrieval"), dict) else None),
-            instrumentation=primary.instrumentation,
         )
 
     try:

@@ -36,10 +36,10 @@ import random
 import time
 from collections import deque
 
+from ..faults import FAULTS
 from ..obs.breaker import breaker_set as _breaker_set
 from ..obs.metrics import METRICS
 from ..obs.trace import current_request_id, trace_event
-from .faults import FAULTS
 
 log = logging.getLogger("predictionio_tpu.server")
 

@@ -76,7 +76,7 @@ Self-healing (ISSUE 18) adds two things on top:
   ``GET /fleet/restart`` delegates a rolling, canary-gated restart
   wave to the attached supervisor.
 
-Chaos sites (``workflow/faults.py`` harness): ``fleet.route`` at the
+Chaos sites (``faults.py`` harness): ``fleet.route`` at the
 head of the routing decision, ``fleet.replica_dispatch`` before every
 proxied query attempt (arm an error to prove the hedge path),
 ``fleet.delta_fanout`` before every per-replica delta POST (a lagging
@@ -109,13 +109,13 @@ from pathlib import Path
 import aiohttp
 from aiohttp import web
 
+from ..faults import FAULTS
 from ..obs.aggregate import FleetCollector
 from ..obs.breaker import breaker_set
 from ..obs.metrics import METRICS
 from ..obs.replay import PROVENANCE_HEADER, diff_tier
 from ..obs.trace import TRACE_HEADER, ensure_request_id, trace_event
 from ..storage.journal import EventJournal, JournalFull, iter_journal_records
-from .faults import FAULTS
 from .variants import VARIANT_HEADER, entity_key
 
 __all__ = [

@@ -36,9 +36,9 @@ opens it: the pipeline reports it on the dispatch worker thread
 (``ops.pipeline.set_step_end_hook``), and the batcher is woken on its
 loop. Host work after the step (result scatter, the answers' JSON)
 holds no place ahead of the device and overlaps the next batch's device
-step. A ``batch_fn`` that reports nothing (the legacy serving path,
-host-scored engines, a plain callable) is gated on its whole call: the
-same rule with the only signal there is.
+step. A ``batch_fn`` that reports nothing (a model served through its
+retriever alone, host-scored engines, a plain callable) is gated on its
+whole call: the same rule with the only signal there is.
 
 ``max_inflight`` is not the depth of that queue. It is the gate's other
 count: the calls that may be live at once, those still in their host
@@ -62,6 +62,7 @@ import logging
 import time
 from typing import Any, Callable, Sequence
 
+from ..faults import FAULTS
 from ..obs.flight import FLIGHT
 from ..obs.metrics import METRICS
 from ..obs.trace import current_request_id, span, trace_event
@@ -69,7 +70,6 @@ from ..obs.waterfall import (BatchClock, current_sink, reset_stage_sink,
                              set_stage_sink, stage_span)
 from ..ops.pipeline import (STAGING_DEPTH, reset_step_end_hook,
                             set_step_end_hook)
-from .faults import FAULTS
 
 log = logging.getLogger("predictionio_tpu.server")
 

@@ -50,7 +50,7 @@ operator. The publish path carries its own circuit breaker
 half-open contract as the ingest drainer's.
 
 Fault sites: ``stream.tail`` / ``stream.fold_in`` / ``stream.publish``
-(workflow/faults.py). Trace ids ride from the WAL record (the ``"t"``
+(faults.py). Trace ids ride from the WAL record (the ``"t"``
 field stamped at ingress) through the ``stream.tail`` / ``stream.fold_in``
 trace events into the patch request's ``X-PIO-Request-ID`` header, so one
 grep joins ingress -> journal -> fold-in -> serve.
@@ -69,11 +69,12 @@ import urllib.request
 import numpy as np
 
 from ..controller.metric import AverageMetric
+from ..faults import FAULTS, FaultInjected
 from ..obs.breaker import breaker_set
 from ..obs.metrics import METRICS
 from ..obs.trace import TRACE_HEADER, trace_event
 from ..obs.training import TRAINING
-from .faults import FAULTS, FaultInjected
+from ..storage.journal import JournalFollower
 from .supervisor import classify_error
 
 log = logging.getLogger("predictionio_tpu.workflow.streaming")
@@ -191,11 +192,6 @@ class StreamingUpdater:
         variant: str | None = None,
         rng: random.Random | None = None,
     ):
-        # deferred: storage.journal itself imports workflow.faults, so a
-        # module-level import here would be circular when the storage
-        # layer loads first
-        from ..storage.journal import JournalFollower
-
         self.model = model
         self.follower = JournalFollower(journal_dir, name=name,
                                         partitions=partitions)

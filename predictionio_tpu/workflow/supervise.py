@@ -38,7 +38,7 @@ end-to-end:
   wave with the rest of the fleet untouched.
 
 Every recovery path is provable (TensorFlow's nonfatal-failure design,
-arXiv:1605.08695 §4.2, same as the rest of ``workflow/faults.py``): the
+arXiv:1605.08695 §4.2, same as the rest of ``faults.py``): the
 ``supervisor.respawn`` chaos site fires right before each respawn
 ``Popen`` — an armed error is a failed exec, which counts against the
 crash window and re-enters backoff instead of busy-looping.
@@ -65,9 +65,9 @@ import urllib.request
 from collections import deque
 from dataclasses import dataclass, field
 
+from ..faults import FAULTS
 from ..obs.metrics import METRICS
 from ..obs.trace import trace_event
-from .faults import FAULTS
 
 __all__ = ["SupervisedReplica", "FleetSupervisor"]
 

@@ -48,7 +48,7 @@ from dataclasses import replace
 from datetime import datetime, timezone
 from typing import Any, Callable
 
-from .faults import FaultInjected
+from ..faults import FaultInjected
 
 log = logging.getLogger("predictionio_tpu.workflow.supervisor")
 

@@ -49,6 +49,7 @@ from ..controller.evaluation import MetricEvaluatorResult, MetricScores
 from ..controller.fast_eval import FastEvalEngine
 from ..controller.metric import Metric
 from ..controller.params import EngineParams, params_to_json
+from ..faults import FAULTS
 from ..obs.metrics import METRICS
 from ..obs.training import TRAINING
 from ..storage import Storage
@@ -56,7 +57,6 @@ from ..storage.frame import Ratings
 from ..storage.metadata import EngineInstance
 from .context import Context
 from .core_workflow import run_train, stamp_evaluator_results
-from .faults import FAULTS
 from .supervisor import TrainSupervisor
 
 log = logging.getLogger("predictionio_tpu.tuning")

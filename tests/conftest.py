@@ -37,7 +37,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "chaos: fault-injection resilience tests (CPU-fast, deterministic "
-        "via predictionio_tpu.workflow.faults; guarded by a per-test "
+        "via predictionio_tpu.faults; guarded by a per-test "
         "SIGALRM timeout so an injected hang cannot wedge the suite)")
     config.addinivalue_line(
         "markers",
@@ -154,7 +154,7 @@ def _chaos_guard(request):
 
     import signal
 
-    from predictionio_tpu.workflow.faults import FAULTS
+    from predictionio_tpu.faults import FAULTS
 
     def _expired(signum, frame):
         FAULTS.clear()  # release hung threads before failing the test

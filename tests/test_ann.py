@@ -93,7 +93,7 @@ def test_edge_shapes_route_through_dispatch(rng):
     """k > N and the single-vector query must flow through the shared
     _dispatch_topk entry (proven by arming its chaos site), with the
     exact path's -1/-inf padding contract."""
-    from predictionio_tpu.workflow.faults import FAULTS, FaultInjected
+    from predictionio_tpu.faults import FAULTS, FaultInjected
 
     items = _clustered(rng, 20_000, 16)
     ann = AnnRetriever(items, nprobe=4, n_cells=64, min_items=0)
@@ -185,7 +185,7 @@ def test_brownout_clamp_shrinks_probe_work(rng):
 @pytest.mark.chaos
 def test_failed_index_build_degrades_to_exact(rng):
     from predictionio_tpu.obs.metrics import METRICS
-    from predictionio_tpu.workflow.faults import FAULTS, SITES
+    from predictionio_tpu.faults import FAULTS, SITES
 
     assert "retrieval.ann_build" in SITES
     items = _clustered(rng, 20_000, 16)
@@ -285,30 +285,6 @@ def test_deployed_auto_mesh_and_ann_attach(rng):
     m2.item_factors = m.item_factors
     m2.item_ids = m.item_ids
     d2 = Deployed(None, SimpleNamespace(models=[m2]), retriever_mesh="auto")
-    # cost model says 1-way at 2k rows; the pipelined default (ISSUE 16)
-    # serves the compiled exact program on EVERY backend, CPU included
+    # cost model says 1-way at 2k rows; the server (ISSUE 16) serves the
+    # compiled exact program on EVERY backend, CPU included
     assert isinstance(getattr(m2, "_retriever", None), DeviceRetriever)
-
-    m3 = M()
-    m3.item_factors = m.item_factors
-    m3.item_ids = m.item_ids
-    d3 = Deployed(None, SimpleNamespace(models=[m3]), retriever_mesh="auto",
-                  serving_pipeline="legacy")
-    # the legacy escape hatch keeps the pre-16 posture: 1-way on CPU is
-    # host scoring, the exact baseline
-    assert getattr(m3, "_retriever", None) is None
-
-
-def test_serve_bench_ann_sweep_smoke(rng):
-    """tools/serve_bench.ann_sweep emits the exact/ann row pair with a
-    measured recall and the ivf index tag."""
-    from predictionio_tpu.tools.serve_bench import ann_sweep, format_table
-
-    rows = ann_sweep(n_items=20_000, rank=16, batch=16, k=10, iters=2)
-    by = {r["mode"]: r for r in rows}
-    assert by["exact"]["recall_at_k"] == 1.0
-    assert 0.0 < by["ann"]["recall_at_k"] <= 1.0
-    assert by["ann"]["merge"].startswith("ivf:")
-    assert by["ann"]["build_s"] > 0
-    table = format_table(rows)
-    assert "recall@k" in table and "ivf:" in table

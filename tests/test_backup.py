@@ -41,7 +41,7 @@ from predictionio_tpu.storage.journal import EventJournal
 from predictionio_tpu.storage.metadata import (EngineInstance, MetadataStore,
                                                Model)
 from predictionio_tpu.tools.cli import main as pio
-from predictionio_tpu.workflow.faults import FAULTS
+from predictionio_tpu.faults import FAULTS
 
 pytestmark = pytest.mark.dr
 
@@ -590,7 +590,7 @@ def test_sigkill_mid_second_backup_prior_backup_survives(tmp_path):
     code = (
         "import os\n"
         "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
-        "from predictionio_tpu.workflow.faults import FAULTS\n"
+        "from predictionio_tpu.faults import FAULTS\n"
         "FAULTS.inject('backup.copy', 'hang', times=1, after=2,\n"
         "              max_hang_s=90)\n"
         "from predictionio_tpu.storage.backup import create_backup\n"

@@ -129,18 +129,22 @@ def test_pio_deploy_help_documents_overload_flags(tmp_path):
         assert flag in out.stdout, f"{flag} missing from deploy --help"
 
 
-def test_pio_bench_serve_help_documents_retrieval_flag(tmp_path):
-    """ISSUE 7 satellite: `pio bench serve --help` must advertise the
-    retrieval-mode switch (and both its choices) plus the 'auto' mesh
-    width, so the Retrieval-at-scale runbook stays honest."""
-    env = dict(os.environ, PIO_HOME=str(tmp_path), JAX_PLATFORMS="cpu")
-    out = subprocess.run([str(REPO / "bin" / "pio"), "bench", "serve",
-                          "--help"],
-                         capture_output=True, text=True, env=env, timeout=60)
-    assert out.returncode == 0
-    assert "--retrieval" in out.stdout
-    assert "{exact,ann}" in out.stdout
-    assert "auto" in out.stdout
+@pytest.mark.parametrize("argv, code", [
+    (["deploy", "--serving-pipeline", "legacy"], 2),
+    (["deploy", "--no-instrumentation"], 2),
+    (["bench", "serve"], 2),
+    (["bench", "backup", "--help"], 0),
+], ids=["deploy-serving-pipeline", "deploy-no-instrumentation",
+        "bench-serve", "bench-backup-help"])
+def test_pio_has_one_serving_path_and_one_bench(argv, code):
+    """`pio deploy` has one serving path and no switch for its
+    waterfall, and `pio bench` measures backups only (serving is
+    measured by benchmarks/run.py): argparse refuses what went."""
+    from predictionio_tpu.tools.cli import build_parser
+
+    with pytest.raises(SystemExit) as exc:
+        build_parser().parse_args(argv)
+    assert exc.value.code == code
 
 
 def test_pio_deploy_help_documents_retrieval_flags(tmp_path):

@@ -448,12 +448,14 @@ def test_replay_in_process_ann_full_cover_delegate(tmp_path, rng):
 
 
 # ---------------------------------------------------------------------------
-# ISSUE 16 parity gate: legacy capture -> pipelined replay, bitwise
+# ISSUE 16 parity gate: retriever-only capture -> pipeline replay, bitwise
 
 
-def _capture_legacy(tmp_path, engine, inst, retrieval, *, name: str,
-                    delta: dict | None = None):
-    """Capture B=1 golden traffic on a LEGACY-path server; when ``delta``
+def _capture_retriever_only(tmp_path, engine, inst, retrieval, *, name: str,
+                            delta: dict | None = None):
+    """Capture B=1 golden traffic on a server whose models serve through
+    their retriever alone (the pipeline detached: the state
+    ``batch_recommend`` handles for a model without one); when ``delta``
     is given, patch mid-stream so the tail of the capture carries
     patchEpoch 1 (the delta-patched variant capture)."""
     from predictionio_tpu.workflow.create_server import (
@@ -462,10 +464,12 @@ def _capture_legacy(tmp_path, engine, inst, retrieval, *, name: str,
     )
 
     cap_dir = tmp_path / name
-    legacy = EngineServer(engine, inst, capture_dir=str(cap_dir),
-                          capture_sample=1.0, retrieval=retrieval,
-                          serving_pipeline="legacy")
-    st = ServerThread(lambda: create_engine_server_app(legacy))
+    plain = EngineServer(engine, inst, capture_dir=str(cap_dir),
+                         capture_sample=1.0, retrieval=retrieval)
+    for model in plain.deployed.result.models:
+        assert model._retriever is not None
+        model._pipeline = None
+    st = ServerThread(lambda: create_engine_server_app(plain))
     try:
         users = [f"u{i}" for i in range(8)] + ["nobody"]
         for u in users:
@@ -491,42 +495,41 @@ def _capture_legacy(tmp_path, engine, inst, retrieval, *, name: str,
     return records
 
 
-def test_pipelined_replay_of_legacy_capture_bitwise(tmp_path, rng):
-    """ISSUE 16 parity gate: a golden-traffic capture taken on the
-    LEGACY serving path replays 100% bitwise on the device-resident
-    pipelined path — including a delta-patched variant stretch. The
-    capture server forces ``retrieval: {"device": true}`` so both paths
+def test_pipeline_replays_retriever_only_capture_bitwise(tmp_path, rng):
+    """ISSUE 16 parity gate: a golden-traffic capture taken through the
+    retriever alone (host gather, pad and upload around the compiled
+    top-k program) replays 100% bitwise through the device-resident
+    pipeline — including a delta-patched variant stretch. Both sides
     score through the same compiled-executable family (host numpy vs
     XLA differ in reduction order at B=1; the pipeline is pinned
     against the compiled program, which is the TPU serving reality)."""
     from predictionio_tpu.workflow.create_server import EngineServer
 
     engine, inst = _train_quickstart(tmp_path, rng, "pipepartest")
-    retrieval = {"mode": "exact", "device": True}
-    pre = _capture_legacy(tmp_path, engine, inst, retrieval, name="cap0")
+    retrieval = {"mode": "exact"}
+    pre = _capture_retriever_only(tmp_path, engine, inst, retrieval,
+                                  name="cap0")
 
     fresh = EngineServer(engine, inst, batch_window_ms=0,
-                         retrieval=retrieval)  # pipelined default
+                         retrieval=retrieval)
     model = fresh.deployed.result.models[0]
     assert getattr(model, "_pipeline", None) is not None, \
-        "pipeline did not attach — parity test would compare legacy/legacy"
+        "pipeline did not attach — the test would compare a path to itself"
     report = replay_records(pre, server=fresh)
     assert report["total"] == len(pre) and report["skipped"] == 0
     assert report["tiers"]["bitwise"] == len(pre)
     assert report["parityPct"] == 100.0
-    # the two bundles warm DIFFERENT executables (that is the point) so
-    # the exec digest moves; everything else — blob, instance, epoch —
-    # must agree
+    # blob, instance, epoch must agree; only the exec digest may move
     assert set(report["provenance"]["delta"]) <= {"execCacheKey"}
 
-    # delta-patched variant: the legacy capture carries patchEpoch 1 on
-    # its tail; the pipelined replayer applies the same patch (the
+    # delta-patched variant: the capture carries patchEpoch 1 on its
+    # tail; the pipeline-side replayer applies the same patch (the
     # copy-on-write refresh — no recompile) and matches bitwise
     rank = int(np.asarray(model.user_factors).shape[1])
     patch = {"u1": (3.5 * np.ones(rank)).tolist(),
              "u5": (-2.0 * np.ones(rank)).tolist()}
-    tagged = _capture_legacy(tmp_path, engine, inst, retrieval,
-                             name="cap1", delta=patch)
+    tagged = _capture_retriever_only(tmp_path, engine, inst, retrieval,
+                                     name="cap1", delta=patch)
     pre_d = [r for r in tagged if r["provenance"]["patchEpoch"] == 0]
     post_d = [r for r in tagged if r["provenance"]["patchEpoch"] == 1]
     assert len(post_d) == 3
@@ -552,18 +555,18 @@ def test_pipelined_replay_of_legacy_capture_bitwise(tmp_path, rng):
     assert EXEC_CACHE.stats()["misses"] == misses0
 
 
-def test_pipelined_replay_of_legacy_ann_capture_bitwise(tmp_path, rng):
+def test_pipeline_replays_retriever_only_ann_capture_bitwise(tmp_path, rng):
     """ISSUE 16 parity gate, ANN-mode variant: with nprobe >= n_cells
     the index delegates to exact scoring, and the pipeline's gather
     front end hands the ANN retriever a bit-identical query matrix —
-    a legacy ANN capture replays 100% bitwise through the pipelined
-    gather dispatch."""
+    an ANN capture taken through the retriever alone replays 100%
+    bitwise through the pipeline's gather dispatch."""
     from predictionio_tpu.workflow.create_server import EngineServer
 
     engine, inst = _train_quickstart(tmp_path, rng, "pipeanntest")
     retrieval = {"mode": "ann", "min_items": 0, "n_cells": 4, "nprobe": 99}
-    records = _capture_legacy(tmp_path, engine, inst, retrieval,
-                              name="capann")
+    records = _capture_retriever_only(tmp_path, engine, inst, retrieval,
+                                      name="capann")
     fresh = EngineServer(engine, inst, batch_window_ms=0,
                          retrieval=retrieval)
     model = fresh.deployed.result.models[0]

@@ -433,7 +433,7 @@ class TestShardedChaos:
 
     @pytest.mark.chaos
     def test_shard_write_fault_leaves_previous_step(self, tmp_path):
-        from predictionio_tpu.workflow.faults import FAULTS, FaultInjected
+        from predictionio_tpu.faults import FAULTS, FaultInjected
 
         ck = ShardedTrainCheckpointer(tmp_path / "ck")
         ck.save(1, _state())
@@ -449,7 +449,7 @@ class TestShardedChaos:
             self, tmp_path, capsys):
         from predictionio_tpu.obs.metrics import METRICS
         from predictionio_tpu.tools import cli
-        from predictionio_tpu.workflow.faults import FAULTS, FaultInjected
+        from predictionio_tpu.faults import FAULTS, FaultInjected
 
         d = tmp_path / "ck"
         ck = ShardedTrainCheckpointer(d)

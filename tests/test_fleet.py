@@ -33,7 +33,7 @@ import requests
 from predictionio_tpu.obs.metrics import METRICS
 from predictionio_tpu.obs.replay import PROVENANCE_HEADER
 from predictionio_tpu.obs.trace import TRACE_HEADER
-from predictionio_tpu.workflow.faults import FAULTS, FaultInjected
+from predictionio_tpu.faults import FAULTS, FaultInjected
 from predictionio_tpu.workflow.fleet import (
     DEADLINE_HEADER,
     FLEET_REPLICA_HEADER,

@@ -773,14 +773,6 @@ def test_fleet_observability_chaos_acceptance(tmp_path):
         assert 'replica="r0"' in page and 'replica="r1"' in page
         assert "pio_serving_latency_seconds_bucket" in page
 
-        # a traced request for (4): the id must be in r?'s flight ring
-        rid = "chaos-rid-0001"
-        r = requests.post(st.url + "/queries.json",
-                          json={"user": "u3", "num": 2},
-                          headers={TRACE_HEADER: rid,
-                                   DEADLINE_HEADER: "8000"}, timeout=10)
-        assert r.status_code == 200
-
         # -- (2) deadline burst on r0 -> correlated bundle --------------
         # 1 us budgets are expired by the time submit() checks them
         # (the same trigger the PR-5 acceptance uses); >=10 inside 5 s
@@ -848,6 +840,16 @@ def test_fleet_observability_chaos_acceptance(tmp_path):
         assert "r1" in bundle["replicas"]  # the dead r0 has no page now
 
         # -- (4) one-command cross-process trace assembly ----------------
+        # the traced request goes out here, with the hammers stopped:
+        # the router's hop log (512) and a replica's flight ring (256)
+        # are bounded, and sent before the bursts the id had turned out
+        # of both after a few seconds more of hammering
+        rid = "chaos-rid-0001"
+        r = requests.post(st.url + "/queries.json",
+                          json={"user": "u3", "num": 2},
+                          headers={TRACE_HEADER: rid,
+                                   DEADLINE_HEADER: "8000"}, timeout=10)
+        assert r.status_code == 200
         out = subprocess.run(
             [str(REPO / "bin" / "pio"), "trace", rid,
              "--router-url", st.url],

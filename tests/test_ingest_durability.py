@@ -4,7 +4,7 @@
 The contract under test is the one the reference got from HBase's WAL:
 a 201 means the event is durably journaled and WILL reach the backend —
 through a storage outage, a process kill, and a restart — exactly once
-and in order. Deterministic outages come from workflow/faults.py
+and in order. Deterministic outages come from predictionio_tpu/faults.py
 (``eventserver.drain`` / ``journal.append``); the chaos marker's
 conftest guard clears armed faults and bounds each test.
 """
@@ -18,7 +18,7 @@ import requests
 from predictionio_tpu.api import DurableIngestor, create_event_app
 from predictionio_tpu.storage import Storage
 from predictionio_tpu.storage.events_base import EventQuery
-from predictionio_tpu.workflow.faults import FAULTS
+from predictionio_tpu.faults import FAULTS
 
 pytestmark = pytest.mark.ingest
 

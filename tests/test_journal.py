@@ -232,7 +232,7 @@ def test_unreadable_cursor_replays_from_oldest(jdir):
 
 @pytest.mark.chaos
 def test_append_fault_site(jdir):
-    from predictionio_tpu.workflow.faults import FAULTS, FaultInjected
+    from predictionio_tpu.faults import FAULTS, FaultInjected
 
     j = EventJournal(jdir)
     FAULTS.inject("journal.append", "error", times=1)
@@ -245,7 +245,7 @@ def test_append_fault_site(jdir):
 
 @pytest.mark.chaos
 def test_fsync_fault_site(jdir):
-    from predictionio_tpu.workflow.faults import FAULTS, FaultInjected
+    from predictionio_tpu.faults import FAULTS, FaultInjected
 
     j = EventJournal(jdir, fsync="batch")
     j.append(p(0))
@@ -402,7 +402,7 @@ def test_partition_resize_requires_drained(jdir):
 
 @pytest.mark.chaos
 def test_partition_append_fault_site(jdir):
-    from predictionio_tpu.workflow.faults import FAULTS, FaultInjected
+    from predictionio_tpu.faults import FAULTS, FaultInjected
 
     j = _pj(jdir, 2)
     FAULTS.inject("journal.partition_append", "error", times=1)

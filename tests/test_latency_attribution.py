@@ -44,7 +44,7 @@ from predictionio_tpu.obs.waterfall import (
     reset_stage_sink,
     set_stage_sink,
 )
-from predictionio_tpu.workflow.faults import FAULTS
+from predictionio_tpu.faults import FAULTS
 from tests.helpers import ServerThread
 
 

@@ -514,7 +514,7 @@ from predictionio_tpu.models.als import ALSConfig, train_als
 from predictionio_tpu.storage.bimap import BiMap
 from predictionio_tpu.storage.frame import Ratings
 from predictionio_tpu.workflow.checkpoint import ShardedTrainCheckpointer
-from predictionio_tpu.workflow.faults import FAULTS, FaultInjected
+from predictionio_tpu.faults import FAULTS, FaultInjected
 from predictionio_tpu.workflow.supervisor import classify_error
 
 
@@ -567,7 +567,7 @@ def test_host_loss_mid_run_then_elastic_resume_2_to_1(tmp_path):
     uninterrupted run."""
     from predictionio_tpu.models.als import ALSConfig, train_als
     from predictionio_tpu.workflow.checkpoint import ShardedTrainCheckpointer
-    from predictionio_tpu.workflow.faults import FAULTS
+    from predictionio_tpu.faults import FAULTS
 
     ckpt = tmp_path / "ck"
     worker = tmp_path / "chaos_worker.py"

@@ -7,7 +7,7 @@ Covers the satellite checklist:
   and no metric family is declared twice;
 - histogram bucket edges: 0, sub-bucket-min, above-max overflow;
 - concurrent record() from threads AND asyncio tasks;
-- guard: every fault site in workflow/faults.py has a pre-registered
+- guard: every fault site in predictionio_tpu/faults.py has a pre-registered
   ``faults_injected_total{site=...}`` series, and SITES is exactly the
   set of literal FAULTS.fire/afire call sites in the package;
 
@@ -44,8 +44,8 @@ from predictionio_tpu.obs.trace import (
     span,
     trace_event,
 )
-from predictionio_tpu.workflow import faults
-from predictionio_tpu.workflow.faults import FAULTS, FaultInjected
+from predictionio_tpu import faults
+from predictionio_tpu.faults import FAULTS, FaultInjected
 from tests.helpers import ServerThread
 
 # ---------------------------------------------------------------------------
@@ -237,7 +237,7 @@ def test_sites_matches_literal_fire_call_sites():
     """SITES must be exactly the literal FAULTS.fire/afire sites in the
     package — a new injection point without a counter series (or a stale
     SITES entry) fails here."""
-    pkg = pathlib.Path(faults.__file__).resolve().parents[1]
+    pkg = pathlib.Path(faults.__file__).resolve().parent
     found: set[str] = set()
     for p in pkg.rglob("*.py"):
         for m in re.finditer(r'FAULTS\.a?fire\(\s*["\']([^"\']+)["\']',
@@ -254,8 +254,8 @@ def test_every_pio_metric_is_documented_in_operations_md():
     computed names, e.g. the per-stage waterfall histograms built from
     an f-string), and a source scan of literal METRICS registrations
     (catches families a test run might not import). ``Histogram``
-    instances constructed outside the registry (serve_bench's local
-    timer) are intentionally out of scope: they never reach /metrics."""
+    instances constructed outside the registry are intentionally out of
+    scope: they never reach /metrics."""
     import importlib
     import pkgutil
 

@@ -7,7 +7,7 @@ controller's signal math and fail-open contract), the engine server's
 shed/brownout surfaces, the event server's ingest 429 path, the
 feedback publisher's Retry-After honoring, and the ingest journal's
 dynamic Retry-After — all CPU-fast and deterministic (faults armed via
-`workflow/faults.py`, clocks injected where timing matters).
+`predictionio_tpu/faults.py`, clocks injected where timing matters).
 """
 
 from __future__ import annotations
@@ -30,7 +30,7 @@ from predictionio_tpu.workflow.create_server import (
     EngineServer,
     create_engine_server_app,
 )
-from predictionio_tpu.workflow.faults import FAULTS
+from predictionio_tpu.faults import FAULTS
 from predictionio_tpu.workflow.microbatch import DeadlineExceeded, MicroBatcher
 from tests.helpers import ServerThread
 from tests.test_resilience import _poll, _trained

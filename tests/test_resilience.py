@@ -1,6 +1,6 @@
 """Serving resilience layer: deadlines, stuck-dispatch watchdog, degraded
 mode, graceful drain, feedback circuit breaker — proven via the
-deterministic fault-injection harness (predictionio_tpu/workflow/faults.py).
+deterministic fault-injection harness (predictionio_tpu/faults.py).
 
 The acceptance scenario (ISSUE 2): with ``max_inflight`` batches hung via
 injected faults, the watchdog reclaims all pipeline slots, /health.json
@@ -38,7 +38,7 @@ from predictionio_tpu.workflow.create_server import (
     EngineServer,
     create_engine_server_app,
 )
-from predictionio_tpu.workflow.faults import FAULTS, FaultInjected
+from predictionio_tpu.faults import FAULTS, FaultInjected
 from predictionio_tpu.workflow.feedback import FeedbackPublisher
 from predictionio_tpu.workflow.microbatch import (
     DeadlineExceeded,

@@ -551,20 +551,6 @@ def test_dispatch_topk_pad_bucket_lattice(rng):
         "a lattice shape compiled at request time after prewarm"
 
 
-def test_serve_bench_sweep_smoke(rng):
-    """tools/serve_bench.sweep in-process at tiny scale: rows carry the
-    merge-location and cache-hit-rate fields the bench config records."""
-    from predictionio_tpu.tools.serve_bench import format_table, sweep
-
-    rows = sweep((1, 2), n_items=512, rank=8, batch=8, k=5, iters=2)
-    assert [r["ways"] for r in rows] == [1, 2]
-    for r in rows:
-        assert r["merge"] == "device"
-        assert r["exec_cache_hit_rate"] > 0
-        assert r["p50_ms"] > 0 and r["qps"] > 0
-    assert "device" in format_table(rows)
-
-
 def test_sharded_mixin_swaps_in(rng):
     """attach_sharded_retriever must feed the SAME serving surface
     (top_n_from_catalog / top_n_batch) the single-device retriever does."""

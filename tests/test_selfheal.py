@@ -36,7 +36,7 @@ import requests
 from predictionio_tpu.obs.metrics import METRICS
 from predictionio_tpu.storage.journal import JournalFull
 from predictionio_tpu.workflow import fleet as fleet_mod
-from predictionio_tpu.workflow.faults import FAULTS, FaultInjected
+from predictionio_tpu.faults import FAULTS, FaultInjected
 from predictionio_tpu.workflow.fleet import (
     DEADLINE_HEADER,
     FleetRouter,

@@ -35,7 +35,7 @@ from predictionio_tpu.storage.journal import (
     JournalFollower,
     PartitionedJournal,
 )
-from predictionio_tpu.workflow.faults import FAULTS
+from predictionio_tpu.faults import FAULTS
 from predictionio_tpu.workflow.streaming import StreamingUpdater
 from tests.helpers import ServerThread
 

@@ -24,7 +24,7 @@ from predictionio_tpu.testing.sample_engine import (
     make_sample_engine,
 )
 from predictionio_tpu.workflow import Context, run_tune
-from predictionio_tpu.workflow.faults import FAULTS
+from predictionio_tpu.faults import FAULTS
 from predictionio_tpu.workflow.tuning import (
     TrialResult,
     TuneResult,
