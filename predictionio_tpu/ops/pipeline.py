@@ -225,7 +225,8 @@ class ServingPipeline:
         if self.n_rows + 1 > self._cap:
             self._cap = _capacity(self.n_rows)
         # lane width follows the retriever's own contract (lane_dim):
-        # fused mode needs the padded item table's width for the dot;
+        # fused mode needs the width its scoring program takes a query
+        # at (whole 128-lane rows; the program reads the first d_pad);
         # gather mode needs whatever width makes the retriever's lane
         # pad a no-op. 128-rounding is only the fallback for retrievers
         # that predate the accessor.
@@ -252,7 +253,7 @@ class ServingPipeline:
         r = self._retriever
         n_total = r.n_total
         key = ("pipeline", self._token, "fused", b_pad, k_pad, self._cap,
-               self._d_pad, int(r._items.shape[0]), n_total, self._donate)
+               self._d_pad, int(r._items.shape[1]), n_total, self._donate)
 
         def build():
             import jax
@@ -261,8 +262,8 @@ class ServingPipeline:
             if r._mode == "xla":
                 raw = _raw_xla_call(n_total, k_pad)
             else:
-                raw = _raw_call(b_pad, self._d_pad, int(r._items.shape[0]),
-                                n_total, k_pad, r._mode == "interpret")
+                raw = _raw_call(b_pad, *r._items.shape, n_total, k_pad,
+                                r._mode == "interpret")
             packed = n_total < PACKED_IDX_LIMIT
             fn = _fused_fn(raw, packed)
             jitted = (jax.jit(fn, donate_argnums=(0,)) if self._donate

@@ -8,9 +8,13 @@ multi/src/main/scala/ALSAlgorithm.scala:146-200 and ALSModel.scala:200-219).
 On TPU the naive form materializes a [B, N] score matrix in HBM and then
 runs top_k over it — 2x the HBM traffic of the matmul itself for large N.
 
-The kernel here streams item tiles through VMEM once, so the full score
-matrix never exists. A grid step takes a tile of catalog rows sized from
-the shapes (`_tile_rows`: 8,192 rows at B 128 and k 16) and scores it one
+The catalog lies on the device with its items along the lanes,
+``[d_pad, N_pad]``: the rank padded to the 8 sublanes of a float32 tile
+and not to 128 lanes, so that a scan reads the bytes it needs (rank 64:
+all of them; rank 10: 16 sublanes, an eighth of a 128-lane row's). The
+kernel streams item tiles through VMEM once, so the full score matrix
+never exists. A grid step takes a tile of catalog columns sized from the
+shapes (`_tile_rows`: 16,384 items at rank 64 and k 16) and scores it one
 sub-tile at a time on the MXU. Each row of the batch keeps its k best
 scores so far, in descending order, in VMEM scratch; a sub-tile's common
 cost is the matmul, one elementwise maximum and one compare of each row's
@@ -20,17 +24,18 @@ rounds that move each such row's best remaining score into its kept list,
 as many as the most entrants any row has (about one late in a scan, k in
 the first sub-tile). A zero row (padding slot, unknown user) fills its
 list from the first sub-tile and never opens the gate again. Ties keep
-the lower catalog row. On the v5e a scan of 15.2 M items takes 24.9 ms at
-B 128 (a third of the sub-tiles merge, 1.07 rounds each) and 11.8 ms at
-B 8, where the every-tile merge it replaces took 282.9 and 47.7 (PERF.md,
-PR 25); what is left at B 128 is the six bf16 passes of
-``Precision.HIGHEST``, at B 8 the 7.78 GB of lane-padded catalog. The kernel counts the sub-tiles it
+the lower catalog row. On the v5e a scan of 15.2 M items at rank 64 takes
+24.4 ms at B 128 (a third of the sub-tiles merge, 1.07 rounds each) and
+6.3 ms at B 8 (PERF.md, PR 30; 11.6 when the catalog was padded to 128
+lanes); what is left at B 128 is the six bf16 passes of
+``Precision.HIGHEST``, at B 8 the 3.89 GB of catalog at nine tenths of
+the chip's bandwidth. The kernel counts the sub-tiles it
 scanned and merged and the rounds it ran; the counts leave the device in
 the packed result (`_pack`) and reach /stats.json's ``retrieval`` block
 and ``pio_topk_tiles_total`` / ``pio_topk_merge_rounds_total``.
 
-Off-TPU, serving auto-selects a plain-XLA top-k over the same padded
-catalog (`_run_topk_xla` — fast compiled host code with the identical
+Off-TPU, serving auto-selects a plain-XLA top-k over the same device
+array (`_run_topk_xla` — fast compiled host code with the identical
 output contract); ``interpret=True`` forces the kernel under the Pallas
 interpreter (numerically identical, ~65x slower on CPU), the parity
 path the kernel tests pin TPU semantics with.
@@ -224,14 +229,23 @@ def _pad_to(x, mult, axis, value=0.0):
     return np.pad(x, pad, constant_values=value) if isinstance(x, np.ndarray) else None
 
 
+def _lanes(d: int) -> int:
+    """Width of a query of rank ``d``: whole 128-lane groups, of which
+    the scoring programs read the catalog's ``d_pad`` first."""
+    return -(-d // 128) * 128
+
+
 #: VMEM the kernel sizes its tile for: the double-buffered catalog tile,
 #: one sub-tile's scores and the kept lists, inside Mosaic's 16 MiB scoped
 #: default with room left for the compiler's own temporaries.
 _VMEM_BUDGET = 12 << 20
 
-#: Most catalog rows a grid step takes; `_pad_items` pads a large catalog
-#: to a multiple of it so that every smaller power-of-two tile divides it.
-_TILE_MAX = 8192
+#: Most items a grid step takes; `_padded_shape` pads a large catalog to a
+#: multiple of it so that every smaller power-of-two tile divides it. At
+#: rank 64 the VMEM budget allows it (two buffers of 4 MiB), and the scan
+#: of 15.2 M items at B 8 takes 5.24 ms where 8,192 take 5.33 (PERF.md,
+#: PR 30).
+_TILE_MAX = 16384
 
 #: Scores of one gated sub-tile, [B, chunk]. The chunk narrows as the
 #: batch grows, so the entrants a sub-tile holds (B * k * chunk / rows
@@ -248,11 +262,12 @@ _CHUNK_MAX = 2048
 
 
 def _tile_rows(B: int, D: int, k: int, N_pad: int) -> tuple[int, int]:
-    """(tile, chunk): catalog rows per grid step and per gated sub-tile,
-    from the shapes alone. The tile is the largest power-of-two multiple
-    of 128 that divides ``N_pad`` and fits the VMEM budget beside the
-    sub-tile's scores and the kept lists (which grow with k, so a large
-    k shrinks the tile); the chunk is `_CHUNK_ELEMS / B`, at least one
+    """(tile, chunk): items per grid step and per gated sub-tile, from
+    the shapes alone (``D`` the catalog's padded rank, its sublanes). The
+    tile is the largest power-of-two multiple of 128 that divides
+    ``N_pad`` and fits the VMEM budget beside the sub-tile's scores and
+    the kept lists (which grow with k, so a large k shrinks the tile, as
+    a large rank does); the chunk is `_CHUNK_ELEMS / B`, at least one
     lane group and at most `_CHUNK_MAX` and the tile."""
     k_lanes = -(-k // 128) * 128
     chunk = 128
@@ -272,9 +287,10 @@ def _tile_rows(B: int, D: int, k: int, N_pad: int) -> tuple[int, int]:
 def _topk_kernel(q_ref, items_ref, vals_ref, idx_ref, cnt_ref,
                  kv_ref, ki_ref, kth_ref, s_ref, *,
                  k, tile_n, chunk, n_total, n_pad):
-    """One grid step: score a tile of the catalog, sub-tile by sub-tile,
-    and let a sub-tile touch the kept lists only if some row's best
-    score in it beats that row's own k-th kept value.
+    """One grid step: score a tile of the catalog (``items_ref``
+    [D, tile_n], an item a lane), sub-tile by sub-tile, and let a
+    sub-tile touch the kept lists only if some row's best score in it
+    beats that row's own k-th kept value.
 
     kv_ref / ki_ref: [B, k_lanes] kept values (descending) and their
     catalog rows, lane-padded so that an insertion is one lane roll;
@@ -301,7 +317,7 @@ def _topk_kernel(q_ref, items_ref, vals_ref, idx_ref, cnt_ref,
         cnt_ref[1] = 0
         cnt_ref[2] = 0
 
-    q = q_ref[...]  # [B, D]
+    q = q_ref[:, :items_ref.shape[0]]  # [B, D] of the 128-lane query
     col = jax.lax.broadcasted_iota(jnp.int32, (B, chunk), 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, kv_ref.shape, 1)
 
@@ -344,7 +360,7 @@ def _topk_kernel(q_ref, items_ref, vals_ref, idx_ref, cnt_ref,
     def sub_tile(c, carry):
         off = pl.multiple_of(c * chunk, chunk)
         s = jax.lax.dot_general(
-            q, items_ref[pl.ds(off, chunk), :], (((1,), (1,)), ((), ())),
+            q, items_ref[:, pl.ds(off, chunk)], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST,  # full-f32 MXU passes:
             # scores must rank stably against host-side float32 references
@@ -387,7 +403,8 @@ def _topk_kernel(q_ref, items_ref, vals_ref, idx_ref, cnt_ref,
 
 def _raw_call(B, D, N_pad, n_total, k, interpret, *, tile_n=None):
     """The un-jitted fused top-k pallas call, ``(q, items) -> (values
-    [B, k], rows [B, k], counters int32[3])``: shared by the jitted
+    [B, k], rows [B, k], counters int32[3])`` over a query of
+    `_lanes(D)` lanes and the catalog as [D, N_pad]: shared by the jitted
     serving entry (`_build_call`) and the serving pipeline's fused
     program (ops/pipeline.py), which composes it with the row gather.
     ``tile_n`` is for tests that pin one tile size; serving never passes
@@ -407,8 +424,9 @@ def _raw_call(B, D, N_pad, n_total, k, interpret, *, tile_n=None):
         kernel,
         grid=(N_pad // tile,),
         in_specs=[
-            pl.BlockSpec((B, D), lambda j: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((tile, D), lambda j: (j, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((B, _lanes(D)), lambda j: (0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((D, tile), lambda j: (0, j), memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((B, k), lambda j: (0, 0), memory_space=pltpu.VMEM),
@@ -496,14 +514,14 @@ def _aot_with_packing(call, n_total: int, B: int, D: int, N_pad: int):
 
         is_packed = True
     compiled = jax.jit(fn).lower(
-        jax.ShapeDtypeStruct((B, D), jnp.float32),
-        jax.ShapeDtypeStruct((N_pad, D), jnp.float32),
+        jax.ShapeDtypeStruct((B, _lanes(D)), jnp.float32),
+        jax.ShapeDtypeStruct((D, N_pad), jnp.float32),
     ).compile()
     return compiled, is_packed
 
 
 def _raw_xla_call(n_total: int, k: int):
-    """Un-jitted plain-XLA top-k over the full padded catalog — the
+    """Un-jitted plain-XLA top-k over the same device array — the
     serving path for NON-TPU backends, where running the Pallas kernel
     under ``interpret=True`` is a correctness tool, not a serving path
     (the interpreter is orders of magnitude slower than compiled XLA
@@ -512,9 +530,9 @@ def _raw_xla_call(n_total: int, k: int):
     import jax
     import jax.numpy as jnp
 
-    def run(q, items):  # q [B, D_pad] f32, items [N_pad, D_pad] f32
+    def run(q, items):  # q [B, 128 lanes] f32, items [D_pad, N_pad] f32
         scores = jax.lax.dot_general(
-            q, items, (((1,), (1,)), ((), ())),
+            q[:, :items.shape[0]], items, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST,  # rank-stable vs the
             # kernel / sharded paths and host f32 references (DEFAULT
@@ -546,8 +564,7 @@ def _run_topk_xla(q: np.ndarray, items_dev, n_total: int, k: int):
 
     def invoke(qp, k_pad):
         call, is_packed = _build_xla_call(
-            qp.shape[0], items_dev.shape[1], items_dev.shape[0],
-            n_total, k_pad)
+            qp.shape[0], *items_dev.shape, n_total, k_pad)
         # the compiled executable takes the padded numpy batch directly —
         # no jnp.asarray bounce through the default device
         return call(qp, items_dev), is_packed
@@ -556,23 +573,52 @@ def _run_topk_xla(q: np.ndarray, items_dev, n_total: int, k: int):
 
 
 def _padded_shape(n: int, d: int) -> tuple[int, int]:
-    """(rows, lanes) of the catalog on the device: features padded to
-    the 128-lane width, rows so that the kernel's tiles divide them: to
-    a multiple of the largest power of two between 128 and `_TILE_MAX`
-    that is at most a sixteenth of the rows (a small catalog is not
-    padded by a large tile's worth)."""
+    """(sublanes, lanes) of an [n, d] catalog on the device: the rank
+    padded to the 8 sublanes of a float32 tile, the items so that the
+    kernel's tiles divide them: to a multiple of the largest power of
+    two between 128 and `_TILE_MAX` that is at most a sixteenth of the
+    items (a small catalog is not padded by a large tile's worth)."""
     quantum = 128
     while quantum < _TILE_MAX and quantum * 16 <= n:
         quantum *= 2
-    return -(-n // quantum) * quantum, -(-d // 128) * 128
+    return -(-d // 8) * 8, -(-n // quantum) * quantum
 
 
-def _pad_items(items: np.ndarray) -> np.ndarray:
-    """The catalog zero-padded to `_padded_shape`: one allocation and
-    one copy into it."""
+#: Catalog rows one block of the upload holds (128 MiB at rank 64).
+_UPLOAD_ROWS = 1 << 19
+
+
+@functools.lru_cache(maxsize=None)
+def _place_block():
+    """The jitted step of `_pad_items`: a block of catalog rows
+    transposed into its columns of the device array. The array is
+    donated where the backend can alias it; elsewhere a step copies it,
+    which the sizes that run there can afford."""
+    import jax
+
+    def place(out, block, start):
+        return jax.lax.dynamic_update_slice(out, block.T, (0, start))
+
+    donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
+    return jax.jit(place, donate_argnums=donate)
+
+
+def _pad_items(items: np.ndarray):
+    """The catalog as the kernel and the XLA program read it: a zeroed
+    [d_pad, N_pad] device array (`_padded_shape`) with item i in column
+    i. The model's own [N, d] array goes up block by block and is
+    transposed on the device, each block waited for: the host holds no
+    second copy, the device one block beside the array (0.7 s for 3.89
+    GB on the v5e; PERF.md, PR 30)."""
+    import jax
+    import jax.numpy as jnp
+
     n, d = items.shape
-    out = np.zeros(_padded_shape(n, d), np.float32)
-    out[:n, :d] = items
+    out = jnp.zeros(_padded_shape(n, d), jnp.float32)
+    place = _place_block()
+    for start in range(0, n, _UPLOAD_ROWS):
+        out = jax.block_until_ready(
+            place(out, items[start:start + _UPLOAD_ROWS], start))
     return out
 
 
@@ -644,9 +690,7 @@ def _run_topk(q: np.ndarray, items_dev, n_total: int, k: int,
 
     def invoke(qp, k_pad):
         call, is_packed = _build_call(
-            qp.shape[0], items_dev.shape[1], items_dev.shape[0], n_total,
-            k_pad, interpret,
-        )
+            qp.shape[0], *items_dev.shape, n_total, k_pad, interpret)
         return call(qp, items_dev), is_packed
 
     return _dispatch_topk(q, n_total, k, invoke, on_scan)
@@ -673,13 +717,11 @@ def topk_scores(queries, items, k: int, *, interpret=None):
     are -1. Runs the Pallas kernel natively on TPU, plain XLA elsewhere;
     ``interpret=True`` forces the interpret-mode kernel (parity testing).
     """
-    import jax.numpy as jnp
-
     mode = _resolve_topk_mode(interpret)
     q = np.asarray(queries, dtype=np.float32)
     it = np.asarray(items, dtype=np.float32)
     n_total = it.shape[0]
-    items_dev = jnp.asarray(_pad_items(it))
+    items_dev = _pad_items(it)
     if mode == "xla":
         return _run_topk_xla(q, items_dev, n_total, k)
     return _run_topk(q, items_dev, n_total, k, mode == "interpret")
@@ -692,24 +734,22 @@ class DeviceRetriever:
     building a new DeviceRetriever and swapping the reference)."""
 
     def __init__(self, items: np.ndarray, *, interpret=None):
-        import jax
-        import jax.numpy as jnp
-
         self._mode = _resolve_topk_mode(interpret)
         self._scan_lock = threading.Lock()
-        self._scan = np.zeros(3, np.int64)  # scanned, merged, rounds
+        # sub-tiles scanned, merged, rounds; catalog bytes needed, read
+        self._scan = np.zeros(5, np.int64)
         with span("deploy.attach_retriever.catalog_pad",
                   sink=STARTUP.phase) as s:
+            # the model's own array where it is float32 already
             it = np.asarray(items, dtype=np.float32)
             self.n_total, self.dim = it.shape
-            it = _pad_items(it)
             s["bytes"] = int(it.nbytes)
         with span("deploy.attach_retriever.catalog_upload",
-                  sink=STARTUP.phase, bytes=int(it.nbytes)):
-            # waited for, so that the upload's seconds stand here and not
-            # in whatever first needs the catalog
-            self._items = jax.block_until_ready(
-                jax.device_put(jnp.asarray(it)))
+                  sink=STARTUP.phase) as s:
+            # every block waited for, so that the upload's seconds stand
+            # here and not in whatever first needs the catalog
+            self._items = _pad_items(it)
+            s["bytes"] = int(self._items.nbytes)
 
     @property
     def kernel(self) -> str:
@@ -724,29 +764,37 @@ class DeviceRetriever:
         (``_dispatch_topk``'s lane pad is then a no-op), which is what
         lets the device-resident pipeline's gathered query matrix hand
         off with zero re-pad."""
-        return int(self._items.shape[1])
+        return _lanes(self.dim)
 
     def record_scan(self, counts) -> None:
         """Add one kernel call's counters (sub-tiles scanned, sub-tiles
         merged, extraction rounds) to this retriever's totals and to the
-        registry. Called with what `_unpack` found in the pulled buffer,
-        by ``topk`` and by the serving pipeline's fused dispatch."""
+        registry, and with them the bytes of catalog the scan needed
+        (float32 factors of the real items) and those of the device
+        array it read. Called with what `_unpack` found in the pulled
+        buffer, by ``topk`` and by the serving pipeline's fused
+        dispatch."""
         scanned, merged, rounds = (int(c) for c in counts)
         with self._scan_lock:
-            self._scan += (scanned, merged, rounds)
+            self._scan += (scanned, merged, rounds,
+                           self.n_total * self.dim * 4,
+                           int(self._items.nbytes))
         _M_TILES.inc(scanned, event="scanned")
         _M_TILES.inc(merged, event="merged")
         _M_ROUNDS.inc(rounds)
 
     def stats(self) -> dict:
-        """/stats.json's ``retrieval`` block. The three counters stay 0
-        where the XLA program scores the catalog: it has no tiles."""
+        """/stats.json's ``retrieval`` block. The counters stay 0 where
+        the XLA program scores the catalog: it has no tiles and reports
+        no scan."""
         with self._scan_lock:
-            scanned, merged, rounds = (int(c) for c in self._scan)
+            scanned, merged, rounds, needed, read = (
+                int(c) for c in self._scan)
         return {"mode": "exact", "kernel": self._mode,
                 "nTotal": self.n_total, "sharded": False,
                 "tilesScanned": scanned, "tilesMerged": merged,
-                "mergeRounds": rounds}
+                "mergeRounds": rounds, "catalogBytesNeeded": needed,
+                "catalogBytesScanned": read}
 
     def topk(self, queries, k: int):
         """(values [B, k], indices [B, k]) — indices -1 beyond catalog."""
@@ -775,14 +823,12 @@ class DeviceRetriever:
                 with span("deploy.prewarm.program", sink=STARTUP.phase,
                           kind="topk", b_pad=b_pad, k_pad=k_pad):
                     if self._mode == "xla":
-                        _build_xla_call(b_pad, self._items.shape[1],
-                                        self._items.shape[0], self.n_total,
-                                        k_pad, pin=True)
+                        _build_xla_call(b_pad, *self._items.shape,
+                                        self.n_total, k_pad, pin=True)
                     else:
-                        _build_call(b_pad, self._items.shape[1],
-                                    self._items.shape[0], self.n_total,
-                                    k_pad, self._mode == "interpret",
-                                    pin=True)
+                        _build_call(b_pad, *self._items.shape,
+                                    self.n_total, k_pad,
+                                    self._mode == "interpret", pin=True)
                 warmed.append((b_pad, k_pad))
         return warmed
 

@@ -62,6 +62,36 @@ def test_fused_dispatch_bitwise_matches_legacy_host_gather(rng):
     assert np.array_equal(idx, legacy_i)
 
 
+@pytest.mark.parametrize("interpret", [True, None],
+                         ids=["kernel", "default-xla"])
+@pytest.mark.parametrize("dim", [10, 64, 100])
+def test_fused_program_scores_like_the_retriever_at_its_lane_dim(
+        rng, dim, interpret):
+    """The query table keeps whole 128-lane rows
+    (`DeviceRetriever.lane_dim`: it is gathered by row, never scanned)
+    while the catalog holds the rank in sublane groups of 8; the scoring
+    program reads the query's first `d_pad` lanes. The fused program
+    answers bit for bit what the retriever's own program answers for
+    the same rows, zero rows included, and feeds the retriever's
+    counters."""
+    items = rng.standard_normal((3000, dim)).astype(np.float32)
+    users = rng.standard_normal((40, dim)).astype(np.float32)
+    ret = DeviceRetriever(items, interpret=interpret)
+    pipe = ServingPipeline(users, ret)
+    assert pipe._d_pad == ret.lane_dim == 128
+    assert ret._items.shape[0] == -(-dim // 8) * 8
+    assert pipe._qtab.shape == (pipe._cap, 128)
+    rows = np.array([7, -1, 39, 7, 0], np.int32)
+    vals, idx = pipe.topk_rows(rows, 10)
+    q = users[rows]
+    q[1] = 0.0
+    want_v, want_i = ret.topk(q, 10)
+    assert np.array_equal(vals, want_v)
+    assert np.array_equal(idx, want_i)
+    scans = 2 if interpret else 0  # the XLA program reports none
+    assert ret.stats()["catalogBytesNeeded"] == scans * items.nbytes
+
+
 def test_unknown_rows_gather_the_zero_sentinel(rng):
     """Negative / out-of-table row ids must score exactly like the
     zero-padded rows the legacy path builds with np.pad."""

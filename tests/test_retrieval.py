@@ -27,6 +27,8 @@ def exact_topk(q, items, k):
     (8, 5000, 64, 16),     # five 1,024-row tiles, the last ragged
     (8, 140_000, 16, 10),  # eighteen 8,192-row tiles, the last ragged
     (128, 3000, 32, 16),   # b_pad 128: 512-column sub-tiles
+    (8, 3000, 100, 16),    # rank 100: 104 sublanes, four of them zeros
+    (4, 3000, 128, 16),    # rank 128: the widest a 128-lane pad held
 ])
 def test_matches_exact(rng, B, N, D, k, interpret):
     q = rng.standard_normal((B, D)).astype(np.float32)
@@ -44,14 +46,16 @@ def _old_topk_call(B, D, N_pad, n_total, k, tile_n):
     """The kernel as it stood before ISSUE 25 (merge gated on the best
     score of ANY row against the lowest kept value of ANY row, k
     unconditional extraction rounds over [B, k + T]), kept here as the
-    reference the new kernel's answers must equal bit for bit."""
+    reference the new kernel's answers must equal bit for bit. It reads
+    the catalog as the device holds it since ISSUE 30, [D, N_pad]: what
+    it pins is the merge, not a layout."""
     import functools
 
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    def kernel(q_ref, items_ref, vals_ref, idx_ref):
+    def kernel(q_ref, items_ref, vals_ref, idx_ref):  # items [D, tile_n]
         j = pl.program_id(0)
 
         @pl.when(j == 0)
@@ -60,7 +64,7 @@ def _old_topk_call(B, D, N_pad, n_total, k, tile_n):
             idx_ref[:] = jnp.full(idx_ref.shape, -1, idx_ref.dtype)
 
         scores = jax.lax.dot_general(
-            q_ref[:], items_ref[:], (((1,), (1,)), ((), ())),
+            q_ref[:, :D], items_ref[:], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST)
         cand = j * tile_n + jax.lax.broadcasted_iota(
@@ -95,8 +99,8 @@ def _old_topk_call(B, D, N_pad, n_total, k, tile_n):
 
     return pl.pallas_call(
         kernel, grid=(N_pad // tile_n,),
-        in_specs=[pl.BlockSpec((B, D), lambda j: (0, 0)),
-                  pl.BlockSpec((tile_n, D), lambda j: (j, 0))],
+        in_specs=[pl.BlockSpec((B, 128), lambda j: (0, 0)),
+                  pl.BlockSpec((D, tile_n), lambda j: (0, j))],
         out_specs=[pl.BlockSpec((B, k), lambda j: (0, 0)),
                    pl.BlockSpec((B, k), lambda j: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((B, k), jnp.float32),
@@ -105,12 +109,14 @@ def _old_topk_call(B, D, N_pad, n_total, k, tile_n):
 
 
 def _padded(q, items):
-    """(q, items) as the kernel takes them: 128 lanes, whole tiles."""
+    """(q [B, 128], items [d_pad, N_pad]) as the kernel takes them: the
+    query in 128 lanes, the catalog's rank in whole sublane groups, its
+    items in whole tiles."""
     from predictionio_tpu.ops.retrieval import _pad_items
 
     qp = np.zeros((q.shape[0], 128), np.float32)
     qp[:, :q.shape[1]] = q
-    return qp, _pad_items(items)
+    return qp, np.asarray(_pad_items(items))
 
 
 def test_answers_are_the_old_extractions_bit_for_bit(rng):
@@ -127,13 +133,13 @@ def test_answers_are_the_old_extractions_bit_for_bit(rng):
     q = rng.standard_normal((B, D)).astype(np.float32)
     q[3] = 0.0  # the sentinel row: every score ties at 0
     qp, ip = _padded(q, items)
-    assert ip.shape[0] % 512 == 0 and ip.shape[0] > N
-    want_v, want_i = _old_topk_call(B, 128, ip.shape[0], N, k, 512)(qp, ip)
-    got_v, got_i, counts = _raw_call(B, 128, ip.shape[0], N, k, True,
+    assert ip.shape[0] == D and ip.shape[1] % 512 == 0 and ip.shape[1] > N
+    want_v, want_i = _old_topk_call(B, *ip.shape, N, k, 512)(qp, ip)
+    got_v, got_i, counts = _raw_call(B, *ip.shape, N, k, True,
                                      tile_n=512)(qp, ip)
     assert np.array_equal(np.asarray(got_v), np.asarray(want_v))
     assert np.array_equal(np.asarray(got_i), np.asarray(want_i))
-    assert int(counts[0]) == ip.shape[0] // 512
+    assert int(counts[0]) == ip.shape[1] // 512
     assert list(np.asarray(want_i)[3]) == list(range(k))  # lower row wins
 
 
@@ -182,10 +188,11 @@ def test_counters_equal_a_replay_of_the_gate(rng, B, N, k):
     # long rows first, so that the late sub-tiles hold no entrant
     items = items[np.argsort(-np.abs(items).sum(axis=1), kind="stable")]
     qp, ip = _padded(q, items)
-    tile, chunk = _tile_rows(B, 128, k, ip.shape[0])
-    assert ip.shape[0] // tile > 1 or N == 2048
-    got_v, got_i, counts = _raw_call(B, 128, ip.shape[0], N, k, True)(qp, ip)
-    want_v, want_i, want_counts = _replay_gate(qp, ip, N, k, tile, chunk)
+    tile, chunk = _tile_rows(B, ip.shape[0], k, ip.shape[1])
+    assert ip.shape[1] // tile > 1 or N == 2048
+    got_v, got_i, counts = _raw_call(B, *ip.shape, N, k, True)(qp, ip)
+    want_v, want_i, want_counts = _replay_gate(qp[:, :ip.shape[0]], ip.T,
+                                               N, k, tile, chunk)
     assert np.array_equal(np.asarray(got_v), want_v)
     assert np.array_equal(np.asarray(got_i), want_i)
     assert [int(c) for c in counts] == want_counts
@@ -234,6 +241,90 @@ def test_zero_rows_among_real_ones_stop_merging(rng):
     r.topk(q, 10)
     assert r.stats()["tilesScanned"] == 2 * st["tilesScanned"]
     assert DeviceRetriever(items[:500]).stats()["tilesScanned"] == 0
+
+
+@pytest.mark.parametrize("rank", [10, 32, 64, 100, 128])
+def test_layout_round_trip(rng, rank, monkeypatch):
+    """`_pad_items` and back gives the catalog: item i is column i of a
+    [d_pad, N_pad] array, the rank in whole sublane groups of 8 and not
+    in 128 lanes, everything beyond the catalog zero. The upload goes in
+    blocks (here three, the last ragged) and no block shows a seam."""
+    from predictionio_tpu.ops import retrieval
+
+    monkeypatch.setattr(retrieval, "_UPLOAD_ROWS", 1024)
+    n = 2500
+    items = rng.standard_normal((n, rank)).astype(np.float32)
+    dev = retrieval._pad_items(items)
+    d_pad, n_pad = retrieval._padded_shape(n, rank)
+    assert dev.shape == (d_pad, n_pad) == (-(-rank // 8) * 8, 2560)
+    back = np.asarray(dev)
+    assert np.array_equal(back[:rank, :n].T, items)
+    assert not back[rank:].any() and not back[:, n:].any()
+    # the bytes a scan reads are the bytes it needs, but for that padding
+    assert dev.nbytes * rank * n == items.nbytes * d_pad * n_pad
+    # a strided view of a wider array (a caller's slice) goes up the same
+    wide = rng.standard_normal((n, rank + 3)).astype(np.float32)
+    assert np.array_equal(
+        np.asarray(retrieval._pad_items(wide[:, 3:]))[:rank, :n].T,
+        wide[:, 3:])
+
+
+@pytest.mark.parametrize("interpret", [True, None],
+                         ids=["kernel", "default-xla"])
+def test_tie_across_the_seams_of_a_sub_tile_answers_the_lower_row(
+        rng, interpret):
+    """Equal scores inside ONE sub-tile whose items are not neighbours
+    in memory: across two lane groups (columns 127 and 128), at the same
+    lane of two lane groups (the halves the gate folds onto each other:
+    columns 40 and 1064) and at a sub-tile's two ends. Each tie answers
+    the lower catalog row first, as a stable sort of the scores does."""
+    B, N, D, k = 8, 5000, 64, 8
+    items = 0.01 * rng.standard_normal((N, D)).astype(np.float32)
+    q = rng.standard_normal((B, D)).astype(np.float32)
+    # four pairs of duplicates inside the second 2,048-column sub-tile,
+    # each pair the best of some row
+    pairs = [(2048 + 127, 2048 + 128), (2048 + 40, 2048 + 1064),
+             (2048, 4095), (2048 + 1023, 2048 + 1024)]
+    for row, (lo, hi) in enumerate(pairs):
+        items[lo] = items[hi] = (row + 2) * q[row] / np.linalg.norm(q[row])
+    vals, idx = topk_scores(q, items, k, interpret=interpret)
+    want_v, want_i = exact_topk(q, items, k)
+    assert np.array_equal(idx, want_i)
+    for row, (lo, hi) in enumerate(pairs):
+        assert list(idx[row, :2]) == [lo, hi]
+        assert vals[row, 0] == vals[row, 1]
+
+
+def test_catalog_bytes_add_up_per_scan(rng):
+    """/stats.json's `retrieval.catalogBytesNeeded` and
+    `catalogBytesScanned`: each scan adds the float32 factors of the
+    real items and the bytes of the device array it read, through
+    `topk` and through the serving pipeline's fused dispatch alike;
+    their ratio is the fill of the layout (100% less the padding)."""
+    from predictionio_tpu.ops.pipeline import ServingPipeline
+
+    n, rank = 3000, 10
+    items = rng.standard_normal((n, rank)).astype(np.float32)
+    users = rng.standard_normal((20, rank)).astype(np.float32)
+    r = DeviceRetriever(items, interpret=True)
+    assert r.lane_dim == 128 and r._items.shape == (16, 3072)
+    st = r.stats()
+    assert st["catalogBytesNeeded"] == st["catalogBytesScanned"] == 0
+    r.topk(users[:3], 5)
+    st = r.stats()
+    assert st["catalogBytesNeeded"] == n * rank * 4
+    assert st["catalogBytesScanned"] == 16 * 3072 * 4 == r._items.nbytes
+    ServingPipeline(users, r).topk_rows(np.array([1, 2], np.int32), 5)
+    r.topk(users[0], 5)
+    st = r.stats()
+    assert st["catalogBytesNeeded"] == 3 * n * rank * 4
+    assert st["catalogBytesScanned"] == 3 * r._items.nbytes
+    # a 128-lane pad of this rank would have read eight times as much
+    assert st["catalogBytesScanned"] * 8 == 3 * 3072 * 128 * 4
+    # the XLA program reports no scan
+    x = DeviceRetriever(items)
+    x.topk(users[:3], 5)
+    assert x.stats()["catalogBytesScanned"] == 0
 
 
 @pytest.mark.parametrize("interpret", [True, None],
@@ -383,11 +474,18 @@ def test_sharded_bitwise_parity(rng, width):
     catalog rows) and all-zero scores (a zero query ties the whole
     catalog), where the tie-break order is the contract. Works because
     the tiled all-gather is shard-major (candidates in ascending global
-    index order) and top_k breaks ties by lowest index on both paths."""
+    index order) and top_k breaks ties by lowest index on both paths.
+
+    Factors are small integers, so a score is exact whatever the order
+    of its sum: since ISSUE 30 the single device holds the catalog as
+    [d_pad, N_pad] and sums a score down 24 sublanes, a shard holds
+    [S, 128] and sums along 128 lanes, and the CPU backend's two dots
+    round iid normal factors apart in the last bit of a few scores (the
+    merge and the tie order, which this test pins, never moved)."""
     N, D, k = 1536, 24, 10
-    base = rng.standard_normal((N - 64, D)).astype(np.float32)
+    base = rng.integers(-4, 5, (N - 64, D)).astype(np.float32)
     items = np.concatenate([base, base[:64]], axis=0)  # exact dup rows
-    q = rng.standard_normal((5, D)).astype(np.float32)
+    q = rng.integers(-4, 5, (5, D)).astype(np.float32)
     q[0] = 0.0  # full-catalog tie
     want_v, want_i = DeviceRetriever(items).topk(q, k)
     ret = _sharded(items, axis_len=width)

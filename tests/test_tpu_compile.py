@@ -25,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from predictionio_tpu.models.als import _layout_shardings, make_train_step
 from predictionio_tpu.ops.neighbors import build_bilinear_layout
 from predictionio_tpu.ops.pipeline import _capacity, _fused_fn
-from predictionio_tpu.ops.retrieval import (ShardedDeviceRetriever,
+from predictionio_tpu.ops.retrieval import (ShardedDeviceRetriever, _lanes,
                                             _padded_shape, _query_shapes,
                                             _raw_call, _tile_rows)
 
@@ -33,7 +33,7 @@ from predictionio_tpu.ops.retrieval import (ShardedDeviceRetriever,
 N_ITEMS, N_USERS, RANK = 26_744, 138_493, 64
 
 #: the serving cells' catalog and query table (benchmarks/configs/
-#: als-amazon18.json): 7.78 GB on the chip, so shapes only
+#: als-amazon18.json): 3.89 GB on the chip, so shapes only
 CELL_ITEMS, CELL_USERS = 15_200_000, 1_000_000
 
 
@@ -52,26 +52,28 @@ def v5e():
     return topo.devices
 
 
-def _compile_kernel(dev, n_items, b_pad, k_pad):
-    n_pad, d_pad = _padded_shape(n_items, RANK)
+def _compile_kernel(dev, n_items, b_pad, k_pad, rank=RANK):
+    d_pad, n_pad = _padded_shape(n_items, rank)
     one = SingleDeviceSharding(dev)
     return jax.jit(_raw_call(b_pad, d_pad, n_pad, n_items, k_pad,
                              False)).lower(
-        jax.ShapeDtypeStruct((b_pad, d_pad), jnp.float32, sharding=one),
-        jax.ShapeDtypeStruct((n_pad, d_pad), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((b_pad, _lanes(d_pad)), jnp.float32,
+                             sharding=one),
+        jax.ShapeDtypeStruct((d_pad, n_pad), jnp.float32, sharding=one),
     ).compile()
 
 
 def _compile_fused(dev, n_items, n_users, b_pad, k_pad):
-    n_pad, d_pad = _padded_shape(n_items, RANK)
+    d_pad, n_pad = _padded_shape(n_items, RANK)
     cap = _capacity(n_users)
     one = SingleDeviceSharding(dev)
     raw = _raw_call(b_pad, d_pad, n_pad, n_items, k_pad, False)
     # as ServingPipeline._exec_fused builds it on a TPU: donating
     return jax.jit(_fused_fn(raw, True), donate_argnums=(0,)).lower(
         jax.ShapeDtypeStruct((b_pad,), jnp.int32, sharding=one),
-        jax.ShapeDtypeStruct((cap, d_pad), jnp.float32, sharding=one),
-        jax.ShapeDtypeStruct((n_pad, d_pad), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((cap, _lanes(d_pad)), jnp.float32,
+                             sharding=one),
+        jax.ShapeDtypeStruct((d_pad, n_pad), jnp.float32, sharding=one),
     ).compile()
 
 
@@ -96,18 +98,20 @@ def test_fused_pipeline_program_over_the_prewarm_lattice(v5e):
 
 @pytest.mark.parametrize("b_pad", [8, 32, 128])
 def test_topk_kernel_at_the_serving_cells_shape(v5e, b_pad):
-    """15.2 M items padded to 128 lanes, k_pad 16: the kernel alone and
-    inside the fused program, shapes only. What Mosaic refuses at this
-    size (a dynamic loop, an unaligned store, a tile over the VMEM
-    budget) fails here, not on the chip."""
-    n_pad, d_pad = _padded_shape(CELL_ITEMS, RANK)
+    """15.2 M items along the lanes at rank 64, k_pad 16: the kernel
+    alone and inside the fused program, shapes only. What Mosaic refuses
+    at this size (a dynamic loop, an unaligned store, a tile over the
+    VMEM budget) fails here, not on the chip."""
+    d_pad, n_pad = _padded_shape(CELL_ITEMS, RANK)
+    assert d_pad == RANK  # lane-dense: no byte of the scan is a pad's
     tile, chunk = _tile_rows(b_pad, d_pad, 16, n_pad)
-    assert 2048 <= tile <= 8192 and n_pad % tile == 0 and tile % chunk == 0
+    assert 2048 <= tile <= 16384 and n_pad % tile == 0 and tile % chunk == 0
     _compile_kernel(v5e[0], CELL_ITEMS, b_pad, 16)
     exe = _compile_fused(v5e[0], CELL_ITEMS, CELL_USERS, b_pad, 16)
     _assert_one_kernel_and_the_packed_result(exe, b_pad, 16)
-    # the catalog, the user table and little else
-    assert exe.memory_analysis().argument_size_in_bytes > 8.3e9
+    # the dense catalog (3.89 GB), the user table (gathered by row and
+    # never scanned: whole 128-lane rows, 0.58 GB) and little else
+    assert 4.4e9 < exe.memory_analysis().argument_size_in_bytes < 4.6e9
 
 
 def test_topk_kernel_at_a_large_k(v5e):
@@ -115,6 +119,19 @@ def test_topk_kernel_at_a_large_k(v5e):
     kept lists (four lane groups wide) take room beside the tile."""
     for b_pad, k_pad in ((8, 504), (128, 504)):
         _compile_kernel(v5e[0], N_ITEMS, b_pad, k_pad)
+
+
+@pytest.mark.parametrize("rank", [10, 32, 100, 128])
+def test_topk_kernel_at_the_ranks_a_template_may_train(v5e, rank):
+    """The layout adapts to the rank alone: 10 (the recommendation
+    template's default) takes 16 sublanes, 100 takes 104, and the tile
+    shrinks as the rank grows so that two buffers of it fit VMEM."""
+    d_pad, n_pad = _padded_shape(CELL_ITEMS, rank)
+    assert d_pad == -(-rank // 8) * 8
+    tile, _ = _tile_rows(8, d_pad, 16, n_pad)
+    assert tile == (16384 if d_pad <= 64 else 8192)
+    for b_pad, k_pad in ((8, 16), (128, 504)):
+        _compile_kernel(v5e[0], CELL_ITEMS, b_pad, k_pad, rank=rank)
 
 
 @pytest.mark.parametrize("n_dev", [1, 4])
