@@ -105,6 +105,19 @@ class Algorithm(abc.ABC, Generic[PD, M, Q, P]):
         where possible; the default maps ``predict``."""
         return [(i, self.predict(model, q)) for i, q in queries]
 
+    def cost_budget(self, model: M) -> int | None:
+        """The most one device step of ``batch_predict`` takes, in the
+        unit of ``query_cost``, where this model's queries cost unequally
+        (a sequence model: tokens); None where a row costs what every
+        other row costs. The serving micro-batcher cuts its batches by it
+        (workflow/microbatch.py)."""
+        return None
+
+    def query_cost(self, model: M, query: Q) -> int:
+        """What ``query`` adds to a device step, read only where
+        ``cost_budget`` states a budget."""
+        return 1
+
 
 class Serving(abc.ABC, Generic[Q, P]):
     """Combine per-algorithm predictions into the served result

@@ -12,8 +12,9 @@ score matrix never materializes on a single device.
 
 Layout: histories are LEFT-padded (pad id 0, real items 1..n_items) so the
 last position always holds the newest interaction; serving scores the last
-hidden state against the tied item-embedding table (one [D] x [D, NI]
-matmul + top-k on TPU).
+hidden state against the tied item-embedding table through the shared
+route of the sequence models (models/seq_serving.py: the serving
+pipeline's encoder seam and the retriever's fused top-k).
 """
 
 from __future__ import annotations
@@ -25,6 +26,7 @@ from typing import Any
 import numpy as np
 
 from ..storage.bimap import BiMap
+from .seq_serving import SequenceServingMixin
 
 __all__ = [
     "SeqRecConfig",
@@ -128,7 +130,7 @@ def _make_model(n_items: int, cfg: SeqRecConfig, mesh=None):
 
     class SeqRec(nn.Module):
         @nn.compact
-        def __call__(self, seqs):  # [B, L] int32
+        def __call__(self, seqs, hidden_only=False):  # [B, L] int32
             B, L = seqs.shape
             emb = nn.Embed(vocab, cfg.embed_dim,
                            embedding_init=nn.initializers.normal(0.02),
@@ -140,87 +142,68 @@ def _make_model(n_items: int, cfg: SeqRecConfig, mesh=None):
             for _ in range(cfg.num_blocks):
                 h = Block()(h)
             h = nn.LayerNorm()(h)
+            if hidden_only:  # serving: the retriever scores the table
+                return h
             # tied weights: logits against the embedding table
             return h @ emb.embedding.T  # [B, L, vocab]
 
     return SeqRec()
 
 
-@functools.lru_cache(maxsize=8)
-def _jitted_apply_last(n_items: int, cfg: SeqRecConfig):
-    """Serving forward returning ONLY the last position's logits
-    [B, vocab]: the [B, L, vocab] tensor never leaves the device (at a
-    50k-item catalog the full logits of one big eval batch are GBs)."""
-    import jax
+class SeqRecEncoder:
+    """The serving pipeline's encoder (ops/pipeline.py) for this model:
+    every history taken whole, left pads included, as the model was
+    trained (its positions are learned and counted from the right end),
+    so a row costs ``max_len`` tokens and a step's stream is
+    ``[rows, max_len]`` row by row."""
 
-    model = _make_model(n_items, cfg)
+    dense = True
+    aux_name = None
+    passes = 1
+    #: most rows one step takes
+    STEP_ROWS = 128
 
-    def last(params, seq_batch):
-        return model.apply(params, seq_batch)[:, -1, :]
+    def __init__(self, params, n_items: int, cfg: SeqRecConfig):
+        import jax
 
-    return jax.jit(last)
+        self.cfg = cfg
+        self.dim = cfg.embed_dim
+        self.max_len = cfg.max_len
+        self.budget = cfg.max_len * self.STEP_ROWS
+        self.lattice = tuple(cfg.max_len * b for b in (8, 16, 32, 64, 128))
+        self._model = _make_model(n_items, cfg)
+        self.params = jax.block_until_ready(jax.device_put(params))
+        self.param_bytes = int(sum(
+            x.nbytes for x in jax.tree_util.tree_leaves(self.params)))
+
+    def program(self, t_pad: int):
+        model, L = self._model, self.max_len
+
+        def fn(stream, params):
+            h = model.apply(params, stream[0].reshape(t_pad // L, L),
+                            hidden_only=True)
+            return h.reshape(t_pad, -1), None, None
+
+        return fn
 
 
 @dataclasses.dataclass
-class SeqRecModel:
+class SeqRecModel(SequenceServingMixin):
     params: Any
     seqs: np.ndarray  # [NU, L] training-time histories for serving
     user_ids: BiMap
     item_ids: BiMap
     config: SeqRecConfig
 
-    #: forward-pass cap for batched serving/eval: bounds the device
-    #: [chunk, L, d] activations and the [chunk, vocab] logits pull (the
-    #: eval path hands batch_predict a WHOLE fold in one call)
-    BATCH_CHUNK = 256
+    @property
+    def catalog(self) -> np.ndarray:
+        """The tied item-embedding table's item rows (row 0, the pad id,
+        left out): what the retriever scans."""
+        table = self.params["params"]["item_embed"]["embedding"]
+        return np.asarray(table, np.float32)[1:]
 
-    def recommend_products(
-        self, user_id: str, num: int, *, exclude_seen: bool = True
-    ) -> list[tuple[str, float]]:
-        return self.batch_recommend([user_id], [num],
-                                    exclude_seen=exclude_seen)[0]
-
-    def batch_recommend(
-        self, users: list, nums: list, *, exclude_seen: bool = True
-    ) -> list[list[tuple[str, float]]]:
-        """Per-user next-item top-N, one forward pass per <=BATCH_CHUNK
-        queries ([B, L] histories stacked, batch padded to a power of two
-        so traffic-dependent sizes reuse a handful of compiled shapes;
-        only the last position's [B, vocab] logits leave the device).
-        This is the serving path the micro-batcher
-        feeds, and the single home of the seen-mask/top-k dance
-        (``recommend_products`` delegates here). Unknown users get []."""
-        out: list = [[] for _ in users]
-        known = [(j, self.user_ids.get(u)) for j, u in enumerate(users)]
-        known = [(j, r) for j, r in known if r is not None]
-        if not known:
-            return out
-        apply_last = _jitted_apply_last(len(self.item_ids), self.config)
-        inv = self.item_ids.inverse
-        for start in range(0, len(known), self.BATCH_CHUNK):
-            part = known[start:start + self.BATCH_CHUNK]
-            rows = [r for _, r in part]
-            seqs = self.seqs[rows]  # [B, L]
-            b = len(rows)
-            b_pad = 8
-            while b_pad < b:
-                b_pad *= 2
-            fed = np.pad(seqs, ((0, b_pad - b), (0, 0))) if b_pad != b else seqs
-            logits = np.asarray(
-                apply_last(self.params, fed))[:b, 1:]  # [B, vocab-1], no pad id
-            for (j, _row), seq, row_scores in zip(part, seqs, logits):
-                scores = row_scores
-                if exclude_seen:
-                    seen = seq[seq > 0] - 1
-                    scores = scores.copy()
-                    scores[seen] = -np.inf
-                num = min(max(nums[j], 0), int(np.isfinite(scores).sum()))
-                if num <= 0:
-                    continue
-                top = np.argpartition(-scores, num - 1)[:num]
-                top = top[np.argsort(-scores[top])]
-                out[j] = [(inv[int(i)], float(scores[i])) for i in top]
-        return out
+    def make_encoder(self) -> SeqRecEncoder:
+        return SeqRecEncoder(self.params, len(self.item_ids), self.config)
 
 
 def train_seq_rec(

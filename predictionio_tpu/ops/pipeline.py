@@ -61,6 +61,36 @@ was is not measured on the chip.
 Deploy-time ``prewarm`` walks the full pad-bucketed (b_pad, k_pad)
 lattice and accounts every pinned buffer in the PR 12 device ledger
 (components ``pipeline_query_table`` / ``pipeline_staging``).
+
+* **Encoder seam** — a model whose query vector is COMPUTED (a sequence
+  model: rows -> histories[rows] -> encoder(params, .) -> score) hands
+  the pipeline an ``encoder``. The query table is then the int32 history
+  table, kept on the host (a step's tokens are a few KB); a step packs
+  its rows' histories into one token stream of a lattice length, the
+  encoder's program turns the stream into that step's query table on
+  the device, and the SAME fused program gathers the histories' last
+  positions from it and scores them. The lattice has a second
+  dimension, tokens: ``(t_pad)`` for the encoder's executables and
+  staging, ``(b_pad, k_pad)`` for the fused ones; the two are chained on
+  the device with no host sync between. A model with no encoder (ALS)
+  compiles and runs exactly the program it had. An encoder is any
+  object with
+
+  - ``dim``: width of the states it emits; ``max_len``; ``aux_name``
+    or None, and ``passes``, the most a position's aux can read;
+  - ``dense``: True takes every row whole, pads included, at a cost of
+    ``max_len`` tokens (the stream is then ``[rows, max_len]`` row by
+    row); False packs only the real events, a row costing its length;
+  - ``budget``: most tokens one step holds, and ``lattice``: the
+    ascending stream lengths a step is padded to, ending at the budget;
+  - ``params``: a pytree of device arrays, an argument of every call
+    (``param_bytes``, where it has one, is what the ledger accounts);
+  - ``program(t_pad)``: ``fn(stream int32[3, t_pad], params) ->
+    (states [t_pad, dim] float32, aux int32[t_pad] | None, passes int32
+    | None)``, the stream being tokens, segment ids (1.. per history, 0
+    for padding) and positions within the history; ``passes`` is what
+    the program itself counted of its loop's passes (``loopPasses`` adds
+    it up: no product of the configuration), None where it has no loop.
 """
 
 from __future__ import annotations
@@ -164,6 +194,45 @@ def _fused_fn(raw, packed: bool):
     return fn
 
 
+def _scoped(fn, scope: str):
+    """``fn`` under a ``jax.named_scope``, so that a capture opened in a
+    viewer groups its operations by that name."""
+
+    def scoped(*args):
+        import jax
+
+        with jax.named_scope(scope):
+            return fn(*args)
+
+    return scoped
+
+
+def _encoder_fn(program, cap: int, d_pad: int):
+    """stream, params -> (a step's query table [cap, d_pad], aux
+    int32[t_pad + 1]): the encoder's states laid into the zeroed table
+    the fused program gathers from (rows past the stream stay zero: the
+    sentinel), and its aux with the passes it counted behind it, one
+    array for the one pull; apart for tests/test_tpu_compile.py like
+    `_fused_fn`."""
+
+    def fn(stream, params):
+        import jax
+        import jax.numpy as jnp
+
+        states, aux, passes = program(stream, params)
+        table = jax.lax.dynamic_update_slice(
+            jnp.zeros((cap, d_pad), jnp.float32),
+            states.astype(jnp.float32), (0, 0))
+        if aux is None:
+            aux = jnp.zeros(stream.shape[1:], jnp.int32)
+        if passes is None:
+            passes = jnp.int32(0)
+        return table, jnp.concatenate(
+            [aux.astype(jnp.int32), passes.astype(jnp.int32).reshape(1)])
+
+    return fn
+
+
 class _SharedState:
     """Mutable pipeline state shared across copy-on-write ``refresh``
     clones: the staging pools, the overlap/dispatch counters, and the
@@ -173,6 +242,13 @@ class _SharedState:
     def __init__(self, clock=time.perf_counter):
         self.cond = threading.Condition()
         self.staging: dict[int, list[np.ndarray]] = {}
+        # encoder pipelines: token-stream buffers int32[3, t_pad] by
+        # t_pad, and what /stats.json's `sequence` block counts
+        self.streams: dict[int, list[np.ndarray]] = {}
+        self.seq = {"steps": 0, "rows": 0, "tokensReal": 0,
+                    "tokensComputed": 0, "attentionPairs": 0,
+                    "loopPasses": 0}
+        self.aux_hist: np.ndarray | None = None
         self.in_device = 0       # dispatches currently in their device step
         self.dispatches = 0
         self.overlapped = 0
@@ -209,17 +285,27 @@ class ServingPipeline:
     """
 
     def __init__(self, query_table: np.ndarray, retriever, *,
+                 encoder=None, ks: tuple[int, ...] = (10,),
                  _token: int | None = None, _capacity_rows: int | None = None):
         import jax
         import jax.numpy as jnp
 
         if retriever is None:
             raise ValueError("ServingPipeline requires an attached retriever")
+        self._retriever = retriever
+        self._fused = isinstance(retriever, DeviceRetriever)
+        self._encoder = encoder
+        #: the k's `prewarm` compiles for (a sequence model's lattice)
+        self.ks = tuple(ks)
+        self._token = _token if _token is not None else next(_RETRIEVER_TOKENS)
+        self._donate = jax.default_backend() in ("tpu", "gpu")
+        self._state = _SharedState()
+        if encoder is not None:
+            self._init_encoded(query_table)
+            return
         qt = np.asarray(query_table, np.float32)
         if qt.ndim != 2:
             raise ValueError("query table must be [rows, dim]")
-        self._retriever = retriever
-        self._fused = isinstance(retriever, DeviceRetriever)
         self.n_rows, self.dim = qt.shape
         self._cap = _capacity_rows or _capacity(self.n_rows)
         if self.n_rows + 1 > self._cap:
@@ -234,14 +320,41 @@ class ServingPipeline:
             ((self.dim + 127) // 128) * 128)
         if self._d_pad < self.dim:
             raise ValueError("retriever lane width narrower than factors")
-        self._token = _token if _token is not None else next(_RETRIEVER_TOKENS)
         self._sentinel = self._cap - 1  # permanently a zero row
         tab = np.zeros((self._cap, self._d_pad), np.float32)
         tab[: self.n_rows, : self.dim] = qt
         self._qtab = jax.device_put(jnp.asarray(tab))
-        self._donate = jax.default_backend() in ("tpu", "gpu")
-        self._state = _SharedState()
         LEDGER.track_buffer("pipeline_query_table", int(self._qtab.nbytes))
+
+    def _init_encoded(self, histories) -> None:
+        """The encoder's side of the constructor: the history table and
+        its lengths on the host, and the shape of the per-step query
+        table the encoder's program emits and the fused program reads."""
+        enc = self._encoder
+        if not self._fused:
+            raise ValueError(
+                "an encoder serves through the exact single-device "
+                "retriever (the fused program gathers from the step's "
+                "table); ANN and sharded retrievers take no encoder")
+        self._set_histories(histories)
+        self.dim = int(enc.dim)
+        self._d_pad = int(self._retriever.lane_dim)
+        if self._d_pad < self.dim:
+            raise ValueError("retriever lane width narrower than the "
+                             "encoder's states")
+        # the step's table: the budget's positions, then zero rows
+        self._cap = int(enc.budget) + 8
+        self._sentinel = self._cap - 1
+        self._qtab = None
+        self._state.aux_hist = np.zeros(int(enc.passes) + 1, np.int64)
+
+    def _set_histories(self, histories) -> None:
+        hist = np.ascontiguousarray(histories, np.int32)
+        if hist.ndim != 2 or hist.shape[1] != self._encoder.max_len:
+            raise ValueError("history table must be [rows, max_len]")
+        self._hist = hist
+        self.n_rows = hist.shape[0]
+        self._lengths = (hist > 0).sum(axis=1).astype(np.int32)
 
     # -- compiled programs --------------------------------------------
 
@@ -266,6 +379,8 @@ class ServingPipeline:
                                 r._mode == "interpret")
             packed = n_total < PACKED_IDX_LIMIT
             fn = _fused_fn(raw, packed)
+            if self._encoder is not None:
+                fn = _scoped(fn, "pio.seq.head_topk")
             jitted = (jax.jit(fn, donate_argnums=(0,)) if self._donate
                       else jax.jit(fn))
             compiled = jitted.lower(
@@ -274,6 +389,30 @@ class ServingPipeline:
                 jax.ShapeDtypeStruct(r._items.shape, jnp.float32),
             ).compile()
             return compiled, packed
+
+        out = EXEC_CACHE.get_or_build(key, build)
+        if pin:
+            EXEC_CACHE.pin(key)
+        return out
+
+    def _exec_encoder(self, t_pad: int, *, pin: bool = False):
+        """Compiled stream -> (the step's query table, aux) for one
+        lattice point of the token dimension."""
+        enc = self._encoder
+        key = ("pipeline", self._token, "encoder", t_pad, self._cap,
+               self._d_pad)
+
+        def build():
+            import jax
+            import jax.numpy as jnp
+
+            fn = _encoder_fn(enc.program(t_pad), self._cap, self._d_pad)
+            return jax.jit(fn).lower(
+                jax.ShapeDtypeStruct((3, t_pad), jnp.int32),
+                jax.tree_util.tree_map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                    enc.params),
+            ).compile()
 
         out = EXEC_CACHE.get_or_build(key, build)
         if pin:
@@ -307,18 +446,23 @@ class ServingPipeline:
 
     # -- staging double buffer ----------------------------------------
 
-    def _acquire_staging(self, b_pad: int) -> tuple[np.ndarray, bool]:
+    def _acquire_staging(self, b_pad: int, *, stream: bool = False
+                         ) -> tuple[np.ndarray, bool]:
         """A staging buffer for one dispatch: a pinned one when the pool
         has a free slot (waiting at most STAGING_WAIT_S for the double
         buffer to swap), else a transient allocation — slow, but a hung
-        handoff can never wedge the pool. Returns (buffer, transient)."""
+        handoff can never wedge the pool. Returns (buffer, transient).
+        ``stream``: an encoder's token stream int32[3, b_pad] (b_pad
+        then counts tokens), from its own pools."""
         st = self._state
+        shape = (3, b_pad) if stream else (b_pad,)
         t0 = time.perf_counter()
         with st.cond:
-            pool = st.staging.get(b_pad)
+            pools = st.streams if stream else st.staging
+            pool = pools.get(b_pad)
             if pool is None:
-                pool = st.staging[b_pad] = [
-                    np.empty(b_pad, np.int32) for _ in range(STAGING_DEPTH)]
+                pool = pools[b_pad] = [
+                    np.empty(shape, np.int32) for _ in range(STAGING_DEPTH)]
             if not pool:
                 st.cond.wait(timeout=STAGING_WAIT_S)
             buf = pool.pop() if pool else None
@@ -326,16 +470,17 @@ class ServingPipeline:
         if buf is None:
             with st.cond:
                 st.transient += 1
-            return np.empty(b_pad, np.int32), True
+            return np.empty(shape, np.int32), True
         return buf, False
 
     def _release_staging(self, b_pad: int, buf: np.ndarray,
-                         transient: bool) -> None:
+                         transient: bool, *, stream: bool = False) -> None:
         if transient:
             return
         st = self._state
         with st.cond:
-            st.staging.setdefault(b_pad, []).append(buf)
+            pools = st.streams if stream else st.staging
+            pools.setdefault(b_pad, []).append(buf)
             st.cond.notify()
 
     def _fill_staging(self, buf: np.ndarray, rows: np.ndarray) -> None:
@@ -360,6 +505,8 @@ class ServingPipeline:
         k_eff = min(k, n_total)
         if b == 0 or k_eff <= 0 or n_total == 0:
             return (np.zeros((b, 0), np.float32), np.zeros((b, 0), np.int32))
+        if self._encoder is not None:
+            return self._topk_encoded(rows, k_eff)
         b_pad, k_pad = _query_shapes(b, k_eff, n_total)
         LEDGER.record_padding_waste(b, b_pad)
         st = self._state
@@ -388,6 +535,146 @@ class ServingPipeline:
         finally:
             if buf is not None:
                 self._release_staging(b_pad, buf, transient)
+
+    # -- the encoder's hot path ---------------------------------------
+
+    @property
+    def cost_budget(self) -> int | None:
+        """Most cost (tokens) one device step takes; None where every row
+        costs the same (no encoder)."""
+        return None if self._encoder is None else int(self._encoder.budget)
+
+    def history_lengths(self, rows) -> np.ndarray:
+        return self._lengths[np.asarray(rows, np.int64)]
+
+    def _row_costs(self, rows: np.ndarray) -> np.ndarray:
+        """Tokens each row adds to a step: its history's length where the
+        encoder packs, ``max_len`` where it takes rows whole."""
+        if self._encoder.dense:
+            return np.full(len(rows), self._encoder.max_len, np.int64)
+        return self._lengths[rows].astype(np.int64)
+
+    def row_cost(self, row: int) -> int:
+        return int(self._row_costs(np.asarray([row]))[0])
+
+    def _topk_encoded(self, rows: np.ndarray, k_eff: int):
+        """Rows -> steps of at most the encoder's budget (a caller that
+        cuts by cost, the micro-batcher, gets one; an evaluation fold gets
+        as many as it needs), in order. Only the last step's end is
+        reported: the batch holds its place ahead of the device until
+        then."""
+        enc = self._encoder
+        costs = self._row_costs(rows)
+        bounds, spent = [0], 0
+        for j, c in enumerate(costs.tolist()):
+            if spent + c > enc.budget and j > bounds[-1]:
+                bounds.append(j)
+                spent = 0
+            spent += c
+        bounds.append(len(rows))
+        hook = set_step_end_hook(None) if len(bounds) > 2 else None
+        parts = []
+        try:
+            for lo, hi in zip(bounds[:-1], bounds[1:]):
+                if hook is not None and hi == len(rows):
+                    reset_step_end_hook(hook)
+                    hook = None
+                parts.append(self._encoded_step(rows[lo:hi], costs[lo:hi],
+                                                k_eff))
+        finally:
+            if hook is not None:
+                reset_step_end_hook(hook)
+        if len(parts) == 1:
+            return parts[0]
+        return (np.concatenate([p[0] for p in parts]),
+                np.concatenate([p[1] for p in parts]))
+
+    def _encoded_step(self, rows: np.ndarray, costs: np.ndarray, k_eff: int):
+        """One device step: the rows' histories packed into a stream of a
+        lattice length -> the encoder's program -> the fused program over
+        the states of the histories' last positions."""
+        import jax
+
+        enc, st = self._encoder, self._state
+        b, tokens = len(rows), int(costs.sum())
+        t_pad = next((t for t in enc.lattice if t >= tokens), None)
+        if t_pad is None:
+            raise ValueError(f"one row of {tokens} tokens is over the "
+                             f"step's budget of {enc.budget}")
+        b_pad, k_pad = _query_shapes(b, k_eff, self._retriever.n_total)
+        LEDGER.record_padding_waste(tokens, t_pad)
+        facts = {"rows": b, "b_pad": b_pad, "t_pad": t_pad}
+        stream = last = None
+        s_transient = l_transient = True
+        in_device = False
+        try:
+            with stage_span("host_assembly", **facts):
+                with span("serve.seq_encode", level=logging.DEBUG,
+                          tokens=tokens, **facts):
+                    stream, s_transient = self._acquire_staging(
+                        t_pad, stream=True)
+                    last, l_transient = self._acquire_staging(b_pad)
+                    with st.cond:
+                        overlapped = st.in_device > 0
+                    starts = np.cumsum(costs) - costs
+                    L = self._hist.shape[1]
+                    stream[:, tokens:] = 0
+                    stream[0, :tokens] = np.concatenate(
+                        [self._hist[r, L - c:] for r, c
+                         in zip(rows.tolist(), costs.tolist())])
+                    stream[1, :tokens] = np.repeat(
+                        np.arange(1, b + 1, dtype=np.int32), costs)
+                    stream[2, :tokens] = (np.arange(tokens)
+                                          - np.repeat(starts, costs))
+                    last[:b] = starts + costs - 1
+                    last[b:] = self._sentinel
+                FAULTS.fire("pipeline.swap")
+            try:
+                with stage_span("device_dispatch", **facts):
+                    encode = self._exec_encoder(t_pad)
+                    score, is_packed = self._exec_fused(b_pad, k_pad)
+                    with st.cond:
+                        st.advance(+1)
+                    in_device = True
+                    table, aux = encode(stream, enc.params)
+                    out = score(last, table, self._retriever._items)
+                    if self._donate:
+                        _M_DONATED.inc()
+                with stage_span("device_compute", **facts):
+                    jax.block_until_ready(out)
+            finally:
+                if in_device:
+                    with st.cond:
+                        st.advance(-1)
+                    device_step_ended()
+            with stage_span("result_scatter", **facts):
+                vals, idx, counts = _unpack(out, is_packed, b, k_eff, k_pad)
+                aux = np.asarray(aux)
+                exits = aux[last[:b]] if enc.aux_name else None
+            if counts is not None:
+                self._retriever.record_scan(counts)
+            real = self._lengths[rows].astype(np.int64)
+            with st.cond:
+                st.dispatches += 1
+                st.overlapped += 1 if overlapped else 0
+                seq = st.seq
+                seq["steps"] += 1
+                seq["rows"] += b
+                seq["tokensReal"] += int(real.sum())
+                seq["tokensComputed"] += t_pad
+                seq["attentionPairs"] += int((real * (real + 1) // 2).sum())
+                seq["loopPasses"] += int(aux[-1])
+                if exits is not None:
+                    st.aux_hist += np.bincount(
+                        exits, minlength=len(st.aux_hist))[:len(st.aux_hist)]
+                ratio = st.overlapped / st.dispatches
+            _M_OVERLAP.set(ratio)
+            return vals, idx
+        finally:
+            if stream is not None:
+                self._release_staging(t_pad, stream, s_transient, stream=True)
+            if last is not None:
+                self._release_staging(b_pad, last, l_transient)
 
     def _dispatch_fused(self, buf, b, k_eff, k_pad, facts):
         import jax
@@ -446,7 +733,7 @@ class ServingPipeline:
 
     # -- lifecycle -----------------------------------------------------
 
-    def prewarm(self, batch_sizes=(1,), ks=(10,)) -> list[tuple]:
+    def prewarm(self, batch_sizes=(1,), ks=None) -> list[tuple]:
         """AOT-build and PIN this pipeline's executables for the full
         pad-bucketed lattice, allocate the pinned staging pairs, and
         account every pinned buffer in the device ledger. Returns the
@@ -455,6 +742,19 @@ class ServingPipeline:
         seen: set[tuple[int, int]] = set()
         gathered: set[int] = set()
         n_total = self._retriever.n_total
+        ks = self.ks if ks is None else ks
+        if self._encoder is not None:
+            # the token dimension of the lattice; the fused programs
+            # follow at the model's own k's
+            for t_pad in self._encoder.lattice:
+                with span("deploy.prewarm.program", sink=STARTUP.phase,
+                          kind="encoder", t_pad=t_pad):
+                    self._exec_encoder(t_pad, pin=True)
+                warmed.append(("pipeline", "encoder", t_pad))
+                with self._state.cond:
+                    self._state.streams.setdefault(t_pad, [
+                        np.empty((3, t_pad), np.int32)
+                        for _ in range(STAGING_DEPTH)])
         for b in batch_sizes:
             for k in ks:
                 k_eff = min(k, n_total)
@@ -487,8 +787,17 @@ class ServingPipeline:
         with self._state.cond:
             staged = sum(STAGING_DEPTH * b_pad * 4
                          for b_pad in self._state.staging)
+            staged += sum(STAGING_DEPTH * 3 * t_pad * 4
+                          for t_pad in self._state.streams)
         LEDGER.track_buffer("pipeline_staging", staged)
-        LEDGER.track_buffer("pipeline_query_table", int(self._qtab.nbytes))
+        if self._encoder is None:
+            LEDGER.track_buffer("pipeline_query_table",
+                                int(self._qtab.nbytes))
+        else:  # a step's table, alive while the step is
+            LEDGER.track_buffer("pipeline_query_table",
+                                self._cap * self._d_pad * 4)
+            LEDGER.track_buffer("pipeline_encoder_params", int(
+                getattr(self._encoder, "param_bytes", 0)))
 
     def refresh(self, query_table: np.ndarray) -> "ServingPipeline":
         """Copy-on-write table swap for a delta epoch bump: re-upload
@@ -501,6 +810,12 @@ class ServingPipeline:
         import jax
         import jax.numpy as jnp
 
+        if self._encoder is not None:
+            # the histories live on the host: a new table, same programs
+            new = object.__new__(ServingPipeline)
+            new.__dict__.update(self.__dict__)
+            new._set_histories(query_table)
+            return new
         qt = np.asarray(query_table, np.float32)
         if qt.ndim != 2 or qt.shape[1] != self.dim:
             raise ValueError("refresh requires a [rows, %d] table" % self.dim)
@@ -522,7 +837,20 @@ class ServingPipeline:
         with st.cond:
             staged = {int(b): len(p) for b, p in st.staging.items()}
             now = st.advance()
+            sequence = {}
+            if self._encoder is not None:
+                seq = st.seq
+                sequence = {"sequence": {
+                    **seq,
+                    "rowsPerStep": (seq["rows"] / seq["steps"]
+                                    if seq["steps"] else 0.0),
+                    "tokenBudget": int(self._encoder.budget),
+                    "tokenLattice": list(self._encoder.lattice),
+                    **({self._encoder.aux_name: st.aux_hist.tolist()}
+                       if self._encoder.aux_name else {}),
+                }}
             return {
+                **sequence,
                 "mode": "fused" if self._fused else "gather",
                 "rows": self.n_rows,
                 "capacity": self._cap,

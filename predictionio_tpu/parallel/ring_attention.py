@@ -29,6 +29,7 @@ __all__ = [
     "blockwise_attention",
     "flash_attention",
     "ring_attention",
+    "segment_attention",
     "ring_self_attention",
     "ulysses_attention",
 ]
@@ -182,32 +183,81 @@ def blockwise_attention(q, k, v, *, causal: bool = False, block_size: int = 1024
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
 
 
-def flash_attention(q, k, v, *, causal: bool = False, block_size: int = 1024):
+def segment_attention(q, k, v, segment_ids, *, causal: bool = False):
+    """Plain XLA attention for [B, L, H, D] in which a position sees only
+    positions of its own segment (``segment_ids`` int [B, L]): the
+    [L, L] scores are materialized, so this is for the sizes that run
+    off the TPU (the flash kernel takes the same ids there)."""
+    import jax
+    import jax.numpy as jnp
+
+    B, L, H, D = q.shape
+    f32 = jnp.float32
+    mm_dtype = q.dtype if q.dtype == jnp.bfloat16 else f32
+    prec = None if mm_dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+    s = jnp.einsum("blhd,bshd->bhls", q.astype(mm_dtype), k.astype(mm_dtype),
+                   preferred_element_type=f32, precision=prec) / (D ** 0.5)
+    mask = segment_ids[:, :, None] == segment_ids[:, None, :]
+    if causal:
+        pos = jnp.arange(L)
+        mask = mask & (pos[None, :] <= pos[:, None])[None]
+    s = jnp.where(mask[:, None], s, _NEG)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhls,bshd->blhd", p.astype(mm_dtype),
+                     v.astype(mm_dtype), preferred_element_type=f32,
+                     precision=prec)
+    return out.astype(q.dtype)
+
+
+def flash_attention(q, k, v, *, causal: bool = False, block_size: int = 1024,
+                    segment_ids=None):
     """Best-available single-device attention for [B, L, H, D]: the stock
     Pallas TPU flash kernel (jax.experimental.pallas.ops.tpu) when on TPU
     and the shape fits its tiling, else ``blockwise_attention``. The
     Pallas kernel fuses the whole softmax-accumulate into one Mosaic
     program (against blockwise: not measured on the chip); NOTE its
     ``sm_scale`` defaults to 1.0, so the 1/sqrt(D) scale must be passed
-    explicitly."""
+    explicitly. ``segment_ids`` (int [B, L]) keeps attention inside a
+    segment: histories packed one after another in a row; off the TPU
+    that is ``segment_attention``."""
     import jax
 
     B, L, H, D = q.shape
     if jax.default_backend() == "tpu" and L % 128 == 0 and D in (64, 128):
-        # the shape test above decides which kernel runs; a compile or
-        # run-time error of the chosen kernel is an error, not a reason
-        # to change kernels behind the caller's back
-        import jax.numpy as jnp
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            flash_attention as _pallas_flash)
-
-        qt = jnp.transpose(q, (0, 2, 1, 3))
-        kt = jnp.transpose(k, (0, 2, 1, 3))
-        vt = jnp.transpose(v, (0, 2, 1, 3))
-        out = _pallas_flash(qt, kt, vt, causal=causal,
-                            sm_scale=1.0 / (D**0.5))
-        return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
+        return _stock_flash(q, k, v, causal, segment_ids)
+    if segment_ids is not None:
+        return segment_attention(q, k, v, segment_ids, causal=causal)
     return blockwise_attention(q, k, v, causal=causal, block_size=block_size)
+
+
+#: Rows and columns of one grid step of the stock kernel where the stream
+#: is long enough (its default, 128, makes 256 steps a head at 2,048).
+_FLASH_BLOCK = 512
+
+
+def _stock_flash(q, k, v, causal: bool, segment_ids=None):
+    """The stock Pallas kernel on [B, L, H, D], whatever the backend (so
+    that tests/test_tpu_compile.py compiles what a TPU runs): the shape
+    test in ``flash_attention`` decides which kernel runs; a compile or
+    run-time error of the chosen kernel is an error, not a reason to
+    change kernels behind the caller's back."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu.flash_attention import (
+        BlockSizes, SegmentIds, flash_attention as _pallas_flash)
+
+    L, D = q.shape[1], q.shape[3]
+    sizes = None
+    if L % _FLASH_BLOCK == 0:
+        sizes = BlockSizes(block_q=_FLASH_BLOCK, block_k_major=_FLASH_BLOCK,
+                           block_k=_FLASH_BLOCK, block_b=1)
+    seg = (None if segment_ids is None
+           else SegmentIds(q=segment_ids, kv=segment_ids))
+    qt = jnp.transpose(q, (0, 2, 1, 3))
+    kt = jnp.transpose(k, (0, 2, 1, 3))
+    vt = jnp.transpose(v, (0, 2, 1, 3))
+    out = _pallas_flash(qt, kt, vt, segment_ids=seg, causal=causal,
+                        sm_scale=1.0 / (D**0.5), block_sizes=sizes)
+    return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
 
 
 def ring_self_attention(mesh, q, k, v, *, causal: bool = False,

@@ -458,6 +458,7 @@ class EngineServer:
                 # stretch toward a full batch (workflow/microbatch.py)
                 dispatch_timeout_s=self.dispatch_timeout_s,
                 on_watchdog=self._on_watchdog_trip,
+                costing=self._costing,
             )
         # adaptive admission (ISSUE 6): shed 429 + Retry-After at ingress
         # off live batcher/registry signals, before work can blow its
@@ -893,6 +894,31 @@ class EngineServer:
         qcls = getattr(algo, "query_class", None)
         return parse_params(qcls, query_json) if qcls is not None else query_json
 
+    def _costing(self):
+        """What the micro-batcher asks at every cut (a /reload may swap
+        the model): ``(cost_of(query_json), budget)`` where an algorithm
+        of the CURRENT bundle states that its queries cost unequally
+        (``Algorithm.cost_budget``), else None. The cost is the
+        algorithm's own, of the parsed query; a query that does not
+        parse costs nothing here and fails alone in
+        ``serve_query_batch``."""
+        result = self.deployed.result
+        for algo, model in zip(result.algorithms, result.models):
+            stated = getattr(algo, "cost_budget", None)
+            budget = stated(model) if stated is not None else None
+            if budget is None:
+                continue
+
+            def cost_of(query_json, algo=algo, model=model) -> int:
+                try:
+                    return int(algo.query_cost(
+                        model, self._decode(algo, query_json)))
+                except Exception:  # noqa: BLE001 - per-query isolation
+                    return 0
+
+            return cost_of, budget
+        return None
+
     def serve_query(self, query_json: dict) -> dict:
         """Single-query path (batching disabled)."""
         tag, payload = self.serve_query_batch([query_json])[0]
@@ -1325,6 +1351,10 @@ class EngineServer:
         # ISSUE 14: traffic split + per-variant slices. On a child
         # server this is its own one-entry table; on the primary it is
         # the process router the /variants endpoints mutate.
+        pipeline_block = self._pipeline_stats(bundle)
+        # a sequence model's step counters (tokens real and computed,
+        # steps, loop passes): their own block, beside `pipeline`
+        sequence_block = pipeline_block.pop("sequence", None)
         variants_block = self.variants.snapshot()
         if variants_block["count"] > 1:
             variants_block["byVariant"] = {
@@ -1357,7 +1387,8 @@ class EngineServer:
             "retrieval": self._retrieval_stats(bundle),
             # ISSUE 16: device-resident dispatch posture (overlap ratio,
             # staging pool, capacity); empty where no model has a pipeline
-            "pipeline": self._pipeline_stats(bundle),
+            "pipeline": pipeline_block,
+            **({"sequence": sequence_block} if sequence_block else {}),
             "admission": (self.admission.stats()
                           if self.admission is not None else None),
             "resilience": {

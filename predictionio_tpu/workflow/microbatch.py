@@ -53,6 +53,16 @@ The batch function contract: ``batch_fn(list[query]) -> list[("ok",
 result) | ("err", exception)]``, run in a worker thread; it must be
 thread-safe up to ``max_inflight`` concurrent calls (the engine-server
 batch path is: stats under a lock, deployed bundle read via snapshot).
+
+Rows of unequal cost: ``costing()`` is asked at every cut and answers
+``(cost_of, budget)``, what a query costs (a sequence model's history
+length in tokens) and what one device step takes, or None. A cut then
+takes queries in arrival order up to that budget (and ``max_batch``), at
+least one; the cost is read over the queries the cut looks at, never per
+arrival. No ``costing``, or an answer of None: the cut is by
+``max_batch`` alone, as before. The sojourn estimate, the drain rate and the adaptive window
+still count ``max_batch`` rows a batch: under a cut by cost they read
+low (they promise more than is served, so they shed late, not early).
 """
 
 from __future__ import annotations
@@ -142,9 +152,15 @@ class MicroBatcher:
         adaptive: bool = False,
         dispatch_timeout_s: float | None = None,
         on_watchdog: Callable[[], None] | None = None,
+        costing: Callable[
+            [], tuple[Callable[[Any], int], int] | None] | None = None,
     ):
         self.batch_fn = batch_fn
         self.max_batch = max(1, max_batch)
+        #: asked at every cut (a /reload may swap the model behind it):
+        #: (what one query costs a device step, the most a step takes),
+        #: or None: the cut is by max_batch alone
+        self.costing = costing
         self.window_s = max(0.0, window_s)
         self.max_pending = max(1, max_pending)
         self.max_inflight = max(1, max_inflight)
@@ -273,6 +289,24 @@ class MicroBatcher:
                 self._gate.set()
         self._open = is_open
 
+    def _take(self) -> list[tuple]:
+        """Cut: the queued queries, in arrival order, that one device
+        step takes. Up to ``max_batch`` rows; where rows have a cost, up
+        to the budget too, and never fewer than one."""
+        n = min(len(self._pending), self.max_batch)
+        stated = self.costing() if self.costing is not None else None
+        if stated is not None:
+            cost_of, budget = stated
+            spent = 0
+            for j in range(n):
+                spent += cost_of(self._pending[j][0])
+                if spent > budget and j > 0:
+                    n = j
+                    break
+        batch = self._pending[:n]
+        del self._pending[:n]
+        return batch
+
     def _estimate_sojourn_s(self) -> float:
         """Expected queue wait for a query enqueued now: the number of
         pipeline waves the queued-ahead batches need, times the EWMA
@@ -372,10 +406,8 @@ class MicroBatcher:
                 # of the drain is answering admitted requests, fast
                 self._sweep_expired(time.monotonic())
                 while self._pending:
-                    batch = self._pending[: self.max_batch]
-                    del self._pending[: len(batch)]
                     await self._admit()
-                    self._launch(batch)
+                    self._launch(self._take())
             # let dispatched batches finish — their queries already left
             # the queue and their callers are awaiting results; to_thread
             # work cannot be interrupted anyway
@@ -437,8 +469,7 @@ class MicroBatcher:
             # stripping the queue into waiting tasks
             await self._admit()
             self._sweep_expired(time.monotonic())  # the wait takes time
-            batch = self._pending[: self.max_batch]
-            del self._pending[: len(batch)]
+            batch = self._take()
             if not self._pending:
                 self._wake.clear()
             if batch:

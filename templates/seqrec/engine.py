@@ -9,12 +9,36 @@ events become per-user time-ordered item histories; a causal-attention
 model (models/seq_attention.py) predicts the next item; histories longer
 than one chip shard over a ``seq`` mesh axis via ring attention.
 
+Two algorithms, one serving route (models/seq_serving.py: `pio deploy` ->
+micro-batcher -> serving pipeline -> the retriever's fused top-k):
+
+- ``seqrec``: the SASRec-style model above, learned positions, histories
+  taken whole;
+- ``looped``: a looped decoder (models/looped_lm.py, LoopLM / Ouro):
+  ``num_hidden_layers`` layers whose weights are shared by
+  ``total_ut_steps`` passes, RoPE, sandwich RMSNorms, SwiGLU, an exit
+  gate, an untied head. Its params are the published ``config.json``'s
+  keys, so the ``engine.json`` is the config a user copies:
+
+      "algorithms": [{"name": "looped", "params": {
+          "hidden_size": 2048, "intermediate_size": 5632,
+          "num_hidden_layers": 48, "num_attention_heads": 16,
+          "head_dim": 128, "total_ut_steps": 4,
+          "early_exit_threshold": 1, "rms_norm_eps": 1e-06,
+          "rope_theta": 1000000, "max_len": 512,
+          "epochs": 10, "batch_size": 64, "lr": 0.001, "seed": 0}}]
+
+  (the item table stands in for ``vocab_size``; ``max_len`` is the most
+  events of a history that are kept; training at those widths does not
+  fit one chip, serving does: PERF.md).
+
 Query:  {"user": "u1", "num": 4}
 Result: {"itemScores": [{"item": "i1", "score": 3.2}, ...]}
 """
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 
 import numpy as np
@@ -27,6 +51,11 @@ from predictionio_tpu.controller import (
     Params,
     Preparator,
     SanityCheck,
+)
+from predictionio_tpu.models.looped_lm import (
+    LoopedLMConfig,
+    LoopedLMModel,
+    train_looped_lm,
 )
 from predictionio_tpu.models.seq_attention import (
     SeqRecConfig,
@@ -52,6 +81,26 @@ class AlgorithmParams(Params):
     batch_size: int = 256
     lr: float = 1e-3
     seq_parallel: bool = False
+    seed: int = 0
+
+
+@dataclass(frozen=True)
+class LoopedParams(Params):
+    """The published config's keys, then what training takes."""
+    hidden_size: int = 2048
+    intermediate_size: int = 5632
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 16
+    head_dim: int = 128
+    total_ut_steps: int = 4
+    early_exit_threshold: float = 1.0
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1_000_000.0
+    max_len: int = 512
+    compute_dtype: str = "bfloat16"
+    epochs: int = 10
+    batch_size: int = 64
+    lr: float = 1e-3
     seed: int = 0
 
 
@@ -122,16 +171,16 @@ class SeqRecAlgorithm(Algorithm):
         )
         return train_seq_rec(seqs, uids, iids, cfg, mesh=ctx.mesh)
 
-    def predict(self, model: SeqRecModel, query: Query) -> PredictedResult:
+    def predict(self, model, query: Query) -> PredictedResult:
         recs = model.recommend_products(query.user, query.num)
         return PredictedResult(
             itemScores=tuple(ItemScore(item=i, score=s) for i, s in recs)
         )
 
-    def batch_predict(self, model: SeqRecModel, queries) -> list:
-        """One forward pass for the whole micro-batch (the dispatcher in
+    def batch_predict(self, model, queries) -> list:
+        """One device step for the whole micro-batch (the dispatcher in
         workflow/microbatch.py feeds this; per-query predict pays one
-        device dispatch per request instead)."""
+        step per request instead)."""
         recs = model.batch_recommend([q.user for _, q in queries],
                                      [q.num for _, q in queries])
         return [
@@ -140,11 +189,32 @@ class SeqRecAlgorithm(Algorithm):
             for (i, _q), rec in zip(queries, recs)
         ]
 
+    def cost_budget(self, model) -> int:
+        """Tokens a serving step holds: the micro-batcher's cut."""
+        return model.serving_cost_budget
+
+    def query_cost(self, model, query: Query) -> int:
+        return model.serving_cost(query.user)
+
+
+class LoopedAlgorithm(SeqRecAlgorithm):
+    """The looped decoder behind the same queries and the same route."""
+
+    params_class = LoopedParams
+
+    def train(self, ctx, td: TrainingData) -> LoopedLMModel:
+        cfg = LoopedLMConfig(**dataclasses.asdict(self.params))
+        seqs, uids, iids = build_sequences(
+            td.users, td.items, td.times, max_len=cfg.max_len
+        )
+        return train_looped_lm(seqs, uids, iids, cfg, mesh=ctx.mesh)
+
 
 def engine_factory() -> Engine:
     return Engine(
         data_source_classes=SeqDataSource,
         preparator_classes=SeqPreparator,
-        algorithm_classes={"seqrec": SeqRecAlgorithm},
+        algorithm_classes={"seqrec": SeqRecAlgorithm,
+                           "looped": LoopedAlgorithm},
         serving_classes=FirstServing,
     )

@@ -995,3 +995,84 @@ def test_only_a_batchs_last_device_step_opens_the_gate():
         reset_step_end_hook(token)
     assert [tag for tag, _ in out] == ["ok", "ok"]
     assert order == [1, 2, "gate"]
+
+
+class TestCutByCost:
+    """Rows of unequal cost (a sequence model's tokens): a cut takes
+    queries in arrival order up to the budget the caller states."""
+
+    @staticmethod
+    def _serve(costs, budget, max_batch=128, **kw):
+        cuts = []
+
+        def batch_fn(queries):
+            cuts.append(list(queries))
+            return [("ok", q) for q in queries]
+
+        async def go():
+            mb = MicroBatcher(batch_fn, max_batch=max_batch, window_s=0.02,
+                              **kw)
+            try:
+                got = await asyncio.gather(*[mb.submit(c) for c in costs])
+            finally:
+                await mb.close()
+            return got, mb
+
+        got, mb = run(go())
+        assert got == list(costs)
+        return cuts, mb
+
+    @pytest.mark.parametrize("budget", [64, 100, 512])
+    def test_a_cut_by_cost_never_exceeds_its_budget(self, budget):
+        rng = np.random.default_rng(budget)
+        costs = rng.integers(1, 60, 200).tolist()
+        cuts, mb = self._serve(costs, budget,
+                               costing=lambda: (lambda q: q, budget))
+        assert [q for cut in cuts for q in cut] == costs  # arrival order
+        assert all(sum(cut) <= budget for cut in cuts)
+        # and takes all the budget allows: the next query did not fit
+        for cut, nxt in zip(cuts, cuts[1:]):
+            assert sum(cut) + nxt[0] > budget or len(cut) == mb.max_batch
+        # the estimators still count max_batch rows a batch
+        assert mb.drain_rate_per_s() == (
+            mb.max_batch * mb._depth() / mb._ewma_dispatch_s)
+
+    def test_a_row_over_the_budget_goes_alone(self):
+        cuts, _mb = self._serve([300, 5, 5], 100,
+                                costing=lambda: (lambda q: q, 100))
+        assert cuts[0] == [300] and sum(map(len, cuts)) == 3
+
+    @pytest.mark.parametrize("kw", [
+        {}, {"costing": lambda: None},            # the model states none
+        {"costing": lambda: (lambda q: 0, 5)}])   # rows that cost nothing
+    def test_no_stated_cost_cuts_as_before(self, kw):
+        cuts, mb = self._serve(list(range(1, 41)), None, max_batch=16, **kw)
+        assert [len(c) for c in cuts][:2] == [16, 16]
+
+    def test_the_budget_is_asked_at_every_cut(self):
+        """A /reload from a model that states no cost to one that does
+        (and back) is followed at the next cut: nothing is read once at
+        construction."""
+        stated = {"now": None}
+        cuts = []
+
+        def batch_fn(queries):
+            cuts.append(list(queries))
+            return [("ok", q) for q in queries]
+
+        async def go():
+            mb = MicroBatcher(batch_fn, max_batch=16, window_s=0.01,
+                              costing=lambda: stated["now"])
+            try:
+                await asyncio.gather(*[mb.submit(5) for _ in range(8)])
+                stated["now"] = (lambda q: q, 10)
+                await asyncio.gather(*[mb.submit(5) for _ in range(8)])
+                stated["now"] = None
+                await asyncio.gather(*[mb.submit(5) for _ in range(8)])
+            finally:
+                await mb.close()
+
+        run(go())
+        sizes = [len(c) for c in cuts]
+        assert sizes[0] == 8 and sizes[-1] == 8
+        assert sizes[1:-1] == [2, 2, 2, 2]

@@ -171,6 +171,58 @@ def test_seq_rec_learns_cycle():
     recs = model.recommend_products("u0", 2, exclude_seen=False)
     assert recs, "no recommendations"
     assert recs[0][0] == "i0"
+    # through the sequence models' one route: the serving pipeline's
+    # encoder seam and the retriever's top-k, no forward of its own
+    stats = model._pipeline.stats()
+    assert stats["mode"] == "fused" and model._retriever.kernel == "xla"
+    assert stats["sequence"]["steps"] == 1
+    assert stats["sequence"]["tokensComputed"] == 8 * cfg.max_len
+
+
+def test_seq_rec_serves_a_fold_in_steps_of_whole_rows():
+    """What `BATCH_CHUNK` did, on the shared route: a caller that cuts
+    nothing (an evaluation fold of 300 users) is served in steps of at
+    most `SeqRecEncoder.STEP_ROWS` whole left-padded rows, on the
+    lattice of 8 to 128 rows, with the answers a lone query gets; an
+    unknown user and a user without events get []."""
+    from predictionio_tpu.models.seq_attention import (SeqRecEncoder,
+                                                       SeqRecModel,
+                                                       _make_model)
+    from predictionio_tpu.storage.bimap import BiMap
+
+    cfg = SeqRecConfig(max_len=8, embed_dim=16, num_heads=2, num_blocks=1)
+    n_items, n_users = 37, 300
+    rng = np.random.default_rng(0)
+    seqs = rng.integers(1, n_items + 1, (n_users, cfg.max_len)).astype(
+        np.int32)
+    seqs[:, :3] = 0          # left pads
+    seqs[7] = 0              # a user without a single event
+    params = _make_model(n_items, cfg).init(
+        jax.random.PRNGKey(1), jnp.asarray(seqs[:2]))
+    model = SeqRecModel(
+        jax.tree_util.tree_map(np.asarray, params), seqs,
+        BiMap({f"u{i}": i for i in range(n_users)}),
+        BiMap({f"i{i}": i for i in range(n_items)}), cfg)
+    users = [f"u{i}" for i in range(n_users)] + ["nobody"]
+    got = model.batch_recommend(users, [3] * len(users))
+    assert got[7] == [] and got[-1] == []
+    assert all(len(g) == 3 for j, g in enumerate(got[:-1]) if j != 7)
+    seq = model._pipeline.stats()["sequence"]
+    assert seq["steps"] == -(-(n_users - 1) // SeqRecEncoder.STEP_ROWS) == 3
+    assert seq["tokenLattice"] == [64, 128, 256, 512, 1024]
+    assert seq["tokensComputed"] == (128 + 128 + 64) * cfg.max_len
+    for j in (0, 150, 299):
+        alone = model.recommend_products(users[j], 3)
+        assert [i for i, _s in alone] == [i for i, _s in got[j]]
+        np.testing.assert_allclose([s for _i, s in alone],
+                                   [s for _i, s in got[j]], rtol=1e-5)
+        seen = {f"i{t - 1}" for t in seqs[j] if t}
+        assert not seen & {i for i, _s in got[j]}
+    # scores are the model's own logits
+    logits = np.asarray(_make_model(n_items, cfg).apply(
+        params, jnp.asarray(seqs[:1])))[0, -1, 1:]
+    for item, score in got[0]:
+        assert abs(logits[int(item[1:])] - score) < 1e-4
 
 
 def test_seq_rec_seq_parallel_matches_serial(seq_mesh):

@@ -411,3 +411,102 @@ def test_batcher_gate_opens_at_the_pipelines_step_end_not_the_calls(rng):
         linger.set()
     expect = ret.topk(users[[1, 2]], 3)[0][:, 0]
     assert out == [float(v) for v in expect]
+
+
+# ---------------------------------------------------------------------------
+# the encoder seam: a model whose query vector is computed
+
+
+class _MeanEncoder:
+    """A toy encoder (the contract is in ops/pipeline.py's docstring):
+    a position's state is the running mean of its history's item rows of
+    a fixed table, so the reference is numpy's."""
+
+    dense = False
+    aux_name = "parity"
+    passes = 1
+
+    def __init__(self, table, max_len=6, budget=32):
+        import jax.numpy as jnp
+
+        self.dim, self.max_len, self.budget = table.shape[1], max_len, budget
+        self.lattice = (16, 32)
+        self.params = {"table": jnp.asarray(table)}
+
+    def program(self, t_pad):
+        def fn(stream, params):
+            import jax.numpy as jnp
+
+            tokens, seg, pos = stream
+            same = (seg[:, None] == seg[None, :]) & (
+                jnp.arange(t_pad)[None, :] <= jnp.arange(t_pad)[:, None])
+            emb = params["table"][tokens]
+            mean = (same[:, :, None] * emb[None]).sum(1) / (pos + 1)[:, None]
+            return mean, pos % 2, jnp.int32(1)
+        return fn
+
+
+def _encoded_fixture(rng, n_users=40, n_items=50, dim=16):
+    table = rng.standard_normal((n_items + 1, dim)).astype(np.float32)
+    hist = np.zeros((n_users, 6), np.int32)
+    for u in range(n_users):
+        n = int(rng.integers(1, 7))
+        hist[u, -n:] = rng.integers(1, n_items + 1, n)
+    ret = DeviceRetriever(table[1:])
+    enc = _MeanEncoder(table)
+    return table, hist, ret, ServingPipeline(hist, ret, encoder=enc, ks=(8,))
+
+
+def test_encoder_seam_scores_the_last_positions_states(rng):
+    """rows -> histories -> the encoder's program -> the SAME fused
+    program over the step's table: the answers are numpy's, in steps of
+    at most the token budget, and the counters count tokens."""
+    table, hist, ret, pipe = _encoded_fixture(rng)
+    rows = np.arange(40, dtype=np.int32)
+    vals, idx = pipe.topk_rows(rows, 4)
+    want_q = np.stack([table[h[h > 0]].mean(0) for h in hist])
+    scores = want_q @ table[1:].T
+    want = np.argsort(-scores, axis=1, kind="stable")[:, :4]
+    np.testing.assert_array_equal(idx, want)
+    np.testing.assert_allclose(
+        vals, np.take_along_axis(scores, want, 1), rtol=1e-5, atol=1e-6)
+    seq = pipe.stats()["sequence"]
+    real = int((hist > 0).sum())
+    assert seq["tokensReal"] == real and seq["rows"] == 40
+    assert seq["steps"] >= -(-real // 32) and seq["steps"] == seq["loopPasses"]
+    assert seq["tokensComputed"] <= 32 * seq["steps"]
+    assert sum(seq["parity"]) == 40          # the encoder's aux, a row
+    assert seq["attentionPairs"] == sum(
+        n * (n + 1) // 2 for n in (hist > 0).sum(1).tolist())
+    assert pipe.cost_budget == 32
+    assert pipe.row_cost(3) == int((hist[3] > 0).sum())
+    assert "sequence" not in _fixture(rng)[2].stats()
+    assert _fixture(rng)[2].cost_budget is None
+
+
+def test_encoder_prewarm_walks_tokens_then_rows_and_refresh_keeps_them(rng):
+    _table, hist, _ret, pipe = _encoded_fixture(rng)
+    warmed = pipe.prewarm(batch_sizes=(1, 8, 16))
+    assert [w for w in warmed if w[1] == "encoder"] == [
+        ("pipeline", "encoder", 16), ("pipeline", "encoder", 32)]
+    assert [w for w in warmed if w[1] == "fused"] == [
+        ("pipeline", "fused", 8, 8), ("pipeline", "fused", 16, 8)]
+    before = EXEC_CACHE.stats()["misses"]
+    pipe.topk_rows(np.arange(12, dtype=np.int32), 5)
+    newer = pipe.refresh(np.roll(hist, 1, axis=0))
+    vals, _idx = newer.topk_rows(np.arange(12, dtype=np.int32), 5)
+    assert EXEC_CACHE.stats()["misses"] == before     # nothing compiled
+    old, _idx = pipe.topk_rows(np.arange(11, dtype=np.int32), 5)
+    np.testing.assert_allclose(vals[1:], old[:11], rtol=1e-6)
+    assert newer.stats()["sequence"]["steps"] == pipe.stats()[
+        "sequence"]["steps"]                          # shared counters
+
+
+def test_encoder_needs_the_exact_single_device_retriever(rng):
+    table, hist, _ret, _pipe = _encoded_fixture(rng)
+    with pytest.raises(ValueError, match="exact single-device"):
+        ServingPipeline(hist, _OwnPrograms(DeviceRetriever(table[1:])),
+                        encoder=_MeanEncoder(table))
+    with pytest.raises(ValueError, match="max_len"):
+        ServingPipeline(hist[:, :4], DeviceRetriever(table[1:]),
+                        encoder=_MeanEncoder(table))

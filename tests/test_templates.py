@@ -291,6 +291,45 @@ class TestSeqRec:
             [s.score for s in got[1].itemScores],
             [s.score for s in single_u5.itemScores], rtol=1e-5, atol=1e-6)
         assert got[2].itemScores == ()
+        # one device step a micro-batch, through the shared route
+        seq = model._pipeline.stats()["sequence"]
+        assert seq["rows"] == 1 + 2 + 1 and seq["steps"] == 3
+        assert model.serving_cost("u0") == 4 == model.config.max_len
+        assert model.serving_cost_budget == 4 * 128
+
+    def test_looped_algorithm_takes_the_published_keys(self, mesh8):
+        """`looped` beside `seqrec` in the one template: its params are
+        the published config's keys, and it learns the same cycle."""
+        mod = load_template("seqrec")
+        app = setup_app()
+        n_items = 6
+        for u in range(48):
+            for t in range(4):
+                insert(app.id, event="view", entity_type="user",
+                       entity_id=f"u{u}", target_entity_type="item",
+                       target_entity_id=f"i{(u + t) % n_items}")
+        engine = mod.engine_factory()
+        ep = EngineParams(
+            data_source_params=("", mod.DataSourceParams(app_name="MyApp")),
+            algorithm_params_list=(
+                ("looped", mod.LoopedParams(
+                    hidden_size=32, intermediate_size=48,
+                    num_hidden_layers=2, num_attention_heads=2, head_dim=16,
+                    total_ut_steps=2, max_len=4, compute_dtype="float32",
+                    epochs=60, batch_size=48, lr=3e-3)),
+            ),
+        )
+        result = engine.train(Context(), ep)
+        algo, model = result.algorithms[0], result.models[0]
+        assert model.params["layers"]["wq"].shape == (2, 32, 32)
+        out = algo.predict(model, mod.Query(user="u0", num=2))
+        assert out.itemScores[0].item == "i4"
+        got = dict(algo.batch_predict(model, [
+            (0, mod.Query(user="u0", num=2)),
+            (1, mod.Query(user="nosuch", num=2))]))
+        assert [s.item for s in got[0].itemScores] == [
+            s.item for s in out.itemScores]
+        assert got[1].itemScores == ()
 
 
 class TestRegression:

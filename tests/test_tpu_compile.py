@@ -196,3 +196,81 @@ def test_stock_flash_kernel_at_the_shapes_flash_attention_admits(
         exe = jax.jit(functools.partial(flash_attention, causal=causal)
                       ).lower(x, x, x).compile()
         assert "tpu_custom_call" in exe.as_text()
+
+
+# ---------------------------------------------------------------------------
+# the sequence cell (benchmarks/configs/ouro-2.6b-seqrec.json): the head
+# at rank 2048 and the looped decoder's serving step, shapes only
+
+SEQ_ITEMS, SEQ_RANK = 49_151, 2048
+
+
+@pytest.mark.parametrize("b_pad", [8, 32, 128])
+def test_topk_kernel_at_the_looped_decoders_head(v5e, b_pad):
+    """[B, 2048] x [2048, 49,152] + top-k: two buffers of a 512-item tile
+    are 8 MiB of the 12 MiB budget, the query is 16 lane groups; at the
+    k's of the sequence models' lattice (16 to max_len + 16), alone and in
+    the fused program over a step's query table."""
+    from predictionio_tpu.models.looped_lm import STEP_TOKEN_BUDGET
+    from predictionio_tpu.models.seq_serving import k_lattice
+
+    d_pad, n_pad = _padded_shape(SEQ_ITEMS, SEQ_RANK)
+    assert (d_pad, n_pad) == (2048, 49_152)
+    one = SingleDeviceSharding(v5e[0])
+    for k_pad in k_lattice(512):
+        tile, chunk = _tile_rows(b_pad, d_pad, k_pad, n_pad)
+        assert n_pad % tile == 0 and tile % chunk == 0
+        _compile_kernel(v5e[0], SEQ_ITEMS, b_pad, k_pad, rank=SEQ_RANK)
+    raw = _raw_call(b_pad, d_pad, n_pad, SEQ_ITEMS, 528, False)
+    exe = jax.jit(_fused_fn(raw, True), donate_argnums=(0,)).lower(
+        jax.ShapeDtypeStruct((b_pad,), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((STEP_TOKEN_BUDGET + 8, 2048), jnp.float32,
+                             sharding=one),
+        jax.ShapeDtypeStruct((d_pad, n_pad), jnp.float32, sharding=one),
+    ).compile()
+    _assert_one_kernel_and_the_packed_result(exe, b_pad, 528)
+
+
+@pytest.mark.parametrize("t_pad", [256, 512, 1024])
+def test_looped_serving_step_at_its_token_lattice(v5e, monkeypatch, t_pad):
+    """The encoder executable of `pio deploy` at Ouro-2.6B's published
+    widths: ONE `while` (48 layers x 4 passes in one scan, so the
+    benchmark's `loop_share` counts no time twice), the stock flash
+    kernel with segment ids inside it, the weights as arguments (4.93 GB
+    of layers and the 0.2 GB embedding, bfloat16) and a step's query
+    table out."""
+    import re
+
+    from predictionio_tpu.models import looped_lm as lm
+    from predictionio_tpu.ops.pipeline import _encoder_fn
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e[0])
+    cfg = lm.LoopedLMConfig()
+    shapes = lm.param_shapes(cfg, SEQ_ITEMS + 1)
+
+    def arg(shape, matrix):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.bfloat16 if matrix else jnp.float32, sharding=one)
+
+    params = {"embed": arg(shapes["embed"], True),
+              "norm_f": arg(shapes["norm_f"], False),
+              "gate_w": arg(shapes["gate_w"], False),
+              "gate_b": arg((), False),
+              "layers": {k: arg(v, k in lm._LAYER_SHAPES)
+                         for k, v in shapes["layers"].items()}}
+    cap = lm.STEP_TOKEN_BUDGET + 8
+    exe = jax.jit(_encoder_fn(lm.encoder_program(cfg), cap, 2048)).lower(
+        jax.ShapeDtypeStruct((3, t_pad), jnp.int32, sharding=one), params,
+    ).compile()
+    hlo = exe.as_text()
+    # by its name, as benchmarks/layers/loop_share.json matches it (the
+    # reads of its results carry the name too; they are no operations)
+    assert len(re.findall(r"^\s*(?:ROOT )?%while[.\d]* = .* while\(", hlo,
+                          re.M)) == 1
+    assert hlo.count(" while(") == 1
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
+    mem = exe.memory_analysis()
+    assert 5.1e9 < mem.argument_size_in_bytes < 5.2e9
+    assert mem.output_size_in_bytes >= cap * 2048 * 4
+    assert mem.temp_size_in_bytes < 1e9
