@@ -33,6 +33,14 @@ matmul inputs are ``compute_dtype`` (bfloat16 as published); the
 residual stream, accumulation, norms, softmax, gate and scores are
 float32.
 
+The PUBLIC tree (``param_shapes``, ``init_params``, a model's ``params``,
+the persisted blob) holds the attention projections as ``[L, D, A]`` and
+``[L, A, D]``. The forward reads them head-major, ``[L, H, hd, D]``
+(``head_major``): in that layout every matrix of a layer is sliced from
+its stack inside the matmul that reads it, where ``[D, H x hd]`` made
+the TPU stage and transpose 8 MB a projection on every layer
+application (PERF.md, PR 35).
+
 The forward takes a token STREAM ``[R, S]`` with segment ids and
 positions: histories packed one after another in a row (serving: R = 1,
 padding waste is what the last lattice point leaves) or one history a
@@ -56,6 +64,7 @@ __all__ = [
     "LoopedEncoder",
     "STEP_TOKEN_BUDGET",
     "forward_hidden",
+    "head_major",
     "init_params",
     "param_count",
     "train_looped_lm",
@@ -157,6 +166,26 @@ def _stored(params: dict, cd) -> dict:
     return out
 
 
+def head_major(layers: dict, cfg: LoopedLMConfig) -> dict:
+    """The layer stacks ``forward_hidden`` reads, from the public ones
+    (all of a tree's, or some): ``wq``, ``wk``, ``wv`` ``[L, D, A]`` ->
+    ``[L, H, hd, D]``, a transpose; ``wo`` ``[L, A, D]`` -> ``[L, H, hd,
+    D]``, a view; the rest as they are. numpy or jax arrays, under a
+    trace or not."""
+    H, hd = cfg.num_attention_heads, cfg.head_dim
+
+    def one(name, w):
+        if name in ("wq", "wk", "wv"):
+            L, D, _A = w.shape
+            return w.reshape(L, D, H, hd).transpose(0, 2, 3, 1)
+        if name == "wo":
+            L, _A, D = w.shape
+            return w.reshape(L, H, hd, D)
+        return w
+
+    return {name: one(name, w) for name, w in layers.items()}
+
+
 def _rms(x, gain, eps):
     import jax
     import jax.numpy as jnp
@@ -187,7 +216,8 @@ def forward_hidden(params: dict, cfg: LoopedLMConfig, tokens, seg, pos):
     passes the loop ran, counted on the device where a pass ends.
 
     tokens, seg, pos: int32 [R, S]; positions of one history share a
-    segment id and count 0, 1, ... within it."""
+    segment id and count 0, 1, ... within it. ``params["layers"]`` is
+    ``head_major``'s tree, not the public one."""
     import jax
     import jax.numpy as jnp
 
@@ -197,21 +227,25 @@ def forward_hidden(params: dict, cfg: LoopedLMConfig, tokens, seg, pos):
     cd = jnp.dtype(cfg.compute_dtype)
     prec = jax.lax.Precision.HIGHEST if cd == f32 else None
     L, T = cfg.num_hidden_layers, cfg.total_ut_steps
-    H, hd, eps = cfg.num_attention_heads, cfg.head_dim, cfg.rms_norm_eps
+    eps = cfg.rms_norm_eps
     R, S = tokens.shape
 
     def mm(x, w):
         return jnp.dot(x.astype(cd), w.astype(cd), precision=prec,
                        preferred_element_type=f32)
 
+    def heads(spec, x, w):
+        return jnp.einsum(spec, x.astype(cd), w.astype(cd), precision=prec,
+                          preferred_element_type=f32)
+
     def layer(h, w):
         a = _rms(h, w["norm1"], eps)
-        q = _rope(mm(a, w["wq"]).reshape(R, S, H, hd), pos, cfg.rope_theta)
-        k = _rope(mm(a, w["wk"]).reshape(R, S, H, hd), pos, cfg.rope_theta)
-        v = mm(a, w["wv"]).reshape(R, S, H, hd)
+        q = _rope(heads("rsd,hkd->rshk", a, w["wq"]), pos, cfg.rope_theta)
+        k = _rope(heads("rsd,hkd->rshk", a, w["wk"]), pos, cfg.rope_theta)
+        v = heads("rsd,hkd->rshk", a, w["wv"])
         o = flash_attention(q.astype(cd), k.astype(cd), v.astype(cd),
                             causal=True, segment_ids=seg)
-        h = h + _rms(mm(o.reshape(R, S, H * hd), w["wo"]), w["norm2"], eps)
+        h = h + _rms(heads("rshk,hkd->rsd", o, w["wo"]), w["norm2"], eps)
         m = _rms(h, w["norm3"], eps)
         act = jax.nn.silu(mm(m, w["wg"])) * mm(m, w["wu"])
         return h + _rms(mm(act, w["wd"]), w["norm4"], eps)
@@ -301,6 +335,15 @@ class LoopedEncoder:
         # the head is the retriever's catalog, not the encoder's
         tree = _stored({k: v for k, v in params.items() if k != "head"},
                        jnp.dtype(cfg.compute_dtype))
+        # head-major once, here, on the device, one stack at a time: the
+        # wait is what frees a stack's public copy and its reshape (0.8
+        # GB at the published widths) before the next stack goes up;
+        # dispatched without it, three stacks' were live at once and the
+        # deploy's peak read 8.4 GB for 5.6 (PERF.md, PR 35)
+        tree["layers"] = {
+            k: jax.block_until_ready(
+                head_major({k: jax.device_put(v)}, cfg)[k])
+            for k, v in tree["layers"].items()}
         self.params = jax.block_until_ready(jax.device_put(tree))
         self.param_bytes = int(sum(
             x.nbytes for x in jax.tree_util.tree_leaves(self.params)))
@@ -344,7 +387,9 @@ def train_looped_lm(seqs: np.ndarray, user_ids: BiMap, item_ids: BiMap,
 
     def loss_fn(p, batch):
         inp, tgt = batch[:, :-1], batch[:, 1:]
-        h, _half, _ran = forward_hidden(p, cfg, *_rows_to_stream(inp))
+        h, _half, _ran = forward_hidden(
+            {**p, "layers": head_major(p["layers"], cfg)}, cfg,
+            *_rows_to_stream(inp))
         logits = jnp.einsum("bld,vd->blv", h, p["head"].astype(jnp.float32))
         mask = (tgt > 0).astype(jnp.float32)
         ce = optax.softmax_cross_entropy_with_integer_labels(logits, tgt)

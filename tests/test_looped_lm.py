@@ -56,6 +56,14 @@ def make_model(cfg=CFG, seed=1, gate_b=0.3) -> lm.LoopedLMModel:
         BiMap({f"i{i}": i for i in range(N_ITEMS)}), cfg)
 
 
+def device_tree(model):
+    """The tree `forward_hidden` reads, as `LoopedEncoder` makes it from
+    the model's public one."""
+    params = jax.tree_util.tree_map(jnp.asarray, model.params)
+    return {**params,
+            "layers": lm.head_major(params["layers"], model.config)}
+
+
 def history(model, user):
     row = model.seqs[model.user_ids.get(user)]
     return row[row > 0]
@@ -113,7 +121,7 @@ def test_left_padded_rows_and_the_packed_stream_agree():
     serving layout (histories packed in one row) give every real
     position the same state, the reference's."""
     model = make_model()
-    params = jax.tree_util.tree_map(jnp.asarray, model.params)
+    params = device_tree(model)
     rows = model.seqs[[3, 5, 8, 13]]
     h_rows, _half, _ran = lm.forward_hidden(params, CFG,
                                       *lm._rows_to_stream(jnp.asarray(rows)))
@@ -135,6 +143,68 @@ def test_left_padded_rows_and_the_packed_stream_agree():
             ref_cfg())
         np.testing.assert_allclose(h_pack[0, at:at + n], want, atol=2e-5)
         at += n
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_head_major_is_a_transpose_and_a_view(dtype):
+    """`wq`, `wk`, `wv` `[L, D, A]` -> `[L, H, hd, D]` and `wo` `[L, A, D]`
+    -> `[L, H, hd, D]` move no value: turned back they are the public
+    stacks bit for bit, `wo` and the stacks it leaves alone are the
+    public tree's own memory, and head h's slice is the h-th block of
+    `head_dim` columns (rows, for `wo`)."""
+    cfg = dataclasses.replace(CFG, compute_dtype=dtype)
+    public = lm._stored(lm.init_params(cfg, N_ITEMS + 1, seed=5),
+                        jnp.dtype(dtype))["layers"]
+    got = lm.head_major(public, cfg)
+    L, H, hd, D = 3, 2, 32, 64
+    assert set(got) == set(public)
+    for k in ("wq", "wk", "wv"):
+        assert got[k].shape == (L, H, hd, D) and got[k].dtype == public[k].dtype
+        back = got[k].transpose(0, 3, 1, 2).reshape(L, D, H * hd)
+        assert back.tobytes() == public[k].tobytes()
+        np.testing.assert_array_equal(
+            got[k][1, 1].astype(np.float32),
+            public[k][1, :, hd:2 * hd].T.astype(np.float32))
+    assert got["wo"].shape == (L, H, hd, D)
+    assert np.shares_memory(got["wo"], public["wo"])
+    assert got["wo"].reshape(L, H * hd, D).tobytes() == public["wo"].tobytes()
+    for k in set(public) - {"wq", "wk", "wv", "wo"}:
+        assert got[k] is public[k]
+    # some of a tree's stacks (the encoder converts stack by stack), and
+    # under a trace, as the trainer's step does
+    np.testing.assert_array_equal(
+        lm.head_major({"wq": public["wq"]}, cfg)["wq"], got["wq"])
+    traced = jax.jit(lambda t: lm.head_major(t, cfg))(public)
+    for k in public:
+        assert np.asarray(traced[k]).tobytes() == np.ascontiguousarray(
+            got[k]).tobytes()
+
+
+def test_a_blob_of_the_public_tree_deploys_head_major():
+    """What `workflow/serialization.py` persists is the public tree
+    (`[L, D, A]`: a blob written before the head-major layout deploys as
+    it is); the encoder holds the converted stacks on the device, the
+    model keeps its own, and the answers are the reference's."""
+    written = make_model()
+    (model,) = deserialize_models(serialize_models([written]))
+    shapes = lm.param_shapes(CFG, N_ITEMS + 1)
+    for k, shape in shapes["layers"].items():
+        assert model.params["layers"][k].shape == shape
+        np.testing.assert_array_equal(model.params["layers"][k],
+                                      written.params["layers"][k])
+    model.attach_retriever()
+    model.attach_pipeline()
+    enc = model._pipeline._encoder
+    assert "head" not in enc.params
+    for k in ("wq", "wk", "wv", "wo"):
+        assert enc.params["layers"][k].shape == (3, 2, 32, 64)
+        assert model.params["layers"][k].shape == shapes["layers"][k]
+    assert enc.params["layers"]["wg"].shape == shapes["layers"]["wg"]
+    assert enc.param_bytes == sum(
+        np.asarray(v).nbytes for v in jax.tree_util.tree_leaves(
+            {k: v for k, v in model.params.items() if k != "head"}))
+    got = model.batch_recommend(USERS, [5] * len(USERS))
+    assert held_to_reference(model, USERS, got, 5) < 1e-5
 
 
 def test_the_tree_and_the_blob_hold_the_layers_once():
@@ -179,7 +249,7 @@ def test_passes_are_the_plain_stack_applied_again(passes):
                      for k, v in model.params["layers"].items()}
                 h = ref.layer_forward(h, w, ref_cfg(cfg))
             h = ref.rms_norm(h, model.params["norm_f"], cfg.rms_norm_eps)
-    params = jax.tree_util.tree_map(jnp.asarray, model.params)
+    params = device_tree(model)
     got, _half, _ran = lm.forward_hidden(
         params, cfg, jnp.asarray(hist)[None],
         jnp.ones((1, len(hist)), jnp.int32),
@@ -226,7 +296,7 @@ def test_exit_distribution_and_threshold(threshold, last):
     assert (np.asarray(exit_step) == cfg.total_ut_steps).all() == last
     assert np.asarray(exit_step).min() >= 1
     assert (np.asarray(half_step) <= np.asarray(exit_step)).all()
-    params = jax.tree_util.tree_map(jnp.asarray, model.params)
+    params = device_tree(model)
     got, half, ran = lm.forward_hidden(
         params, cfg, jnp.asarray(hist)[None],
         jnp.ones((1, len(hist)), jnp.int32),

@@ -231,20 +231,15 @@ def test_topk_kernel_at_the_looped_decoders_head(v5e, b_pad):
     _assert_one_kernel_and_the_packed_result(exe, b_pad, 528)
 
 
-@pytest.mark.parametrize("t_pad", [256, 512, 1024])
-def test_looped_serving_step_at_its_token_lattice(v5e, monkeypatch, t_pad):
-    """The encoder executable of `pio deploy` at Ouro-2.6B's published
-    widths: ONE `while` (48 layers x 4 passes in one scan, so the
-    benchmark's `loop_share` counts no time twice), the stock flash
-    kernel with segment ids inside it, the weights as arguments (4.93 GB
-    of layers and the 0.2 GB embedding, bfloat16) and a step's query
-    table out."""
-    import re
-
+@pytest.fixture(scope="module")
+def looped_step(v5e):
+    """t_pad -> the compiled encoder executable of `pio deploy` at
+    Ouro-2.6B's published widths, from the tree `LoopedEncoder` puts on
+    the device (the public shapes through `head_major`); each compiled
+    once for the tests below."""
     from predictionio_tpu.models import looped_lm as lm
     from predictionio_tpu.ops.pipeline import _encoder_fn
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     one = SingleDeviceSharding(v5e[0])
     cfg = lm.LoopedLMConfig()
     shapes = lm.param_shapes(cfg, SEQ_ITEMS + 1)
@@ -253,16 +248,42 @@ def test_looped_serving_step_at_its_token_lattice(v5e, monkeypatch, t_pad):
         return jax.ShapeDtypeStruct(
             shape, jnp.bfloat16 if matrix else jnp.float32, sharding=one)
 
+    layers = jax.eval_shape(
+        lambda t: lm.head_major(t, cfg),
+        {k: arg(v, k in lm._LAYER_SHAPES)
+         for k, v in shapes["layers"].items()})
     params = {"embed": arg(shapes["embed"], True),
               "norm_f": arg(shapes["norm_f"], False),
               "gate_w": arg(shapes["gate_w"], False),
               "gate_b": arg((), False),
-              "layers": {k: arg(v, k in lm._LAYER_SHAPES)
-                         for k, v in shapes["layers"].items()}}
+              "layers": {k: arg(v.shape, k in lm._LAYER_SHAPES)
+                         for k, v in layers.items()}}
     cap = lm.STEP_TOKEN_BUDGET + 8
-    exe = jax.jit(_encoder_fn(lm.encoder_program(cfg), cap, 2048)).lower(
-        jax.ShapeDtypeStruct((3, t_pad), jnp.int32, sharding=one), params,
-    ).compile()
+
+    @functools.lru_cache(maxsize=None)
+    def compiled(t_pad):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            return jax.jit(
+                _encoder_fn(lm.encoder_program(cfg), cap, 2048)).lower(
+                jax.ShapeDtypeStruct((3, t_pad), jnp.int32, sharding=one),
+                params).compile()
+
+    return compiled
+
+
+@pytest.mark.parametrize("t_pad", [256, 512, 1024])
+def test_looped_serving_step_at_its_token_lattice(looped_step, t_pad):
+    """ONE `while` (48 layers x 4 passes in one scan, so the benchmark's
+    `loop_share` counts no time twice), the stock flash kernel with
+    segment ids inside it, the weights as arguments (4.93 GB of layers
+    and the 0.2 GB embedding, bfloat16: the head-major layout moves no
+    byte of them) and a step's query table out."""
+    import re
+
+    from predictionio_tpu.models.looped_lm import STEP_TOKEN_BUDGET
+
+    exe = looped_step(t_pad)
     hlo = exe.as_text()
     # by its name, as benchmarks/layers/loop_share.json matches it (the
     # reads of its results carry the name too; they are no operations)
@@ -272,5 +293,72 @@ def test_looped_serving_step_at_its_token_lattice(v5e, monkeypatch, t_pad):
     assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
     mem = exe.memory_analysis()
     assert 5.1e9 < mem.argument_size_in_bytes < 5.2e9
-    assert mem.output_size_in_bytes >= cap * 2048 * 4
+    assert mem.output_size_in_bytes >= (STEP_TOKEN_BUDGET + 8) * 2048 * 4
     assert mem.temp_size_in_bytes < 1e9
+
+
+def _moved_whole(body: str) -> list:
+    """The instructions of a computation that only MOVE a whole matrix:
+    a `copy`, a `dynamic-slice` or a fusion named for either, whose
+    result is a bfloat16 array of 2,048 x 2,048 elements or more; and
+    any `copy` of the Mosaic kernel's result or of a bitcast of it."""
+    import re
+
+    found = []
+    kernel = {re.search(r"(%[\w.\-]+) = \S+ custom-call\(.*tpu_custom_call",
+                        body).group(1)}
+    for name, dtype, dims, op, operands in re.findall(
+            r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+            r"([\w\-]+)\(([^)]*)\)", body, re.M):
+        moves = op in ("copy", "dynamic-slice") or (
+            op == "fusion" and ("dynamic-slice" in name or "copy" in name))
+        size = int(np.prod([int(d) for d in dims.split(",") if d]))
+        if moves and dtype == "bf16" and size >= 2048 * 2048:
+            found.append(name)
+        if kernel & set(operands.split(", ")):
+            if op == "bitcast":
+                kernel.add(name)
+            elif op == "copy":
+                found.append(name)
+    return found
+
+
+@pytest.mark.parametrize("t_pad", [256, 512, 1024])
+def test_looped_layer_slices_its_weights_inside_the_matmuls(looped_step,
+                                                             t_pad):
+    """No layer application stages, copies or transposes a matrix before
+    its matmul: the `while` body has no `copy` and no `dynamic-slice`
+    fusion of a bfloat16 array the size of a layer's matrix (the
+    `[L, D, H x hd]` store cost three stagings and three transposes of 8
+    MB an application, 9% of a 1,024-token step: PERF.md, PR 35), and
+    the flash kernel's output goes to `wo` as it lies."""
+    import re
+
+    hlo = looped_step(t_pad).as_text()
+    name = re.search(r" while\(.*?body=(%[\w.\-]+)", hlo).group(1)
+    start = hlo.index("\n" + name + " ")
+    body = hlo[start:hlo.index("\n}\n", start)]
+    assert "custom_call_target=\"tpu_custom_call\"" in body
+    assert _moved_whole(body) == []
+
+
+def test_the_detector_finds_what_the_flat_store_compiled_to():
+    """The `while` body of the `[L, D, H x hd]` store (v5e compile of
+    PR 31's step, `t_pad` 1,024): one staging and one transpose a
+    projection, and the kernel's output turned for `wo`."""
+    tile = "T(8,128)(2,1)S(1)}"
+    assert _moved_whole(
+        f"  %constant_dynamic-slice_fusion.16 = bf16[1,2048,2048]{{2,1,0:{tile}"
+        " fusion(%bitcast.165, %select_n.117), kind=kLoop, calls=%fused.16\n"
+        f"  %copy.20 = bf16[1,2048,2048]{{1,2,0:{tile}"
+        " copy(%constant_dynamic-slice_fusion.16)\n"
+        f"  %flash_attention.6 = bf16[1,16,1024,128]{{3,2,1,0:{tile}"
+        " custom-call(%fusion.83, %fusion.84),"
+        " custom_call_target=\"tpu_custom_call\"\n"
+        f"  %bitcast.168 = bf16[1,1024,16,128]{{3,1,2,0:{tile}"
+        " bitcast(%flash_attention.6)\n"
+        f"  %copy.23 = bf16[1,1024,16,128]{{1,3,2,0:{tile}"
+        " copy(%bitcast.168), metadata={op_name=\"jit(fn)/transpose\"}\n"
+        "  %fusion.86 = bf16[1024,5632]{1,0:T(8,128)(2,1)S(1)}"
+        " fusion(%fusion.85), kind=kLoop, calls=%fused.86\n") == [
+            "%constant_dynamic-slice_fusion.16", "%copy.20", "%copy.23"]
