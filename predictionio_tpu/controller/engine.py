@@ -58,6 +58,10 @@ class TrainResult:
     algorithms: list[Algorithm]
     serving: Serving
     algorithm_names: list[str]
+    #: the stored blob's checksum where the result was rehydrated from
+    #: one (``prepare_deploy``): the bundle's provenance, kept so that
+    #: nobody reads gigabytes a second time to learn it
+    blob_checksum: str | None = None
 
 
 @dataclasses.dataclass

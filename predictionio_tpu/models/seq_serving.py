@@ -37,7 +37,9 @@ class SequenceServingMixin(RetrievalServingMixin):
     """For a model with ``seqs`` [users, max_len] (left-padded, 0 = pad,
     item i stored as i + 1), ``user_ids``, ``item_ids``, a ``catalog``
     property ([items, D] float32, the pad row left out) and
-    ``make_encoder()`` (the contract is in ops/pipeline.py)."""
+    ``make_encoder()`` (the contract is in ops/pipeline.py); optionally
+    ``serving_ks``, the k's its head is compiled for (default
+    ``k_lattice`` of the history length: ``exclude_seen``'s over-fetch)."""
 
     _retrieval_attr = "catalog"
     _query_attr = "seqs"
@@ -51,7 +53,8 @@ class SequenceServingMixin(RetrievalServingMixin):
             s["bytes"] = int(getattr(encoder, "param_bytes", 0))
         self._pipeline = ServingPipeline(
             self.seqs, getattr(self, "_retriever", None), encoder=encoder,
-            ks=k_lattice(self.seqs.shape[1]))
+            ks=(getattr(self, "serving_ks", None)
+                or k_lattice(self.seqs.shape[1])))
 
     def _serving_pipeline(self):
         """The attached pipeline; a model nobody deployed (a test, a

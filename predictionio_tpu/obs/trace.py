@@ -29,15 +29,19 @@ from __future__ import annotations
 import contextvars
 import json
 import logging
+import re
 import sys
 import time
 import uuid
 
 __all__ = [
+    "DEVICE_SCOPES",
+    "DeviceScopes",
     "TRACE_HEADER",
     "current_request_id",
     "ensure_request_id",
     "new_request_id",
+    "operation_key",
     "set_request_id",
     "span",
     "spans_from_waterfall",
@@ -176,6 +180,75 @@ class span:
             log.log(self.level, "%s",
                     json.dumps(rec, sort_keys=True, default=str))
         return False
+
+
+# ---------------------------------------------------------------------------
+# Device time by named scope. The v5e profiler's operation lines name an
+# operation by its instruction (``%fusion.174 = bf16[...] fusion(...)``)
+# and carry no ``jax.named_scope``; the compiled program's text does
+# (``metadata={op_name="jit(fn)/pio.seq.router/..."}``). So a program
+# whose device time is to be read by scope hands its compiled text to
+# ``DEVICE_SCOPES.record`` when it is built, and a capture writes the
+# map beside its ``.xplane.pb`` (``pio_scopes.json``; workflow/tracing.py).
+
+_OPERATION = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(")
+_OP_NAME = re.compile(r"op_name=\"([^\"]*)\"")
+_SCOPE = re.compile(r"(?:^|/)(pio\.[\w.]+)(?=/|$)")
+#: operations that only contain others: their time is their bodies'
+_CONTAINERS = frozenset({"while", "conditional", "call"})
+
+
+def operation_key(line: str) -> str | None:
+    """``%name = type opcode`` of an instruction, from a line of a
+    compiled program's text or from a profiler event's name (which
+    prints the operands' types too): what the two have in common."""
+    m = _OPERATION.match(line)
+    return None if m is None else f"{m.group(1)} = {m.group(2)} {m.group(3)}"
+
+
+class DeviceScopes:
+    """operation -> the innermost ``pio.*`` named scope it was traced
+    under, over the programs recorded so far."""
+
+    def __init__(self):
+        self._map: dict[str, str] = {}
+
+    def record(self, hlo_text: str) -> int:
+        """Read one compiled program's text; returns the operations
+        named. The key holds the result's type, which holds the shapes:
+        two programs' ``%fusion.7`` differ unless they compute alike."""
+        named = 0
+        for line in hlo_text.splitlines():
+            if "pio." not in line:
+                continue
+            m, op_name = _OPERATION.match(line), _OP_NAME.search(line)
+            if m is None or op_name is None or m.group(3) in _CONTAINERS:
+                continue
+            scopes = _SCOPE.findall(op_name.group(1))
+            if scopes:
+                self._map[operation_key(line)] = scopes[-1]
+                named += 1
+        return named
+
+    def snapshot(self) -> dict[str, str]:
+        return dict(self._map)
+
+    def dump(self, trace_dir: str) -> None:
+        """Write the map beside a capture; nothing where nothing was
+        recorded (a program without named scopes)."""
+        if not self._map:
+            return
+        import os
+
+        os.makedirs(trace_dir, exist_ok=True)
+        with open(os.path.join(trace_dir, "pio_scopes.json"), "w") as f:
+            json.dump(self._map, f)
+
+
+#: Process-wide: every compiled program of the process that wants its
+#: device time read by scope (today: the serving pipeline's encoder
+#: programs).
+DEVICE_SCOPES = DeviceScopes()
 
 
 # ---------------------------------------------------------------------------

@@ -91,6 +91,11 @@ lattice and accounts every pinned buffer in the PR 12 device ledger
     for padding) and positions within the history; ``passes`` is what
     the program itself counted of its loop's passes (``loopPasses`` adds
     it up: no product of the configuration), None where it has no loop.
+    A program may hand out a fourth value, ``counters`` int32[n], what
+    the device program counted of its own work in this step, named by
+    the encoder's ``counter_names``: each is added up under its name in
+    ``/stats.json``'s ``sequence`` block;
+  - optionally ``counter_names`` (above).
 """
 
 from __future__ import annotations
@@ -98,6 +103,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from contextvars import ContextVar
 from typing import Callable
 
@@ -106,7 +112,7 @@ import numpy as np
 from ..obs.device import LEDGER
 from ..obs.metrics import METRICS
 from ..obs.startup import STARTUP
-from ..obs.trace import span
+from ..obs.trace import DEVICE_SCOPES, span
 from ..obs.waterfall import stage_span
 from ..faults import FAULTS
 from .retrieval import (
@@ -209,17 +215,17 @@ def _scoped(fn, scope: str):
 
 def _encoder_fn(program, cap: int, d_pad: int):
     """stream, params -> (a step's query table [cap, d_pad], aux
-    int32[t_pad + 1]): the encoder's states laid into the zeroed table
-    the fused program gathers from (rows past the stream stay zero: the
-    sentinel), and its aux with the passes it counted behind it, one
-    array for the one pull; apart for tests/test_tpu_compile.py like
+    int32[t_pad + 1 + counters]): the encoder's states laid into the
+    zeroed table the fused program gathers from (rows past the stream
+    stay zero: the sentinel), and its aux with the passes it counted
+    and then its counters behind it, one array for the one pull; apart for tests/test_tpu_compile.py like
     `_fused_fn`."""
 
     def fn(stream, params):
         import jax
         import jax.numpy as jnp
 
-        states, aux, passes = program(stream, params)
+        states, aux, passes, *counters = program(stream, params)
         table = jax.lax.dynamic_update_slice(
             jnp.zeros((cap, d_pad), jnp.float32),
             states.astype(jnp.float32), (0, 0))
@@ -228,7 +234,8 @@ def _encoder_fn(program, cap: int, d_pad: int):
         if passes is None:
             passes = jnp.int32(0)
         return table, jnp.concatenate(
-            [aux.astype(jnp.int32), passes.astype(jnp.int32).reshape(1)])
+            [aux.astype(jnp.int32), passes.astype(jnp.int32).reshape(1),
+             *(c.astype(jnp.int32) for c in counters)])
 
     return fn
 
@@ -347,9 +354,14 @@ class ServingPipeline:
         self._sentinel = self._cap - 1
         self._qtab = None
         self._state.aux_hist = np.zeros(int(enc.passes) + 1, np.int64)
+        for name in getattr(enc, "counter_names", ()):
+            self._state.seq.setdefault(name, 0)
 
     def _set_histories(self, histories) -> None:
-        hist = np.ascontiguousarray(histories, np.int32)
+        hist = np.asarray(histories)
+        if hist.dtype != np.uint16:  # ids under 65,536 stay two bytes
+            hist = hist.astype(np.int32, copy=False)
+        hist = np.ascontiguousarray(hist)
         if hist.ndim != 2 or hist.shape[1] != self._encoder.max_len:
             raise ValueError("history table must be [rows, max_len]")
         self._hist = hist
@@ -407,12 +419,15 @@ class ServingPipeline:
             import jax.numpy as jnp
 
             fn = _encoder_fn(enc.program(t_pad), self._cap, self._d_pad)
-            return jax.jit(fn).lower(
+            compiled = jax.jit(fn).lower(
                 jax.ShapeDtypeStruct((3, t_pad), jnp.int32),
                 jax.tree_util.tree_map(
                     lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                     enc.params),
             ).compile()
+            # a capture reads this program's device time by named scope
+            DEVICE_SCOPES.record(compiled.as_text())
+            return compiled
 
         out = EXEC_CACHE.get_or_build(key, build)
         if pin:
@@ -663,7 +678,10 @@ class ServingPipeline:
                 seq["tokensReal"] += int(real.sum())
                 seq["tokensComputed"] += t_pad
                 seq["attentionPairs"] += int((real * (real + 1) // 2).sum())
-                seq["loopPasses"] += int(aux[-1])
+                seq["loopPasses"] += int(aux[t_pad])
+                for name, c in zip(getattr(enc, "counter_names", ()),
+                                   aux[t_pad + 1:].tolist()):
+                    seq[name] += c
                 if exits is not None:
                     st.aux_hist += np.bincount(
                         exits, minlength=len(st.aux_hist))[:len(st.aux_hist)]
@@ -739,22 +757,40 @@ class ServingPipeline:
         account every pinned buffer in the device ledger. Returns the
         distinct cache keys warmed (digested into exec_cache_key)."""
         warmed: list[tuple] = []
-        seen: set[tuple[int, int]] = set()
-        gathered: set[int] = set()
-        n_total = self._retriever.n_total
         ks = self.ks if ks is None else ks
-        if self._encoder is not None:
-            # the token dimension of the lattice; the fused programs
-            # follow at the model's own k's
-            for t_pad in self._encoder.lattice:
+        if self._encoder is None:
+            self._prewarm_scoring(batch_sizes, ks, warmed)
+        else:
+            # the token dimension of the lattice, its points compiled
+            # side by side and beside the scoring programs (a compile
+            # holds no interpreter lock and one program of unlike layers
+            # takes tens of seconds); a program that does not compile
+            # fails the deploy when its result is read
+            def warm(t_pad):
                 with span("deploy.prewarm.program", sink=STARTUP.phase,
                           kind="encoder", t_pad=t_pad):
                     self._exec_encoder(t_pad, pin=True)
-                warmed.append(("pipeline", "encoder", t_pad))
-                with self._state.cond:
-                    self._state.streams.setdefault(t_pad, [
-                        np.empty((3, t_pad), np.int32)
-                        for _ in range(STAGING_DEPTH)])
+
+            lattice = self._encoder.lattice
+            with ThreadPoolExecutor(len(lattice)) as encoders:
+                pending = [encoders.submit(warm, t_pad) for t_pad in lattice]
+                for t_pad in lattice:
+                    warmed.append(("pipeline", "encoder", t_pad))
+                    with self._state.cond:
+                        self._state.streams.setdefault(t_pad, [
+                            np.empty((3, t_pad), np.int32)
+                            for _ in range(STAGING_DEPTH)])
+                self._prewarm_scoring(batch_sizes, ks, warmed)
+                for job in pending:
+                    job.result()
+        self._account_buffers()
+        return warmed
+
+    def _prewarm_scoring(self, batch_sizes, ks, warmed: list) -> None:
+        """The (b_pad, k_pad) programs of `prewarm` and their staging."""
+        seen: set[tuple[int, int]] = set()
+        gathered: set[int] = set()
+        n_total = self._retriever.n_total
         for b in batch_sizes:
             for k in ks:
                 k_eff = min(k, n_total)
@@ -780,8 +816,6 @@ class ServingPipeline:
                     self._state.staging.setdefault(b_pad, [
                         np.empty(b_pad, np.int32)
                         for _ in range(STAGING_DEPTH)])
-        self._account_buffers()
-        return warmed
 
     def _account_buffers(self) -> None:
         with self._state.cond:

@@ -30,6 +30,8 @@ __all__ = [
     "flash_attention",
     "ring_attention",
     "segment_attention",
+    "segment_flash_attention",
+    "attention_kernel_for",
     "ring_self_attention",
     "ulysses_attention",
 ]
@@ -209,25 +211,276 @@ def segment_attention(q, k, v, segment_ids, *, causal: bool = False):
     return out.astype(q.dtype)
 
 
+#: Longest stream the plain path (``segment_attention``, which builds the
+#: whole [B, H, L, L] scores) may take ON the TPU for a shape no kernel
+#: there admits (an unaligned L): 64 heads at this length are 0.27 GB of
+#: float32 scores. Off the TPU it takes any length (tests, CPU drives).
+PLAIN_MAX_L = 1024
+
+
+def attention_kernel_for(L: int, d_qk: int, d_v: int, *, backend: str,
+                         segmented: bool) -> str:
+    """Which kernel ``flash_attention`` runs for a shape, by name:
+    ``stock`` (the stock Pallas kernel: on the TPU, L a multiple of 128,
+    q, k and v sharing a head size of 64 or 128), ``segment_flash``
+    (this module's kernel: on the TPU, L a multiple of 128, any other
+    head sizes, d_qk != d_v among them), ``plain`` (``segment_attention``
+    / ``blockwise_attention``: off the TPU, or on it up to
+    ``PLAIN_MAX_L``). A shape none admits on the TPU raises: the plain
+    path at a long L would build gigabytes of scores behind the caller's
+    back."""
+    if backend != "tpu":
+        return "plain"
+    if L % 128 == 0:
+        return "stock" if d_qk == d_v and d_v in (64, 128) else "segment_flash"
+    if L <= PLAIN_MAX_L and (segmented or d_qk == d_v):
+        return "plain"
+    raise ValueError(
+        f"no attention kernel on the TPU for L={L}, d_qk={d_qk}, d_v={d_v}: "
+        f"pad the stream to a multiple of 128 (the plain path builds "
+        f"[B, H, L, L] and is held to L <= {PLAIN_MAX_L})")
+
+
 def flash_attention(q, k, v, *, causal: bool = False, block_size: int = 1024,
                     segment_ids=None):
-    """Best-available single-device attention for [B, L, H, D]: the stock
-    Pallas TPU flash kernel (jax.experimental.pallas.ops.tpu) when on TPU
-    and the shape fits its tiling, else ``blockwise_attention``. The
-    Pallas kernel fuses the whole softmax-accumulate into one Mosaic
-    program (against blockwise: not measured on the chip); NOTE its
-    ``sm_scale`` defaults to 1.0, so the 1/sqrt(D) scale must be passed
-    explicitly. ``segment_ids`` (int [B, L]) keeps attention inside a
-    segment: histories packed one after another in a row; off the TPU
-    that is ``segment_attention``."""
+    """Best-available single-device attention for q, k [B, L, H, D_qk]
+    and v [B, L, H, D_v]; ``attention_kernel_for`` names the choice and
+    is the one place it is made. The stock Pallas kernel fuses the whole
+    softmax-accumulate into one Mosaic program (against blockwise: not
+    measured on the chip); NOTE its ``sm_scale`` defaults to 1.0, so the
+    1/sqrt(D) scale must be passed explicitly. ``segment_ids`` (int
+    [B, L]) keeps attention inside a segment: histories packed one after
+    another in a row. Head sizes the stock kernel does not take (not 64
+    or 128, or d_qk != d_v) run ``segment_flash_attention`` on the TPU."""
     import jax
+    import jax.numpy as jnp
 
     B, L, H, D = q.shape
-    if jax.default_backend() == "tpu" and L % 128 == 0 and D in (64, 128):
+    Dv = v.shape[-1]
+    which = attention_kernel_for(L, D, Dv, backend=jax.default_backend(),
+                                 segmented=segment_ids is not None)
+    if which == "stock":
         return _stock_flash(q, k, v, causal, segment_ids)
-    if segment_ids is not None:
-        return segment_attention(q, k, v, segment_ids, causal=causal)
+    if which == "segment_flash":
+        seg = (jnp.ones((B, L), jnp.int32) if segment_ids is None
+               else segment_ids)
+        t = lambda x: jnp.transpose(x, (0, 2, 1, 3))  # noqa: E731
+        out, _pairs = segment_flash_attention(
+            _lane_parts(t(q)), _lane_parts(t(k)), t(v), seg,
+            scale=1.0 / (D ** 0.5), causal=causal)
+        return t(out).astype(q.dtype)
+    if segment_ids is not None or D != Dv:
+        seg = (jnp.ones((B, L), jnp.int32) if segment_ids is None
+               else segment_ids)
+        return segment_attention(q, k, v, seg, causal=causal)
     return blockwise_attention(q, k, v, causal=causal, block_size=block_size)
+
+
+def _lane_parts(x):
+    """[B, H, L, D] as the parts a kernel contracts one by one: whole
+    128-lane groups, then what is left (192 -> 128 + 64)."""
+    D = x.shape[-1]
+    whole = D // 128 * 128
+    if whole in (0, D):
+        return (x,)
+    return (x[..., :whole], x[..., whole:])
+
+
+#: Rows and columns of one grid step of ``segment_flash_attention``.
+SEGMENT_FLASH_BLOCK = 512
+
+
+def _block_pairs(segment_ids, block: int, causal: bool):
+    """The (query block, key block) pairs whose segments can meet, in
+    query-major order, as the tables the kernel's index maps read:
+    (q_of, k_of, first, last int32 [B, S], count int32 [B]); S is the
+    static most (every pair of the triangle). Two blocks can meet where
+    their ranges of segment ids overlap (and, causal, the key block is
+    not after the query block): exact for ids that never decrease along
+    the stream (packed histories, the padding's 0 last or first), only
+    a superset otherwise, which the mask inside the kernel settles."""
+    import jax.numpy as jnp
+
+    B, L = segment_ids.shape
+    n = L // block
+    blocks = segment_ids.reshape(B, n, block)
+    lo, hi = blocks.min(-1), blocks.max(-1)                       # [B, n]
+    meet = (hi[:, None, :] >= lo[:, :, None]) & (lo[:, None, :]
+                                                 <= hi[:, :, None])
+    if causal:
+        meet &= jnp.tril(jnp.ones((n, n), bool))[None]
+    size = n * (n + 1) // 2 if causal else n * n
+    flat = meet.reshape(B, n * n)
+    count = flat.sum(-1).astype(jnp.int32)
+    # the positions of the pairs that run, ascending: stable sort of
+    # "does not run" keeps the running ones first, in order
+    order = jnp.argsort(~flat, axis=-1, stable=True)[:, :size].astype(
+        jnp.int32)
+    valid = jnp.arange(size)[None, :] < count[:, None]
+    last_valid = jnp.take_along_axis(
+        order, jnp.maximum(count - 1, 0)[:, None], axis=-1)
+    order = jnp.where(valid, order, last_valid)  # no new block to fetch
+    q_of, k_of = order // n, order % n
+    prev_q = jnp.concatenate([jnp.full((B, 1), -1, jnp.int32),
+                              q_of[:, :-1]], -1)
+    next_q = jnp.concatenate([q_of[:, 1:],
+                              jnp.full((B, 1), -1, jnp.int32)], -1)
+    step = jnp.arange(size)[None, :]
+    first = valid & (q_of != prev_q)
+    last = valid & ((q_of != next_q) | (step == count[:, None] - 1))
+    return (q_of, k_of, first.astype(jnp.int32), last.astype(jnp.int32),
+            valid.astype(jnp.int32), count)
+
+
+def _segment_flash_kernel(q_of, k_of, first, last, valid, *refs, n_parts,
+                          scale, causal, block):
+    """One (query block, key block) pair of one head: the flash-softmax
+    carry (m, l, acc in VMEM scratch) over the pairs of a query block,
+    which the tables list one after another. refs: q parts, k parts, v,
+    the query block's segment ids [block, 1], the key block's [1, block];
+    then o, the count of unmasked pairs (SMEM, head 0 alone counts: the
+    mask is every head's); then the scratch."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    q_refs, k_refs = refs[:n_parts], refs[n_parts:2 * n_parts]
+    v_ref, qseg_ref, kseg_ref, o_ref, cnt_ref, m_s, l_s, acc_s = refs[
+        2 * n_parts:]
+    b, h, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    f32 = jnp.float32
+
+    @pl.when((b == 0) & (h == 0) & (s == 0))
+    def _zero_count():
+        cnt_ref[0] = 0
+
+    @pl.when(first[b, s] == 1)
+    def _start():
+        m_s[...] = jnp.full(m_s.shape, _NEG, f32)
+        l_s[...] = jnp.zeros(l_s.shape, f32)
+        acc_s[...] = jnp.zeros(acc_s.shape, f32)
+
+    @pl.when(valid[b, s] == 1)
+    def _pair():
+        sc = None
+        for q_ref, k_ref in zip(q_refs, k_refs):
+            part = jax.lax.dot_general(
+                q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+                preferred_element_type=f32)
+            sc = part if sc is None else sc + part
+        sc = sc * scale
+        mask = qseg_ref[0] == kseg_ref[0]                 # [block, block]
+        if causal:
+            rows = q_of[b, s] * block + jax.lax.broadcasted_iota(
+                jnp.int32, (block, block), 0)
+            cols = k_of[b, s] * block + jax.lax.broadcasted_iota(
+                jnp.int32, (block, block), 1)
+            mask &= cols <= rows
+        sc = jnp.where(mask, sc, _NEG)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a row with nothing unmasked yet keeps m = _NEG and gathers
+        # exp(0) here; its own diagonal, which every row has and which
+        # comes last, multiplies that away (alpha = exp(_NEG - m) = 0)
+        p = jnp.exp(sc - m_new)
+        l_s[...] = alpha * l_s[...] + p.sum(axis=1, keepdims=True)
+        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+            preferred_element_type=f32)
+        m_s[...] = m_new
+
+        @pl.when(h == 0)
+        def _count():
+            cnt_ref[0] += jnp.sum(mask.astype(jnp.int32))
+
+    @pl.when(last[b, s] == 1)
+    def _store():
+        o_ref[0, 0] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
+
+
+def segment_flash_attention(q_parts, k_parts, v, segment_ids, *, scale,
+                            causal: bool = True,
+                            block: int = SEGMENT_FLASH_BLOCK,
+                            interpret: bool | None = None):
+    """Flash attention in which the scores are a SUM of contractions and
+    the value's head size is its own: q_parts[i] [B, H, L, d_i] against
+    k_parts[i] [B, H or 1, L, d_i] (a part with ONE key head is shared
+    by every query head: latent attention's rotary key), v [B, H, L,
+    d_v], ``segment_ids`` int32 [B, L]. A position sees the positions of
+    its own segment (causal: those not after it). Only the block pairs
+    whose segments can meet are grid steps at all (``_block_pairs``): a
+    stream of packed histories costs its histories' triangles, not the
+    stream's. Returns (out [B, H, L, d_v] in v's dtype, the count of
+    unmasked (query, key) pairs, int32: what the kernel's own mask let
+    through, counted once, not once a head).
+
+    L must be a multiple of 128; the block is the largest of ``block``,
+    256, 128 that divides it. ``interpret`` defaults to "not on a TPU"."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, L, d_v = v.shape
+    if L % 128:
+        raise ValueError(f"segment_flash_attention needs L % 128 == 0, "
+                         f"got {L}")
+    block = next(b for b in (block, 256, 128) if b <= block and L % b == 0)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    seg = segment_ids.astype(jnp.int32)
+    q_of, k_of, first, last, valid, count = _block_pairs(seg, block, causal)
+    steps = count.max()
+    n_parts = len(q_parts)
+
+    def q_map(b, h, s, q_of, k_of, *_):
+        return b, h, q_of[b, s], 0
+
+    def k_map(shared):
+        def index(b, h, s, q_of, k_of, *_):
+            return b, (0 if shared else h), k_of[b, s], 0
+        return index
+
+    in_specs = [pl.BlockSpec((1, 1, block, x.shape[-1]), q_map)
+                for x in q_parts]
+    in_specs += [pl.BlockSpec((1, 1, block, x.shape[-1]),
+                              k_map(x.shape[1] == 1)) for x in k_parts]
+    in_specs += [
+        pl.BlockSpec((1, 1, block, d_v), k_map(False)),
+        pl.BlockSpec((1, block, 1),
+                     lambda b, h, s, q_of, k_of, *_: (b, q_of[b, s], 0)),
+        pl.BlockSpec((1, 1, block),
+                     lambda b, h, s, q_of, k_of, *_: (b, 0, k_of[b, s])),
+    ]
+    kernel = functools.partial(_segment_flash_kernel, n_parts=n_parts,
+                               scale=float(scale), causal=causal,
+                               block=block)
+    out, pairs = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, block, d_v), q_map),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+            ],
+            grid=(B, H, steps),
+            scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32),
+                            pltpu.VMEM((block, 1), jnp.float32),
+                            pltpu.VMEM((block, d_v), jnp.float32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, H, L, d_v), v.dtype),
+                   jax.ShapeDtypeStruct((1,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="segment_flash_attention",
+    )(q_of, k_of, first, last, valid, *q_parts, *k_parts, v,
+      seg[:, :, None], seg[:, None, :])
+    return out, pairs[0]
 
 
 #: Rows and columns of one grid step of the stock kernel where the stream

@@ -526,7 +526,8 @@ def prepare_deploy(
         else:
             models.append(m)
     return TrainResult(models=models, algorithms=algos, serving=serving,
-                       algorithm_names=names)
+                       algorithm_names=names,
+                       blob_checksum=blob.checksum or None)
 
 
 def engine_params_from_instance(engine: Engine, instance: EngineInstance) -> EngineParams:

@@ -208,15 +208,18 @@ class Deployed:
         # XLA elsewhere). Building the retriever on the NEW bundle before
         # the swap is the double-buffered /reload: the old bundle keeps
         # serving until this one is fully on-device.
-        try:
-            # the blob a second time (prepare_deploy let go of its own),
-            # for its checksum alone
-            with span("deploy.blob_read", sink=STARTUP.phase,
-                      reason="provenance"):
-                blob = Storage.get_models().get(self.instance.id)
-            self.blob_sha = getattr(blob, "checksum", None)
-        except Exception:  # noqa: BLE001 — provenance is best-effort
-            self.blob_sha = None
+        # the bundle's provenance: the checksum `prepare_deploy` verified
+        # and kept; only a result made another way (a test, a retrain)
+        # costs a read of the blob for it
+        self.blob_sha = getattr(self.result, "blob_checksum", None)
+        if self.blob_sha is None:
+            try:
+                with span("deploy.blob_read", sink=STARTUP.phase,
+                          reason="provenance"):
+                    blob = Storage.get_models().get(self.instance.id)
+                self.blob_sha = getattr(blob, "checksum", None)
+            except Exception:  # noqa: BLE001 — provenance is best-effort
+                self.blob_sha = None
 
         mode = str((self.retrieval or {}).get("mode", "exact")).lower()
         for model in self.result.models:

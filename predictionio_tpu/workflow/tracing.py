@@ -21,7 +21,7 @@ import contextlib
 import json
 import logging
 
-from ..obs.trace import span
+from ..obs.trace import DEVICE_SCOPES, span
 
 log = logging.getLogger("predictionio_tpu.workflow")
 
@@ -80,7 +80,9 @@ def maybe_profile(trace_dir: str | None):
     by the program's own spans (``pio.*``, docs/operations.md). The
     region sits inside one ``pio.profile.window`` annotation, entered
     once the profiler has started and left before it is stopped, so a
-    reader can cut the profiler's own start and stop stalls away."""
+    reader can cut the profiler's own start and stop stalls away. The
+    operation-to-scope map of the programs compiled so far goes beside
+    the capture (``pio_scopes.json``; obs/trace.py says why)."""
     if not trace_dir:
         yield
         return
@@ -95,5 +97,6 @@ def maybe_profile(trace_dir: str | None):
             yield
     finally:
         jax.profiler.stop_trace()
+        DEVICE_SCOPES.dump(trace_dir)
     log.info("profiler trace written to %s (open with TensorBoard/XProf)",
              trace_dir)

@@ -9,7 +9,7 @@ events become per-user time-ordered item histories; a causal-attention
 model (models/seq_attention.py) predicts the next item; histories longer
 than one chip shard over a ``seq`` mesh axis via ring attention.
 
-Two algorithms, one serving route (models/seq_serving.py: `pio deploy` ->
+Three algorithms, one serving route (models/seq_serving.py: `pio deploy` ->
 micro-batcher -> serving pipeline -> the retriever's fused top-k):
 
 - ``seqrec``: the SASRec-style model above, learned positions, histories
@@ -31,6 +31,30 @@ micro-batcher -> serving pipeline -> the retriever's fused top-k):
   (the item table stands in for ``vocab_size``; ``max_len`` is the most
   events of a history that are kept; training at those widths does not
   fit one chip, serving does: PERF.md).
+- ``latent_moe``: a latent-attention mixture-of-experts decoder
+  (models/latent_moe_lm.py; the DeepSeek-V2/V3 family's block as A.X-K1
+  publishes it): low-rank query and key/value bottlenecks, one rotary
+  key shared by all heads, YaRN, a leading dense layer, then routed
+  experts beside a shared one. Its params are the published config's
+  keys plus the part of a deployment this process holds:
+  ``first_layer`` and ``num_hidden_layers`` (the pipeline stage),
+  ``first_expert`` and ``experts_held`` (the block of every routed
+  layer's ``n_routed_experts`` that lives here; the router keeps its
+  width and ``num_experts_per_tok``, and what the absent experts would
+  add is left out). A serving step holds up to 8,192 packed tokens and
+  ``max_len`` may be as long; ``exclude_seen`` is off by default and
+  needs ``max_len`` <= 512 (the head's top-k keeps at most 528):
+
+      "algorithms": [{"name": "latent_moe", "params": {
+          "hidden_size": 7168, "intermediate_size": 18432,
+          "moe_intermediate_size": 2048, "num_attention_heads": 64,
+          "q_lora_rank": 1536, "kv_lora_rank": 512,
+          "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+          "v_head_dim": 128, "n_routed_experts": 192,
+          "n_shared_experts": 1, "num_experts_per_tok": 8,
+          "first_k_dense_replace": 1, "routed_scaling_factor": 2.5,
+          "first_layer": 0, "num_hidden_layers": 5,
+          "first_expert": 0, "experts_held": 12, "max_len": 8192}}]
 
 Query:  {"user": "u1", "num": 4}
 Result: {"itemScores": [{"item": "i1", "score": 3.2}, ...]}
@@ -51,6 +75,11 @@ from predictionio_tpu.controller import (
     Params,
     Preparator,
     SanityCheck,
+)
+from predictionio_tpu.models.latent_moe_lm import (
+    LatentMoEConfig,
+    LatentMoEModel,
+    train_latent_moe,
 )
 from predictionio_tpu.models.looped_lm import (
     LoopedLMConfig,
@@ -100,6 +129,47 @@ class LoopedParams(Params):
     compute_dtype: str = "bfloat16"
     epochs: int = 10
     batch_size: int = 64
+    lr: float = 1e-3
+    seed: int = 0
+
+
+@dataclass(frozen=True)
+class LatentMoEParams(Params):
+    """The published config's keys, the share held here, then serving
+    and training: models/latent_moe_lm.py's ``LatentMoEConfig``, whose
+    defaults are A.X-K1's."""
+    hidden_size: int = 7168
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    num_attention_heads: int = 64
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 192
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    first_k_dense_replace: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    scoring_func: str = "sigmoid"
+    topk_method: str = "none"
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_scaling: dict = dataclasses.field(default_factory=lambda: {
+        "type": "yarn", "factor": 32,
+        "original_max_position_embeddings": 4096, "beta_fast": 32,
+        "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1})
+    first_layer: int = 0
+    num_hidden_layers: int = 5
+    first_expert: int = 0
+    experts_held: int = 12
+    max_len: int = 8192
+    exclude_seen: bool = False
+    compute_dtype: str = "bfloat16"
+    epochs: int = 10
+    batch_size: int = 16
     lr: float = 1e-3
     seed: int = 0
 
@@ -210,11 +280,26 @@ class LoopedAlgorithm(SeqRecAlgorithm):
         return train_looped_lm(seqs, uids, iids, cfg, mesh=ctx.mesh)
 
 
+class LatentMoEAlgorithm(SeqRecAlgorithm):
+    """The latent-attention mixture-of-experts decoder behind the same
+    queries and the same route."""
+
+    params_class = LatentMoEParams
+
+    def train(self, ctx, td: TrainingData) -> LatentMoEModel:
+        cfg = LatentMoEConfig(**dataclasses.asdict(self.params))
+        seqs, uids, iids = build_sequences(
+            td.users, td.items, td.times, max_len=cfg.max_len
+        )
+        return train_latent_moe(seqs, uids, iids, cfg, mesh=ctx.mesh)
+
+
 def engine_factory() -> Engine:
     return Engine(
         data_source_classes=SeqDataSource,
         preparator_classes=SeqPreparator,
         algorithm_classes={"seqrec": SeqRecAlgorithm,
-                           "looped": LoopedAlgorithm},
+                           "looped": LoopedAlgorithm,
+                           "latent_moe": LatentMoEAlgorithm},
         serving_classes=FirstServing,
     )

@@ -362,3 +362,144 @@ def test_the_detector_finds_what_the_flat_store_compiled_to():
         "  %fusion.86 = bf16[1024,5632]{1,0:T(8,128)(2,1)S(1)}"
         " fusion(%fusion.85), kind=kLoop, calls=%fused.86\n") == [
             "%constant_dynamic-slice_fusion.16", "%copy.20", "%copy.23"]
+
+
+# ---------------------------------------------------------------------------
+# the latent-attention mixture-of-experts cell
+# (benchmarks/configs/axk1-seqrec.json): the attention kernel at d_qk 192 /
+# d_v 128, the head at rank 7168, and the serving step, shapes only
+
+AXK1_ITEMS, AXK1_RANK = 20_479, 7168
+
+
+def test_segment_flash_kernel_at_the_latent_heads(v5e):
+    """64 heads of 128 + 64 (the rotary key ONE head, shared) against
+    values of 128 over a stream of 8,192 packed tokens: one Mosaic
+    kernel whose grid is the block pairs that meet, sized on the device
+    (a dynamic grid), and the count of unmasked pairs beside the
+    output."""
+    from predictionio_tpu.parallel.ring_attention import (
+        segment_flash_attention)
+
+    one = SingleDeviceSharding(v5e[0])
+    H, L = 64, 8192
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def fn(q_nope, q_rope, k_nope, k_rope, v, seg):
+        return segment_flash_attention((q_nope, q_rope), (k_nope, k_rope), v,
+                                       seg, scale=0.13, interpret=False)
+
+    exe = jax.jit(fn).lower(
+        arg(1, H, L, 128), arg(1, H, L, 64), arg(1, H, L, 128),
+        arg(1, 1, L, 64), arg(1, H, L, 128), arg(1, L, dtype=jnp.int32)
+    ).compile()
+    assert exe.as_text().count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert exe.memory_analysis().output_size_in_bytes >= H * L * 128 * 2
+
+
+def test_flash_attention_runs_the_new_kernel_where_the_stock_one_cannot(
+        v5e, monkeypatch):
+    """The explicit choice: on a TPU, q and k of 192 against v of 128 (or
+    any head size the stock kernel refuses) compile to the segment flash
+    kernel, never to the path that builds [B, H, L, L]."""
+    from predictionio_tpu.parallel.ring_attention import flash_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e[0])
+    qk = jax.ShapeDtypeStruct((1, 1024, 4, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((1, 1024, 4, 128), jnp.bfloat16, sharding=one)
+    seg = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=one)
+
+    def fn(q, k, v, seg):
+        # the kernel's default is interpret off a TPU: here the backend is
+        # only SAID to be one, which is what the program would see on it
+        return flash_attention(q, k, v, causal=True, segment_ids=seg)
+
+    hlo = jax.jit(fn).lower(qk, qk, v, seg).compile().as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "f32[1,4,1024,1024]" not in hlo  # no [B, H, L, L] scores
+
+
+@pytest.mark.parametrize("b_pad", [8, 128])
+def test_topk_kernel_at_the_latent_decoders_head(v5e, b_pad):
+    """[B, 7168] x [7168, 20,480] + top-k at k 16: two buffers of a
+    256-item tile would be 14.7 MB, over the 12 MiB budget, so the tile
+    is 128 items (7.3 MB); alone and in the fused program over a step's
+    query table of 8,192 + 8 rows."""
+    from predictionio_tpu.models.latent_moe_lm import STEP_TOKEN_BUDGET
+
+    d_pad, n_pad = _padded_shape(AXK1_ITEMS, AXK1_RANK)
+    assert (d_pad, n_pad) == (7168, 20_480)
+    tile, chunk = _tile_rows(b_pad, d_pad, 16, n_pad)
+    assert tile == 128 and chunk == 128
+    _compile_kernel(v5e[0], AXK1_ITEMS, b_pad, 16, rank=AXK1_RANK)
+    one = SingleDeviceSharding(v5e[0])
+    raw = _raw_call(b_pad, d_pad, n_pad, AXK1_ITEMS, 16, False)
+    exe = jax.jit(_fused_fn(raw, True), donate_argnums=(0,)).lower(
+        jax.ShapeDtypeStruct((b_pad,), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((STEP_TOKEN_BUDGET + 8, 7168), jnp.float32,
+                             sharding=one),
+        jax.ShapeDtypeStruct((d_pad, n_pad), jnp.float32, sharding=one),
+    ).compile()
+    _assert_one_kernel_and_the_packed_result(exe, b_pad, 16)
+
+
+def test_latent_moe_serving_step_at_its_budget(v5e):
+    """The encoder executable of `pio deploy` at A.X-K1's published
+    widths for a full step of 8,192 tokens, from the tree
+    `LatentMoEEncoder` puts on the device: five attention kernels (one a
+    layer), three grouped matmuls a routed layer inside ONE `while` a
+    routed layer (the passes over the sorted rows), the share's 6.4 GB
+    of bfloat16 weights and the embedding as arguments, a step's query
+    table and the four counters out, and temporaries that leave the chip
+    room (weights 6.7 + head 0.6 + these under 16 GiB)."""
+    import re
+
+    from predictionio_tpu.models import latent_moe_lm as lm
+    from predictionio_tpu.obs.trace import DeviceScopes
+    from predictionio_tpu.ops.pipeline import _encoder_fn
+
+    one = SingleDeviceSharding(v5e[0])
+    cfg = lm.LatentMoEConfig()
+    shapes = lm.param_shapes(cfg, AXK1_ITEMS + 1)
+
+    def arg(name, shape):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.float32 if lm._is_float32_leaf(name) else jnp.bfloat16,
+            sharding=one)
+
+    public = {"embed": arg("embed", shapes["embed"]),
+              "norm_f": arg("norm_f", shapes["norm_f"]),
+              "layers": {i: {k: arg(k, s) for k, s in layer.items()}
+                         for i, layer in shapes["layers"].items()}}
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        jax.eval_shape(lambda p: lm.device_tree(p, cfg), public))
+    t_pad = lm.STEP_TOKEN_BUDGET
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        exe = jax.jit(_encoder_fn(lm.encoder_program(cfg), t_pad + 8, 7168)
+                      ).lower(
+            jax.ShapeDtypeStruct((3, t_pad), jnp.int32, sharding=one),
+            params).compile()
+    hlo = exe.as_text()
+    kernels = re.findall(
+        r"^\s*(%[\w.\-]+) = .* custom-call\(.*tpu_custom_call", hlo, re.M)
+    assert sum(k.startswith("%segment_flash_attention") for k in kernels) == 5
+    assert sum(k.startswith("%gmm") for k in kernels) == 3 * 4
+    mem = exe.memory_analysis()
+    assert 6.6e9 < mem.argument_size_in_bytes < 6.8e9
+    assert mem.output_size_in_bytes >= (t_pad + 8) * 7168 * 4
+    assert mem.temp_size_in_bytes < 4e9
+    # the capture's operation-to-scope map names the step's operations
+    scopes = DeviceScopes()
+    assert scopes.record(hlo) > 500
+    named = set(scopes.snapshot().values())
+    assert {"pio.seq.embed", "pio.seq.latent_proj", "pio.seq.latent_attn",
+            "pio.seq.router", "pio.seq.experts", "pio.seq.experts.matmul",
+            "pio.seq.shared_expert", "pio.seq.dense_mlp"} <= named
+    # a container's time is its body's: none is named
+    assert not any(k.endswith((" while", " conditional", " call"))
+                   for k in scopes.snapshot())
