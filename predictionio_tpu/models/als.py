@@ -583,10 +583,16 @@ def _spd_solve(a, b, *, solver="cg", cg_iters=DEFAULT_CG_ITERS,
 
 
 def _gram_blocks(ids, vals, other_c, *, implicit, alpha, rank, masked=False,
-                 out_dtype=None, with_diag=False):
+                 out_dtype=None, with_diag=False, hot=None):
     """Partial normal equations for every block row, NO regularization.
 
     ids/vals: [NB, B, D]; other_c: [NO, R] already in compute dtype.
+    ``hot`` = (slice [H, R] of other_c, ids [NB, B, Dh] local to it, vals
+    [NB, B, Dh]) is a split bucket's hot part (ops/neighbors._split_hot):
+    the block row's equations are the sum of both parts', each part
+    gathered from its own table: a row from a table of up to 147,456
+    rows costs a third of one from a larger table (the probe beside
+    ``neighbors.GATHER_NS_BY_TABLE_ROWS``).
     Returns (a [NB, B, R, R] out_dtype (default f32), b [NB, B, R] f32,
     n [NB, B] f32[, d [NB, B, R] f32 — a's f32 diagonal, when
     ``with_diag``]). The cast and diagonal ride INSIDE the lax.map body:
@@ -620,10 +626,9 @@ def _gram_blocks(ids, vals, other_c, *, implicit, alpha, rank, masked=False,
     odt = out_dtype or f32
     eye = jnp.eye(rank, dtype=f32)
 
-    def gram_block(blk):
-        b_ids, b_vals = blk
+    def part(table, b_ids, b_vals):
         valid = b_vals != 0  # [B, D] — padded slots are exactly 0
-        f = other_c[b_ids]  # [B, D, R] gather — bf16 halves this traffic
+        f = table[b_ids]  # [B, D, R] gather — bf16 halves this traffic
         if masked:
             f = f * valid.astype(cdt)[..., None]
         vals_f32 = b_vals.astype(f32)
@@ -643,12 +648,28 @@ def _gram_blocks(ids, vals, other_c, *, implicit, alpha, rank, masked=False,
             a = jnp.einsum("bdr,bds->brs", f, f, preferred_element_type=f32)
             b = jnp.einsum("bd,bdr->br", b_vals.astype(cdt), f,
                            preferred_element_type=f32)
+        return a, b, n
+
+    def gram_block(blk):
+        if hot is not None:
+            # the hot part FIRST: only then does the compiler keep the
+            # slice in VMEM across the loop (its operand of the gather
+            # reads `S(1)`; tests/test_tpu_compile.py pins it), which is
+            # what makes its rows cheap. Two einsums summed, not one over
+            # the concatenated gathers: the probe read the sum faster at
+            # every shape it tried
+            a, b, n = part(hot[0], blk[2], blk[3])
+            a_c, b_c, n_c = part(other_c, blk[0], blk[1])
+            a, b, n = a + a_c, b + b_c, n + n_c
+        else:
+            a, b, n = part(other_c, blk[0], blk[1])
         out = (a.astype(odt), b, n)
         if with_diag:
             out = out + ((a * eye[None]).sum(-1),)
         return out
 
-    return jax.lax.map(gram_block, (ids, vals))
+    xs = (ids, vals) if hot is None else (ids, vals, hot[1], hot[2])
+    return jax.lax.map(gram_block, xs)
 
 
 # NOTE on a road not taken: a fused Pallas gramian kernel (per-row
@@ -657,8 +678,11 @@ def _gram_blocks(ids, vals, other_c, *, implicit, alpha, rank, masked=False,
 # [8192,176,64] block — Mosaic serializes the per-row MXU dots, and
 # dot_general with batch dims hits a lowering bug in this jaxlib), and
 # Mosaic's dynamic-gather lowering cannot express the [NO,R] row gather
-# at all. The einsum path below IS the fast path; the step's floor is the
-# XLA gather itself, which reads a full (8,128) tile per gathered row.
+# at all. The einsum path IS the fast path; the step's floor is the XLA
+# gather itself, and its cost is per gathered ROW, not per byte or tile:
+# 10-12 ns a row from a table of 163,840 rows or more, 4 ns from a
+# smaller one, whatever the ids (the probe of PR 40 beside
+# ops/neighbors.GATHER_NS_BY_TABLE_ROWS). Hence the hot slice.
 
 
 def _ridge(other_c, n, *, lambda_, implicit):
@@ -741,8 +765,8 @@ def _half_step(ids, vals, other, *, lambda_, implicit, alpha, rank,
 
 def put_layout(layout, mesh, *, vals_dtype=None):
     """Device-put one side of the permuted layout: neighbor block rows
-    sharded over the data AND model axes combined, chunk segment ids
-    replicated. No mask upload —
+    (a split bucket's hot part with them) sharded over the data AND model
+    axes combined, chunk segment ids replicated. No mask upload —
     validity is encoded in vals, and padded ids point at the other side's
     zero slot (ops/neighbors.py). ``vals_dtype=bfloat16`` halves the
     ratings' transfer + HBM footprint (exact for half-star ratings;
@@ -766,15 +790,21 @@ def put_layout(layout, mesh, *, vals_dtype=None):
             sharding, _process_local_slice(arr, sharding),
             global_shape=arr.shape)
 
+    def cast(vals):
+        if vals_dtype is None:
+            return vals
+        import ml_dtypes
+
+        return vals.astype(ml_dtypes.bfloat16 if vals_dtype == "bfloat16"
+                           else vals_dtype)
+
     out = []
     for b, m in zip(layout.buckets, layout.metas):
-        vals = b.vals
-        if vals_dtype is not None:
-            import ml_dtypes
-
-            dt = ml_dtypes.bfloat16 if vals_dtype == "bfloat16" else vals_dtype
-            vals = vals.astype(dt)
+        vals = cast(b.vals)
         e = {"ids": put(b.ids, blk), "vals": put(vals, blk)}
+        if b.hot_ids is not None:
+            e["hot_ids"] = put(b.hot_ids, blk)
+            e["hot_vals"] = put(cast(b.hot_vals), blk)
         if m.seg is not None:
             e["seg"] = put(m.seg, rep)
         out.append(e)
@@ -878,17 +908,40 @@ def _solve_side(buckets, layout, other, *, kw, x0=None):
     other_c = other.astype(cdt)
     f32 = jnp.float32
 
+    order = []  # a zero that is known only once the last piece is solved
+
+    def hot_part(b):
+        """A split bucket's (slice of the other side's factors, ids,
+        vals). The slice is taken anew for every `lax.map` (under
+        model_sharded from the replicated factors, after the one
+        all-gather), 38 MB copied: a buffer that lives from here to its
+        loop's end the compiler places in VMEM, which is what makes a
+        hot row cheap; one slice shared by a half-step's loops lives
+        across all of them and stays in HBM for most of them, and so
+        does a slice the scheduler is free to take while the loop
+        before still holds its own (read off the step compiled at the
+        train cell's shapes, `PERF.md` section 6, PR 40;
+        tests/test_tpu_compile.py pins one loop's). So the start is the
+        layout's plus ``order``'s zero, which the compiler cannot
+        prove and cannot have before the piece before is solved."""
+        if "hot_ids" not in b:
+            return None
+        start = layout.hot_lo + (order[-1] if order else 0)
+        return (jax.lax.dynamic_slice_in_dim(other_c, start, layout.hot_rows),
+                b["hot_ids"], b["hot_vals"])
+
     def tier_equations(b, m):
         """One tier's regularization-free normal equations
         (pa [span, R, R] cdt, pb [span, R] f32, pn [span] f32,
         pd [span, R] f32)."""
         chunked = m.seg is not None
+        hot = hot_part(b)
         if chunked:
             # partial gramians stay f32 through the per-owner sums so the
             # chunk accumulation doesn't round at bf16
             pa, pb, pn = _gram_blocks(b["ids"], b["vals"], other_c,
                                       implicit=implicit, alpha=kw["alpha"],
-                                      rank=rank)
+                                      rank=rank, hot=hot)
             seg = b["seg"]
             pa = jax.ops.segment_sum(pa.reshape(-1, rank, rank), seg,
                                      num_segments=m.span,
@@ -905,7 +958,7 @@ def _solve_side(buckets, layout, other, *, kw, x0=None):
             pa, pb, pn, pd = _gram_blocks(b["ids"], b["vals"], other_c,
                                           implicit=implicit, alpha=kw["alpha"],
                                           rank=rank, out_dtype=cdt,
-                                          with_diag=True)
+                                          with_diag=True, hot=hot)
             pa = pa.reshape(-1, rank, rank)
             pb = pb.reshape(-1, rank)
             pn = pn.reshape(-1)
@@ -915,9 +968,15 @@ def _solve_side(buckets, layout, other, *, kw, x0=None):
     def tier_solve(pa, pb, pn, pd, x0_t):
         shift, gram = _ridge(other_c, pn, lambda_=kw["lambda_"],
                              implicit=implicit)
-        return _spd_solve(pa, pb, solver=kw["solver"],
-                          cg_iters=kw["cg_iters"], matvec_dtype=cdt,
-                          shift=shift, gram=gram, diag=pd, x0=x0_t)
+        x = _spd_solve(pa, pb, solver=kw["solver"],
+                       cg_iters=kw["cg_iters"], matvec_dtype=cdt,
+                       shift=shift, gram=gram, diag=pd, x0=x0_t)
+        if layout.hot_rows:
+            # a row's count of entries is never negative
+            x, zero = jax.lax.optimization_barrier(
+                (x, jnp.minimum(pn[0].astype(jnp.int32), 0)))
+            order.append(zero)
+        return x
 
     covered = sum(m.span for m in layout.metas)
     eq_bytes = covered * rank * rank * jnp.dtype(cdt).itemsize
@@ -956,7 +1015,7 @@ def _solve_side(buckets, layout, other, *, kw, x0=None):
             nb, blk = b["ids"].shape[:2]
             g = max(1, rows_budget // blk)  # blocks per solve group
             for s in range(0, nb, g):
-                sub = {"ids": b["ids"][s:s + g], "vals": b["vals"][s:s + g]}
+                sub = {k: v[s:s + g] for k, v in b.items()}
                 rows = int(sub["ids"].shape[0]) * blk
                 pa, pb, pn, pd = tier_equations(sub, m)
                 xs.append(tier_solve(
@@ -1144,6 +1203,14 @@ def train_als(ratings: Ratings, config: ALSConfig, mesh=None, *,
         if dropped:
             log.info("degree tiers dropped %d entries beyond the last tier",
                      dropped)
+        # what the layout says an iteration gathers, padding included, and
+        # how much of it from the two hot slices (0 rows: that table is
+        # not sliced): how often the split engages, known before any step
+        (u_rows, u_hot), (i_rows, i_hot) = u_lay.gather_rows, i_lay.gather_rows
+        TRAINING.note(
+            "train", gatherRowsPerIteration=u_rows + i_rows,
+            hotGatherShare=100.0 * (u_hot + i_hot) / max(1, u_rows + i_rows),
+            hotSliceRowsItems=u_lay.hot_rows, hotSliceRowsUsers=i_lay.hot_rows)
         # factor matrices live in PERMUTED slot order during training
         # (tier-concatenation order, SideLayout.pos maps true rows to
         # slots); slot counts are 8-aligned so rows shard evenly over the

@@ -25,6 +25,7 @@ import numpy as np
 __all__ = [
     "available",
     "counting_argsort",
+    "hot_split_native",
     "neighbor_blocks_native",
     "hash64_batch",
     "scan_jsonl",
@@ -145,6 +146,12 @@ def _load() -> ctypes.CDLL | None:
         lib.pio_counting_argsort_i32.argtypes = [
             i32p, ctypes.c_int64, ctypes.c_int64, i64p,
         ]
+        lib.pio_hot_split.restype = ctypes.c_int64
+        lib.pio_hot_split.argtypes = [
+            i32p, f32p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
+            ctypes.c_int32, ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
+            ctypes.c_int32, i32p, f32p, i32p, f32p,
+        ]
         _lib = lib
         return _lib
 
@@ -186,6 +193,33 @@ def neighbor_blocks_native(
     if dropped < 0:
         raise ValueError("pio_neighbor_blocks: invalid input")
     return ids, vv, mask, int(dropped)
+
+
+def hot_split_native(ids: np.ndarray, vals: np.ndarray, lo: int, hi: int,
+                     d_hot: int, d_cold: int, hot_pad: int, cold_pad: int):
+    """Split built block arrays ``ids``/``vals`` ([rows, d]; a padded slot
+    has vals 0) into a hot part ([rows, d_hot], ids local to the slice
+    [lo, hi)) and a cold part ([rows, d_cold]), each padded with its own
+    id; see ``ops/neighbors._split_hot`` for the rule. Returns (hot_ids,
+    hot_vals, cold_ids, cold_vals, the most cold entries a row holds): the
+    parts are whole only where that is at most ``d_cold``. None if
+    unavailable."""
+    lib = _load()
+    if lib is None:
+        return None
+    rows, d = ids.shape
+    if vals.shape != ids.shape:
+        raise ValueError("ids and vals differ in shape")
+    hot_ids = np.empty((rows, d_hot), np.int32)
+    hot_vals = np.empty((rows, d_hot), np.float32)
+    cold_ids = np.empty((rows, d_cold), np.int32)
+    cold_vals = np.empty((rows, d_cold), np.float32)
+    most = lib.pio_hot_split(ids, vals, rows, d, lo, hi, d_hot, d_cold,
+                             hot_pad, cold_pad, hot_ids, hot_vals, cold_ids,
+                             cold_vals)
+    if most < 0:
+        raise ValueError("pio_hot_split: bad shapes")
+    return hot_ids, hot_vals, cold_ids, cold_vals, int(most)
 
 
 def counting_argsort(keys: np.ndarray, key_max: int) -> np.ndarray | None:

@@ -213,6 +213,60 @@ int64_t pio_neighbor_blocks(const int64_t* rows, const int32_t* cols,
 }
 
 // ---------------------------------------------------------------------------
+// Hot/cold split of built neighbor blocks
+// ---------------------------------------------------------------------------
+// ids/vals: row-major [n_rows, d] as pio_neighbor_blocks leaves them (a
+// padded slot has vals exactly 0, whatever its id). An entry is HOT when
+// its id lies in [lo, hi). Every row's entries in their order: the first
+// d_hot hot ones go to the hot part with ids local to the slice (id - lo),
+// all others (hot ones past d_hot too: the whole table holds them as well)
+// to the cold part with their ids as they are. Outputs are caller-allocated
+// [n_rows, d_hot] and [n_rows, d_cold] and need no initial value: every
+// slot is written, a part's padding with its pad id and vals 0. Returns the
+// largest count of cold entries any row holds: where that is more than
+// d_cold, such a row's cold part is cut short and the caller splits again
+// at that width.
+int64_t pio_hot_split(const int32_t* ids, const float* vals, int64_t n_rows,
+                      int64_t d, int32_t lo, int32_t hi, int64_t d_hot,
+                      int64_t d_cold, int32_t hot_pad, int32_t cold_pad,
+                      int32_t* hot_ids, float* hot_vals, int32_t* cold_ids,
+                      float* cold_vals) {
+  if (n_rows < 0 || d <= 0 || d_hot <= 0 || d_cold <= 0) return -1;
+  const int64_t nt = thread_count(n_rows * d);
+  const int64_t chunk = (n_rows + nt - 1) / nt;
+  std::vector<int64_t> most(static_cast<size_t>(nt), 0);
+  run_parallel(nt, [&](int64_t t) {
+    int64_t m = 0;
+    const int64_t r0 = t * chunk, r1 = std::min(n_rows, (t + 1) * chunk);
+    for (int64_t r = r0; r < r1; ++r) {
+      int32_t* hi_r = hot_ids + r * d_hot;
+      float* hv_r = hot_vals + r * d_hot;
+      int32_t* ci_r = cold_ids + r * d_cold;
+      float* cv_r = cold_vals + r * d_cold;
+      int64_t h = 0, c = 0, cold = 0;
+      for (int64_t j = r * d; j < (r + 1) * d; ++j) {
+        if (vals[j] == 0.0f) continue;
+        const int32_t id = ids[j];
+        const bool in_slice = id >= lo && id < hi;
+        cold += !in_slice;
+        if (in_slice && h < d_hot) {
+          hi_r[h] = id - lo;
+          hv_r[h++] = vals[j];
+        } else if (c < d_cold) {
+          ci_r[c] = id;
+          cv_r[c++] = vals[j];
+        }
+      }
+      for (; h < d_hot; ++h) { hi_r[h] = hot_pad; hv_r[h] = 0.0f; }
+      for (; c < d_cold; ++c) { ci_r[c] = cold_pad; cv_r[c] = 0.0f; }
+      m = std::max(m, cold);
+    }
+    most[static_cast<size_t>(t)] = m;
+  });
+  return *std::max_element(most.begin(), most.end());
+}
+
+// ---------------------------------------------------------------------------
 // Stable counting argsort (bounded keys)
 // ---------------------------------------------------------------------------
 // keys[n] non-negative int32 in [0, key_max]; out[n] receives the

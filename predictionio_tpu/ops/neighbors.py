@@ -39,6 +39,46 @@ __all__ = [
 
 _splitmix64 = native.splitmix64_np
 
+#: What one gathered factor row costs on a v5e, in ns, by the rows of the
+#: [rows, 64] float32 table it is gathered from: gather and gramian of one
+#: block of the step's own shape ([976, 2048, 64], ids drawn Zipf(0.9)),
+#: probe of PR 40 on the chip (`PERF.md` section 5 has the whole table).
+#: The rate is a STEP, not a slope: 4.02-4.10 ns a row from every table of
+#: up to 147,456 rows, which the compiler keeps in VMEM across the
+#: `lax.map`, whatever the ids' distribution or order; from a table of
+#: 163,840 rows (40 MiB) or more, gathered row by row from HBM, 6.3-6.8 ns
+#: or 12.2-12.9 (``GATHER_INDEX_TILE``). A bfloat16 table steps between
+#: 262,144 and 524,288 rows: the same bytes. The sizes are the candidates
+#: for a side's hot slice (``_pick_hot_rows``); the larger table's cost is
+#: the lower of its two, so that a split is chosen only where it pays
+#: against the better of the cold gathers. (Inside the compiled step both
+#: came out cheaper than alone, in the same order: under 1.8 ns a row from
+#: the slice, 4.1-4.3 from the whole table off the index tile; same file.)
+GATHER_NS_BY_TABLE_ROWS = ((4_096, 4.02), (16_384, 4.02), (65_536, 4.04),
+                           (131_072, 4.09), (147_456, 4.10))
+GATHER_NS_LARGER_TABLE = 6.3
+#: A gather from a table too large for VMEM costs 12.2-12.9 ns a row where
+#: the block's count of ids (B x D) is a multiple of 1,024, the tile its
+#: ids lie in, and 6.3-6.8 ns where it is not (same probe: D 2040 and 2056
+#: beside 2048, 544 beside 512 and 576, at B 976; the compiled gather's
+#: `integer_config` reads 256 for 128). A split bucket's cold width takes
+#: 8 columns from its hot width where that leaves the multiple, and its
+#: blocks 8 rows more where no width would (``_block_rows_for``).
+GATHER_INDEX_TILE = 1024
+#: A split bucket's cold width, in standard deviations of a row's count
+#: of cold entries above its mean, were each entry cold with the share the
+#: slice leaves: D q + 6 sqrt(D q (1 - q)). The width that just fits the
+#: bucket's rows would move by 8 from one labelling or one draw of a data
+#: set to the next (the largest of 10^5 binomial counts), and every move
+#: is another shape to compile; six deviations hold 2 x 10^6 block rows
+#: with a chance of 10^-3, and rows that differ more than a draw does
+#: (a user who rates only the tail) get the width they need.
+COLD_WIDTH_SIGMAS = 6.0
+#: a side's table is sliced, and a bucket split, only where the probe's
+#: costs predict this share of its gather time saved; below it the second
+#: gather's own cost (about 1 ms a block, same probe) eats the saving
+HOT_SPLIT_MIN_SAVING = 0.05
+
 
 @dataclasses.dataclass
 class NeighborBlocks:
@@ -56,10 +96,23 @@ class NeighborBlocks:
     num_rows: int  # true number of rows (before padding to NB*B)
     max_degree: int  # D after capping
     dropped: int  # entries dropped by the degree cap
+    #: a SPLIT bucket of the permuted layout (``_split_hot``): the entries
+    #: whose neighbor lies in the other side's hot slice, [NB, B, Dh], ids
+    #: LOCAL to the slice and padded with the slice's zero row; ``ids`` /
+    #: ``vals`` then hold the rest. Dh + D is the unsplit bucket's width.
+    hot_ids: np.ndarray | None = None
+    hot_vals: np.ndarray | None = None
 
     @property
     def mask(self) -> np.ndarray:  # float32 [NB, B, D] 1.0 = real entry
         return (self.vals != 0).astype(np.float32)
+
+    @property
+    def gather_rows(self) -> tuple[int, int]:
+        """(factor rows one pass over this bucket gathers, padding
+        included; those of them gathered from the hot slice)."""
+        hot = 0 if self.hot_ids is None else self.hot_ids.size
+        return self.ids.size + hot, hot
 
     @property
     def padded_rows(self) -> int:
@@ -88,13 +141,27 @@ class SideLayout:
     zero slot (``zero_slot`` = slots-1). ``pos[r]`` is true row r's slot.
     Block ``ids`` reference the OTHER side's slots; padded entries point
     at the other side's ``zero_slot``, so gathers return exact zeros and
-    the solver needs no [B, D, R]-shaped validity mask."""
+    the solver needs no [B, D, R]-shaped validity mask.
+
+    Where the OTHER side's rows are few enough to pay most of the gathers
+    (``_pick_hot_rows``), rows ``hot_lo : hot_lo + hot_rows`` of its factor
+    array are the hot slice this side's split buckets gather from: its
+    most rated rows and, last, one always-zero row for their padding.
+    ``hot_rows`` 0: nothing here is split."""
 
     buckets: list[NeighborBlocks]
     metas: list[TierMeta]
     slots: int
     pos: np.ndarray  # int32 [num_rows] true row -> slot
     zero_slot: int
+    hot_lo: int = 0
+    hot_rows: int = 0
+
+    @property
+    def gather_rows(self) -> tuple[int, int]:
+        """(rows one half-step gathers, those from the hot slice)."""
+        per = [b.gather_rows for b in self.buckets]
+        return sum(p[0] for p in per), sum(p[1] for p in per)
 
     @property
     def dropped(self) -> int:
@@ -221,11 +288,55 @@ class _SidePlan:
     slots: int
     pos: np.ndarray  # int32 [num_rows]
     zero_slot: int
+    #: this side's hot slice (what the OTHER side's split buckets gather
+    #: from): rows hot_lo : hot_lo + hot_rows, the last an always-zero one,
+    #: and the share of all entries whose neighbor lies in it
+    hot_lo: int = 0
+    hot_rows: int = 0
+    hot_share: float = 0.0
+
+
+def _gather_ns(table_rows: int) -> float:
+    for rows, ns in GATHER_NS_BY_TABLE_ROWS:
+        if table_rows <= rows:
+            return ns
+    return GATHER_NS_LARGER_TABLE
+
+
+def _pick_hot_rows(counts: np.ndarray) -> int:
+    """Rows of this side's hot slice, its zero row included, or 0 for none:
+    the candidate size that minimises share x c_hot + (1 - share) x c_whole
+    over the side's own degree histogram, where ``share`` is the part of
+    all entries that the slice's rows hold. A table no larger than a
+    candidate is gathered at the hot rate as it stands, and uniform
+    popularity gives a slice no share to speak of: neither is sliced."""
+    # the factor array has a slot a row, and padding: some thousands more
+    table_rows = len(counts) + 2
+    c_whole = _gather_ns(table_rows)
+    total = int(counts.sum())
+    if total == 0:
+        return 0
+    cum = np.cumsum(np.sort(counts)[::-1])
+    best, best_rows = c_whole, 0
+    for rows, ns in GATHER_NS_BY_TABLE_ROWS:
+        if rows >= table_rows:
+            break
+        share = cum[min(rows - 1, len(cum)) - 1] / total
+        cost = share * ns + (1.0 - share) * c_whole
+        if cost < best:
+            best, best_rows = cost, rows
+    if 1.0 - best / c_whole < HOT_SPLIT_MIN_SAVING:
+        return 0
+    return best_rows
 
 
 def _plan_side(counts: np.ndarray, *, tiers, gather_budget: int,
                chunk_cap: int | None, merge_budget, nnz: int,
-               align: int = 8) -> _SidePlan:
+               align: int = 8, hot_rows: int = 0,
+               split: bool = False) -> _SidePlan:
+    """``hot_rows``: the rows of this side's hot slice (``_pick_hot_rows``;
+    0: none). ``split``: the OTHER side has one, so this side's buckets
+    will be split and its blocks keep off ``GATHER_INDEX_TILE``."""
     num_rows = len(counts)
     align = 8 * max(1, align) // math.gcd(8, max(1, align))  # lcm(8, align)
     if merge_budget == "auto":
@@ -245,16 +356,6 @@ def _plan_side(counts: np.ndarray, *, tiers, gather_budget: int,
     light = (counts > 0) & ~heavy
     tier_list = _assign_tiers(counts, tiers, merge_budget, light, dp_cost)
 
-    pos = np.full(num_rows, -1, np.int64)
-    off = 0
-    tier_block_rows = []
-    for tier_d, row_idx in tier_list:
-        br = _block_rows_for(tier_d, gather_budget, len(row_idx))
-        span = max(1, math.ceil(len(row_idx) / br)) * br
-        pos[row_idx] = off + np.arange(len(row_idx))
-        tier_block_rows.append(br)
-        off += span
-
     chunks: list[_ChunkClass] = []
     if heavy.any():
         heavy_rows = np.nonzero(heavy)[0]  # ascending
@@ -270,13 +371,56 @@ def _plan_side(counts: np.ndarray, *, tiers, gather_budget: int,
         for c in np.unique(cls):
             sel = cls == c
             owners = heavy_rows[sel]
-            span = ((len(owners) + 7) // 8) * 8
-            pos[owners] = off + np.arange(len(owners))
             chunks.append(_ChunkClass(width=int(edges[c]), owners=owners,
-                                      k=k[sel], span=span))
-            off += span
+                                      k=k[sel],
+                                      span=((len(owners) + 7) // 8) * 8))
 
+    tier_block_rows = [_block_rows_for(tier_d, gather_budget, len(row_idx),
+                                       off_tile=split)
+                       for tier_d, row_idx in tier_list]
+    spans = [max(1, math.ceil(len(row_idx) / br)) * br
+             for (_d, row_idx), br in zip(tier_list, tier_block_rows)]
+    covered = sum(spans) + sum(cc.span for cc in chunks)
     deg0 = np.nonzero(counts == 0)[0]
+    # the slice is the last covered slots and one zero row after them, so
+    # the most rated rows must lie last: the chunked classes do (every
+    # heavy row is hotter than any tier's), and a sliced side's tiers
+    # order their rows by degree where an unsliced side's keep row order
+    lo_min = max(0, covered + 1 - hot_rows) if hot_rows else covered
+    hot_lo = covered  # where the slice starts: set at the first cut below
+
+    pos = np.full(num_rows, -1, np.int64)
+    off = 0
+    for t, ((tier_d, row_idx), span) in enumerate(zip(tier_list, spans)):
+        if hot_rows:
+            degs = counts[row_idx]
+            order = np.argsort(degs, kind="stable")
+            row_idx, degs = row_idx[order], degs[order]
+            tier_list[t] = (tier_d, row_idx)
+            if off <= lo_min < off + span:
+                # the slice starts where the degree changes, never among
+                # rows of one degree: which of those lie first is a matter
+                # of row ids, and the slice's rows (so every split width
+                # after them) must not depend on how rows are labelled
+                j = lo_min - off
+                if 0 < j < len(degs) and degs[j] == degs[j - 1]:
+                    j = int(np.searchsorted(degs, degs[j], side="right"))
+                hot_lo = off + j
+        pos[row_idx] = off + np.arange(len(row_idx))
+        off += span
+    for cc in chunks:
+        if hot_rows and off < lo_min < off + cc.span:
+            hot_lo = off + cc.span  # a class's owners lie in row order
+        elif hot_rows and lo_min == off:
+            hot_lo = off
+        pos[cc.owners] = off + np.arange(len(cc.owners))
+        off += cc.span
+    hot_rows = covered + 1 - hot_lo if hot_rows else 0
+    in_slice = pos >= hot_lo if hot_rows else np.zeros(num_rows, bool)
+    hot_share = float(counts[in_slice].sum() / max(1, nnz))
+    # a sliced side keeps slot `covered` zero for the slice to end on (a
+    # degree-0 row there would hold its initial factors in the first sweep)
+    off += 1 if hot_rows else 0
     pos[deg0] = off + np.arange(len(deg0))
     off += len(deg0)
     # ≥1 guaranteed-zero slot, rounded so factor rows shard evenly over a
@@ -286,6 +430,8 @@ def _plan_side(counts: np.ndarray, *, tiers, gather_budget: int,
     return _SidePlan(
         tiers=tier_list, tier_block_rows=tier_block_rows, chunks=chunks,
         slots=slots, pos=pos.astype(np.int32), zero_slot=slots - 1,
+        hot_lo=hot_lo if hot_rows else 0, hot_rows=hot_rows,
+        hot_share=hot_share,
     )
 
 
@@ -300,16 +446,100 @@ def _stable_argsort_bounded(keys: np.ndarray, key_max: int) -> np.ndarray:
     return np.argsort(keys, kind="stable")
 
 
-def _build_side(plan: _SidePlan, rows, cols_slots, vals, *, zero_other: int,
+def _split_hot(b: NeighborBlocks, hot_lo: int, hot_rows: int,
+               table_rows: int, pad_id: int, hot_share: float
+               ) -> NeighborBlocks:
+    """Split a built bucket's width D into a hot part of Dh columns, which
+    the step gathers from the other side's hot slice, and a cold part of
+    D - Dh, which it gathers from the whole table as before: the same
+    slots gathered, so no padding is added. A row's first Dh entries
+    inside the slice go to the hot part under ids local to the slice; all
+    others go to the cold part, the hot ones past Dh too, since the whole
+    table holds every row. The cold width has to hold the most cold
+    entries any row of the bucket has; it is set from the slice's share
+    of all entries (``COLD_WIDTH_SIGMAS``), so that it follows from the
+    degree histograms alone, and from the rows themselves only where one
+    of them has more (the split says so, and is then made again at that
+    width). Dh is what is left of D. Rows keep their order and entries
+    theirs, so a row's two partial normal equations add up to the unsplit
+    row's. Padding is told by vals 0 alone: the bucket's padded ids are
+    not read, and both parts get their own (the slice's zero row,
+    ``pad_id``). The bucket comes back as it is where the split would
+    save less than ``HOT_SPLIT_MIN_SAVING``."""
+    nb, block_rows, d = b.ids.shape
+    ids, vals = b.ids.reshape(-1, d), b.vals.reshape(-1, d)
+    hot_hi = hot_lo + hot_rows - 1  # the slice's last row is its zero row
+    q = 1.0 - hot_share
+    need = d * q + COLD_WIDTH_SIGMAS * math.sqrt(d * q * (1.0 - q)) + 1.0
+    while True:
+        d_cold = max(8, ((int(need) + 7) // 8) * 8)
+        if (block_rows * d_cold) % GATHER_INDEX_TILE == 0 \
+                and (block_rows * 8) % GATHER_INDEX_TILE:
+            d_cold += 8  # see GATHER_INDEX_TILE
+        d_hot = d - d_cold
+        saving = d_hot / d * (1.0 - _gather_ns(hot_rows)
+                              / _gather_ns(table_rows))
+        if d_hot < 8 or saving < HOT_SPLIT_MIN_SAVING:
+            return b
+        args = (ids, vals, hot_lo, hot_hi, d_hot, d_cold, hot_rows - 1, pad_id)
+        *parts, need = (native.hot_split_native(*args)
+                        or _split_parts_numpy(*args))
+        if need <= d_cold:  # else a row has more cold entries: once more
+            break
+    hot_ids, hot_vals, cold_ids, cold_vals = (
+        a.reshape(nb, block_rows, -1) for a in parts)
+    return dataclasses.replace(b, ids=cold_ids, vals=cold_vals,
+                               hot_ids=hot_ids, hot_vals=hot_vals)
+
+
+def _split_parts_numpy(ids, vals, lo, hi, d_hot, d_cold, hot_pad, cold_pad):
+    """``native.hot_split_native`` in numpy: the same five results."""
+    valid = vals != 0
+    hot = valid & (ids >= lo) & (ids < hi)
+    most_cold = int((valid & ~hot).sum(axis=1).max())
+    hot &= np.cumsum(hot, axis=1) <= d_hot
+    cold = valid & ~hot
+    out = []
+    for part, width, pad, base in ((hot, d_hot, hot_pad, lo),
+                                   (cold, d_cold, cold_pad, 0)):
+        at = np.cumsum(part, axis=1) - 1
+        r, j = np.nonzero(part & (at < width))
+        p_ids = np.full((len(ids), width), pad, np.int32)
+        p_vals = np.zeros((len(ids), width), np.float32)
+        p_ids[r, at[r, j]], p_vals[r, at[r, j]] = ids[r, j] - base, vals[r, j]
+        out += [p_ids, p_vals]
+    return (*out, most_cold)
+
+
+def _build_side(plan: _SidePlan, rows, cols_slots, vals, *, other: _SidePlan,
                 gather_budget: int, seed: int) -> SideLayout:
     """Build one side's blocks from its plan. ``cols_slots`` is the
-    neighbor column array ALREADY remapped to the other side's slots.
+    neighbor column array ALREADY remapped to the other side's slots
+    (``other``'s, which also says where padding points and which of its
+    rows are its hot slice).
 
     One radix sort groups the entry stream by tier, then every tier works
     on a contiguous slice — the naive per-tier full-stream mask costs
     O(nnz · tiers) (measured 8s at ML-20M scale against this path's ~2s).
     """
     num_rows = len(plan.pos)
+    zero_other = other.zero_slot
+
+    def blocks(*coo, **kw) -> NeighborBlocks:
+        """One bucket, split where the other side has a hot slice. The
+        split sets both parts' padding ids itself and never reads the
+        unsplit bucket's, so that build leaves them unset and saves its
+        pass over the bucket."""
+        if not other.hot_rows:
+            return build_neighbor_blocks(*coo, pad_id=zero_other, seed=seed,
+                                         **kw)
+        whole = build_neighbor_blocks(*coo, pad_id=0, seed=seed, **kw)
+        b = _split_hot(whole, other.hot_lo, other.hot_rows, other.slots,
+                       zero_other, other.hot_share)
+        if b is whole:  # not worth a second gather
+            b.ids = np.where(b.vals == 0, np.int32(zero_other), b.ids)
+        return b
+
     rows = np.asarray(rows)
     if rows.dtype.itemsize > 4:
         rows = rows.astype(np.int32)  # numpy radix-sorts small ints
@@ -340,11 +570,8 @@ def _build_side(plan: _SidePlan, rows, cols_slots, vals, *, zero_other: int,
                   rows=len(row_idx)):
             sl = order_t[bounds[t + 1]:bounds[t + 2]]
             remap[row_idx] = np.arange(len(row_idx))
-            b = build_neighbor_blocks(
-                remap[rows[sl]], cols_slots[sl], vals[sl],
-                len(row_idx), block_rows=br, degree_cap=tier_d,
-                pad_id=zero_other, seed=seed,
-            )
+            b = blocks(remap[rows[sl]], cols_slots[sl], vals[sl],
+                       len(row_idx), block_rows=br, degree_cap=tier_d)
         buckets.append(b)
         metas.append(TierMeta(span=b.padded_rows))
 
@@ -374,11 +601,10 @@ def _build_side(plan: _SidePlan, rows, cols_slots, vals, *, zero_other: int,
                 vrow = (hv_base[rs[sel]]
                         + (pos_in[sel] * k_full[rs[sel]]) // counts[rs[sel]])
                 n_hv = int(cc.k.sum())
-                br = _block_rows_for(cc.width, gather_budget, n_hv)
-                b = build_neighbor_blocks(
-                    vrow, cols_o[sel], vals_o[sel], n_hv, block_rows=br,
-                    degree_cap=cc.width, pad_id=zero_other, seed=seed,
-                )
+                br = _block_rows_for(cc.width, gather_budget, n_hv,
+                                     off_tile=bool(other.hot_rows))
+                b = blocks(vrow, cols_o[sel], vals_o[sel], n_hv,
+                           block_rows=br, degree_cap=cc.width)
                 # seg: block row (chunk) -> owner's local slot, sorted
                 # ascending; block padding rows map to the LAST local slot
                 # (their partial equations are exactly zero, and a trailing
@@ -392,7 +618,8 @@ def _build_side(plan: _SidePlan, rows, cols_slots, vals, *, zero_other: int,
                 hv_base[cc.owners] = -1
 
     return SideLayout(buckets=buckets, metas=metas, slots=plan.slots,
-                      pos=plan.pos, zero_slot=plan.zero_slot)
+                      pos=plan.pos, zero_slot=plan.zero_slot,
+                      hot_lo=other.hot_lo, hot_rows=other.hot_rows)
 
 
 def build_bilinear_layout(
@@ -446,28 +673,36 @@ def build_bilinear_layout(
         kw = dict(tiers=tiers, gather_budget=gather_budget,
                   chunk_cap=chunk_cap, merge_budget=merge_budget, nnz=nnz,
                   align=align)
-        plan_u = _plan_side(counts_u, **kw)
-        plan_i = _plan_side(counts_i, **kw)
+        hot_u = _pick_hot_rows(counts_u)
+        hot_i = _pick_hot_rows(counts_i)
+        plan_u = _plan_side(counts_u, hot_rows=hot_u, split=bool(hot_i), **kw)
+        plan_i = _plan_side(counts_i, hot_rows=hot_i, split=bool(hot_u), **kw)
     with trace.span("train.als.layout.user", sink=sink, t0=s.t1) as s:
         lay_u = _build_side(plan_u, u_idx, plan_i.pos[i_idx], vals,
-                            zero_other=plan_i.zero_slot,
-                            gather_budget=gather_budget, seed=seed)
+                            other=plan_i, gather_budget=gather_budget,
+                            seed=seed)
     with trace.span("train.als.layout.item", sink=sink, t0=s.t1):
         lay_i = _build_side(plan_i, i_idx, plan_u.pos[u_idx], vals,
-                            zero_other=plan_u.zero_slot,
-                            gather_budget=gather_budget, seed=seed)
+                            other=plan_u, gather_budget=gather_budget,
+                            seed=seed)
     return lay_u, lay_i
 
 
-def _block_rows_for(tier_d: int, gather_budget: int, n_rows: int) -> int:
+def _block_rows_for(tier_d: int, gather_budget: int, n_rows: int, *,
+                    off_tile: bool = False) -> int:
     """Per-block row count for a tier: bounded by the gather budget
     (B*D elements of peak gathered factors) and BALANCED across the
     tier's blocks — a tier one row past a block boundary must not pad a
     whole extra block of rows (ceil-divide the rows over the block count
-    the budget implies; waste < 8 rows per block)."""
+    the budget implies; waste < 8 rows per block). ``off_tile``: a bucket
+    that will be split takes 8 rows more where every width of 8 columns
+    would make its count of ids a multiple of ``GATHER_INDEX_TILE``."""
     b_max = min(8192, max(8, gather_budget // max(tier_d, 8)))
     nb = max(1, math.ceil(max(n_rows, 1) / b_max))
-    return max(8, ((math.ceil(n_rows / nb) + 7) // 8) * 8) if n_rows else 8
+    b = max(8, ((math.ceil(n_rows / nb) + 7) // 8) * 8) if n_rows else 8
+    if off_tile and (b * 8) % GATHER_INDEX_TILE == 0:
+        b += 8
+    return b
 
 
 def build_neighbor_blocks(

@@ -79,12 +79,27 @@ def assert_layout_invariants(lay, other, vals, n):
     import numpy as np
 
     assert lay.dropped == 0
-    assert sum(int(b.mask.sum()) for b in lay.buckets) == n
     assert len(set(lay.pos.tolist())) == len(lay.pos)
     assert lay.pos.max() < lay.slots
     got = []
     for b, m in zip(lay.buckets, lay.metas):
         assert b.ids.max() < other.slots
+        if b.hot_ids is not None:
+            # a split bucket's hot part: ids local to the other side's
+            # hot slice, padded with the slice's last (always-zero) row;
+            # same rows in the same order as the cold part
+            assert lay.hot_rows and b.hot_ids.shape[:2] == b.ids.shape[:2]
+            real = b.hot_vals != 0
+            assert b.hot_ids.min() >= 0
+            assert (b.hot_ids[real] < lay.hot_rows - 1).all()
+            assert (b.hot_ids[~real] == lay.hot_rows - 1).all()
+            got.append(b.hot_vals[real])
+            # the cold part holds a neighbor of the slice only where the
+            # row's hot part is full
+            in_slice = ((b.ids >= lay.hot_lo)
+                        & (b.ids < lay.hot_lo + lay.hot_rows - 1)
+                        & (b.mask != 0))
+            assert real.all(axis=-1)[in_slice.any(axis=-1)].all()
         # padding is defined by the explicit mask, not by vals == 0 —
         # a genuine zero-valued rating slot is REAL and must keep its
         # neighbor id (ADVICE r5; the builder nudges exact zeros, but
@@ -94,4 +109,24 @@ def assert_layout_invariants(lay, other, vals, n):
         if m.seg is not None:
             assert (np.diff(m.seg) >= 0).all()
             assert m.seg.max() < m.span
+    assert sum(len(g) for g in got) == n
     np.testing.assert_allclose(np.sort(np.concatenate(got)), np.sort(vals))
+
+
+#: gather costs that make the layout slice tables of a few hundred rows:
+#: what ``neighbors.GATHER_NS_BY_TABLE_ROWS`` is patched to where a test
+#: wants a split layout from a data set of test size
+SMALL_HOT_SLICES = ((64, 4.0), (128, 4.1))
+
+
+def zipf_coo(rng, nu=600, ni=400, n=24_000, exponent=0.9, sigma=1.2):
+    """(users, items, vals): Zipf item popularity, log-normal user
+    activity, integer ratings 1..5: the shape the hot slice is for."""
+    import numpy as np
+
+    p = 1.0 / np.arange(1, ni + 1) ** exponent
+    items = rng.permutation(ni)[rng.choice(ni, n, p=p / p.sum())]
+    w = np.exp(sigma * rng.standard_normal(nu))
+    users = rng.choice(nu, n, p=w / w.sum())
+    vals = rng.integers(1, 6, n).astype(np.float32)
+    return users.astype(np.int64), items.astype(np.int64), vals

@@ -321,8 +321,11 @@ def test_tier_wise_solve_matches_global(rng, mesh8, monkeypatch):
                                rtol=1e-5, atol=1e-6)
 
 
-def test_model_sharded_collective_inventory(mesh8):
-    """The compiled model-sharded train step's communication story:
+@pytest.mark.parametrize("split", [False, True])
+def test_model_sharded_collective_inventory(mesh8, monkeypatch, split):
+    """The compiled model-sharded train step's communication story
+    (``split``: with both tables' hot slices taken, each from the
+    replicated factors after the half-step's one all-gather):
     the ONLY factor-sized collectives are one
     replication all-gather of the opposite factors per half-step (plus
     the solve-output gathers) — no all-to-all, no reduce-scatter, and
@@ -347,7 +350,17 @@ def test_model_sharded_collective_inventory(mesh8):
     rows = rng.integers(0, nu, n).astype(np.int64)
     cols = rng.integers(0, ni, n).astype(np.int64)
     vals = rng.random(n).astype(np.float32)
+    if split:
+        from predictionio_tpu.ops import neighbors
+        from tests.helpers import zipf_coo
+
+        monkeypatch.setattr(neighbors, "GATHER_NS_BY_TABLE_ROWS",
+                            ((16, 4.0),))
+        monkeypatch.setattr(neighbors, "COLD_WIDTH_SIGMAS", 0.0)
+        rows, cols, vals = zipf_coo(rng, nu, ni, n)
     u_lay, i_lay = build_bilinear_layout(rows, cols, vals, nu, ni, align=2)
+    assert any(b.hot_ids is not None for b in u_lay.buckets) == split
+    assert any(b.hot_ids is not None for b in i_lay.buckets) == split
     u_bk = put_layout(u_lay, mesh)
     i_bk = put_layout(i_lay, mesh)
     step = make_train_step(mesh, u_lay, i_lay, rank=rank, model_sharded=True)
@@ -639,3 +652,327 @@ class TestFoldIn:
         mask = rows == 3
         u = m.fold_in_user([f"i{c}" for c in cols[mask]], vals[mask])
         np.testing.assert_allclose(u, m.user_factors[3], rtol=2e-2, atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# The hot slice (ops/neighbors._pick_hot_rows, _split_hot; models/als
+# _gram_blocks' second gather): where most gathers of a side hit few rows
+# of the other, those rows are gathered from a slice small enough for the
+# chip's fast gather.
+
+
+@pytest.fixture
+def small_slices(monkeypatch):
+    from predictionio_tpu.ops import neighbors
+    from tests.helpers import SMALL_HOT_SLICES
+
+    monkeypatch.setattr(neighbors, "GATHER_NS_BY_TABLE_ROWS",
+                        SMALL_HOT_SLICES)
+
+
+@pytest.fixture
+def exact_cold_widths(monkeypatch):
+    """Cold widths that just fit the rows: the margin of
+    ``COLD_WIDTH_SIGMAS`` is for rows of hundreds of entries, and leaves
+    nothing to split at the widths of a test's data set."""
+    from predictionio_tpu.ops import neighbors
+
+    monkeypatch.setattr(neighbors, "COLD_WIDTH_SIGMAS", 0.0)
+
+
+def _unsplit_layout(monkeypatch, *args, **kw):
+    """The layout as it is built where no table is worth slicing."""
+    from predictionio_tpu.ops import neighbors
+
+    with monkeypatch.context() as m:
+        m.setattr(neighbors, "GATHER_NS_BY_TABLE_ROWS", ())
+        return neighbors.build_bilinear_layout(*args, **kw)
+
+
+@pytest.mark.parametrize("table_rows,popularity,want", [
+    (100, "zipf", 0),        # smaller than every candidate: fast as it is
+    (5_000, "uniform", 0),   # a slice of 128 rows holds 2.6% of the entries
+    (5_000, "zipf", 128),    # the largest candidate: the cost is a step
+    (100_000, "zipf", 128),
+    (120, "zipf", 0),        # 64 rows would be no faster than these 122
+])
+def test_pick_hot_rows_follows_the_histogram(small_slices, table_rows,
+                                             popularity, want):
+    from predictionio_tpu.ops.neighbors import _pick_hot_rows
+
+    ranks = np.arange(1, table_rows + 1)
+    counts = (np.full(table_rows, 50) if popularity == "uniform"
+              else (1e6 / ranks ** 0.9).astype(np.int64) + 1)
+    assert _pick_hot_rows(
+        np.random.default_rng(0).permutation(counts)) == want
+
+
+def test_hot_split_layout_keeps_every_rating_once(small_slices, monkeypatch):
+    """A Zipf data set: both tables are sliced, every rating lies in
+    exactly one of a bucket's hot and cold parts, hot ids lie inside the
+    slice (its last row their padding), and an iteration gathers exactly
+    the rows the unsplit layout gathers: the split adds no padding."""
+    from predictionio_tpu.ops.neighbors import build_bilinear_layout
+    from tests.helpers import assert_layout_invariants, zipf_coo
+
+    users, items, vals = zipf_coo(np.random.default_rng(5))
+    nu, ni = 600, 400
+    kw = dict(chunk_cap=128)
+    u_lay, i_lay = build_bilinear_layout(users, items, vals, nu, ni, **kw)
+    u_old, i_old = _unsplit_layout(monkeypatch, users, items, vals, nu, ni,
+                                   **kw)
+    for lay, other, old in ((u_lay, i_lay, u_old), (i_lay, u_lay, i_old)):
+        assert_layout_invariants(lay, other, vals, len(vals))
+        # the slice starts where the degree changes, so it may fall short
+        assert 100 < lay.hot_rows <= 128 and old.hot_rows == 0
+        # the slice ends on the reserved zero row after the covered slots
+        covered = sum(m.span for m in other.metas)
+        assert lay.hot_lo + lay.hot_rows - 1 == covered
+        assert covered not in other.pos.tolist()
+        rows, hot = lay.gather_rows
+        assert old.gather_rows[1] == 0
+        assert old.gather_rows[0] <= rows <= 1.03 * old.gather_rows[0]
+        assert hot > 0.2 * rows
+        for b, b_old in zip(lay.buckets, old.buckets):
+            hot_width = 0 if b.hot_ids is None else b.hot_ids.shape[2]
+            assert b.ids.shape[2] + hot_width == b_old.ids.shape[2]
+            assert b.ids.shape[1] - b_old.ids.shape[1] in (0, 8)
+        split = [b for b in lay.buckets if b.hot_ids is not None]
+        assert split and any(m.seg is not None for b, m in
+                             zip(lay.buckets, lay.metas)
+                             if b.hot_ids is not None)
+    # the slice holds the most rated rows: every row outside it has at
+    # most the degree of the lightest row inside
+    deg = np.bincount(items, minlength=ni)
+    inside = (i_lay.pos >= u_lay.hot_lo) & (deg > 0)
+    assert 90 < inside.sum() <= 127  # the rest: the spans' padding slots
+    assert deg[~inside].max() < deg[inside].min()
+
+
+def _row_equations(lay, other_factors, rank):
+    """Every covered slot's unregularised normal equations from a layout,
+    through _gram_blocks as the step calls it: [covered, R, R],
+    [covered, R]."""
+    import jax.numpy as jnp
+
+    from predictionio_tpu.models.als import _gram_blocks
+
+    f = jnp.asarray(other_factors)
+    hot_c = f[lay.hot_lo:lay.hot_lo + lay.hot_rows]
+    a_all, b_all = [], []
+    for b, m in zip(lay.buckets, lay.metas):
+        hot = (None if b.hot_ids is None
+               else (hot_c, jnp.asarray(b.hot_ids), jnp.asarray(b.hot_vals)))
+        a, bb, _n = _gram_blocks(jnp.asarray(b.ids), jnp.asarray(b.vals), f,
+                                 implicit=False, alpha=1.0, rank=rank,
+                                 hot=hot)
+        a = np.asarray(a).reshape(-1, rank, rank)
+        bb = np.asarray(bb).reshape(-1, rank)
+        if m.seg is not None:
+            sa = np.zeros((m.span, rank, rank), np.float32)
+            sb = np.zeros((m.span, rank), np.float32)
+            np.add.at(sa, m.seg, a)
+            np.add.at(sb, m.seg, bb)
+            a, bb = sa, sb
+        a_all.append(a)
+        b_all.append(bb)
+    return np.concatenate(a_all), np.concatenate(b_all)  # by slot
+
+
+def test_hot_split_equations_equal_the_unsplit_rows(small_slices,
+                                                    exact_cold_widths,
+                                                    monkeypatch):
+    """A row's hot and cold partial equations add up to the unsplit
+    row's, to float32 rounding, chunked rows included."""
+    from predictionio_tpu.ops.neighbors import build_bilinear_layout
+    from tests.helpers import zipf_coo
+
+    users, items, vals = zipf_coo(np.random.default_rng(6))
+    nu, ni, rank = 600, 400, 8
+    u_lay, i_lay = build_bilinear_layout(users, items, vals, nu, ni,
+                                         chunk_cap=128)
+    u_old, i_old = _unsplit_layout(monkeypatch, users, items, vals, nu, ni,
+                                   chunk_cap=128)
+    v_true = np.random.default_rng(1).normal(size=(ni, rank)).astype(
+        np.float32)
+
+    def permuted(lay):
+        out = np.zeros((lay.slots, rank), np.float32)
+        out[lay.pos] = v_true
+        return out
+
+    rated = np.bincount(users, minlength=nu) > 0
+    a_new, b_new = _row_equations(u_lay, permuted(i_lay), rank)
+    a_old, b_old = _row_equations(u_old, permuted(i_old), rank)
+    # slot orders differ (a sliced side sorts a tier's rows by degree):
+    # line both up by true row
+    new, old = u_lay.pos[rated], u_old.pos[rated]
+    np.testing.assert_allclose(a_new[new], a_old[old], rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(b_new[new], b_old[old], rtol=1e-5, atol=1e-4)
+    assert np.abs(a_old[old]).max() > 10
+
+
+def test_uniform_data_is_not_split_and_its_layout_is_unchanged(small_slices,
+                                                               monkeypatch):
+    """Uniform popularity gives a slice no share worth a second gather:
+    neither table is sliced, and every array of the layout is byte for
+    byte what the builder makes with no candidate at all."""
+    from predictionio_tpu.ops.neighbors import build_bilinear_layout
+
+    rng = np.random.default_rng(2)
+    nu, ni, n = 5_000, 4_000, 60_000
+    users, items = rng.integers(0, nu, n), rng.integers(0, ni, n)
+    vals = rng.integers(1, 6, n).astype(np.float32)
+    got = build_bilinear_layout(users, items, vals, nu, ni)
+    want = _unsplit_layout(monkeypatch, users, items, vals, nu, ni)
+    for g, w in zip(got, want):
+        assert g.hot_rows == 0 and g.slots == w.slots
+        assert g.pos.tobytes() == w.pos.tobytes()
+        assert len(g.buckets) == len(w.buckets)
+        for bg, bw in zip(g.buckets, w.buckets):
+            assert bg.hot_ids is None
+            assert bg.ids.tobytes() == bw.ids.tobytes()
+            assert bg.vals.tobytes() == bw.vals.tobytes()
+
+
+def _split_and_unsplit(monkeypatch, align=8):
+    from predictionio_tpu.ops.neighbors import build_bilinear_layout
+    from tests.helpers import zipf_coo
+
+    users, items, vals = zipf_coo(np.random.default_rng(8), nu=300, ni=200,
+                                  n=9_000)
+    args = (users, items, vals, 300, 200)
+    kw = dict(chunk_cap=128, align=align)
+    split = build_bilinear_layout(*args, **kw)
+    assert split[0].hot_rows and split[1].hot_rows
+    return split, _unsplit_layout(monkeypatch, *args, **kw), (users, items)
+
+
+def _run_steps(mesh, lays, iters, rank=8, model_sharded=False, **kw):
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from predictionio_tpu.models.als import make_train_step, put_layout
+
+    u_lay, i_lay = lays
+    vd = "bfloat16" if kw.get("compute_dtype") == "bfloat16" else None
+    u_bk = put_layout(u_lay, mesh, vals_dtype=vd)
+    i_bk = put_layout(i_lay, mesh, vals_dtype=vd)
+    step = make_train_step(mesh, u_lay, i_lay, rank=rank, lambda_=0.1,
+                           model_sharded=model_sharded, **kw)
+    fac = NamedSharding(mesh, P("model" if model_sharded else None, None))
+    r = np.random.default_rng(4)
+    out = []
+    for lay, n_rows in ((u_lay, 300), (i_lay, 200)):
+        x = np.zeros((lay.slots, rank), np.float32)
+        x[lay.pos] = np.abs(r.normal(size=(n_rows, rank))) / np.sqrt(rank)
+        out.append(jax.device_put(x, fac))
+    u, v = out
+    for _ in range(iters):
+        u, v = step(u_bk, i_bk, u, v)
+    return np.asarray(u)[u_lay.pos], np.asarray(v)[i_lay.pos]
+
+
+@pytest.mark.parametrize("iters", [1, 3])
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("solver", ["cg", "cholesky"])
+@pytest.mark.parametrize("implicit", [False, True])
+def test_hot_split_step_matches_the_unsplit_step(small_slices,
+                                                 exact_cold_widths,
+                                                 monkeypatch, implicit,
+                                                 solver, compute_dtype,
+                                                 iters):
+    """The same sums in another order: factors after one and after three
+    iterations equal the unsplit step's. CG runs to convergence here (a
+    4-iteration CG answers float32 rounding in its inputs with 1e-2 in
+    its output, split or not); bfloat16 gramians round each block row's
+    sum once, so there the two orders meet at bfloat16's step."""
+    from predictionio_tpu.parallel.mesh import make_mesh
+
+    split, unsplit, _ = _split_and_unsplit(monkeypatch)
+    mesh = make_mesh((1,), ("data",))
+    kw = dict(implicit=implicit, solver=solver, compute_dtype=compute_dtype,
+              cg_iters=48)
+    got = _run_steps(mesh, split, iters, **kw)
+    want = _run_steps(mesh, unsplit, iters, **kw)
+    tol = (2e-2 if compute_dtype == "bfloat16" and solver == "cg"
+           else 1e-5 if iters == 1 else 5e-5)  # rounding compounds
+    for g, w in zip(got, want):
+        assert np.abs(w).max() > 0.1
+        np.testing.assert_allclose(g, w, rtol=tol, atol=tol * np.abs(w).max())
+
+
+@pytest.mark.parametrize("iters", [1, 3])
+def test_hot_split_step_under_model_sharding(small_slices,
+                                             exact_cold_widths, monkeypatch,
+                                             iters):
+    """Tensor-parallel factors on a 2 x 2 mesh of virtual devices: the
+    slice is taken from the replicated factors, after the all-gather."""
+    import jax
+
+    from predictionio_tpu.parallel.mesh import make_mesh
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    split, unsplit, _ = _split_and_unsplit(monkeypatch, align=2)
+    mesh = make_mesh((2, 2), ("data", "model"))
+    kw = dict(solver="cholesky", model_sharded=True)
+    got = _run_steps(mesh, split, iters, **kw)
+    want = _run_steps(make_mesh((1,), ("data",)), unsplit, iters,
+                      solver="cholesky")
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=5e-5,
+                                   atol=5e-5 * np.abs(w).max())
+
+
+@pytest.mark.parametrize("solver", ["cg", "cholesky"])
+def test_hot_split_piecewise_solve_matches_global(small_slices,
+                                                  exact_cold_widths,
+                                                  monkeypatch, solver):
+    """Past SOLVE_EQ_BUDGET_BYTES every piece takes its own slice of the
+    other side's factors, each after the piece before is solved (the
+    train cell's path): the same factors as the one global solve, whose
+    tiers share one slice."""
+    import predictionio_tpu.models.als as als_mod
+    from predictionio_tpu.parallel.mesh import make_mesh
+
+    split, _, _ = _split_and_unsplit(monkeypatch)
+    mesh = make_mesh((1,), ("data",))
+    want = _run_steps(mesh, split, 2, solver=solver, cg_iters=48)
+    monkeypatch.setattr(als_mod, "SOLVE_EQ_BUDGET_BYTES", 1)
+    got = _run_steps(mesh, split, 2, solver=solver, cg_iters=48)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5,
+                                   atol=1e-5 * np.abs(w).max())
+
+
+def test_train_als_notes_what_the_layout_gathers(small_slices,
+                                                 exact_cold_widths, mesh8):
+    """`hotGatherShare`, `gatherRowsPerIteration` and the two slices' rows
+    ride the attempt's convergence record; a data set with nothing to
+    slice reports 0 for all three."""
+    from predictionio_tpu.obs.training import TRAINING
+    from tests.helpers import zipf_coo
+
+    def attempt(users, items, vals, nu, ni):
+        ratings = Ratings(
+            user_indices=users.astype(np.int32),
+            item_indices=items.astype(np.int32), ratings=vals,
+            user_ids=BiMap({f"u{i}": i for i in range(nu)}),
+            item_ids=BiMap({f"i{j}": j for j in range(ni)}))
+        train_als(ratings, ALSConfig(rank=4, iterations=1, chunk_cap=128),
+                  mesh=mesh8)
+        TRAINING.finish("train")
+        return TRAINING.summaries("train")[-1]
+
+    users, items, vals = zipf_coo(np.random.default_rng(9), 300, 200, 9_000)
+    rec = attempt(users, items, vals, 300, 200)
+    assert 100 < rec["hotSliceRowsItems"] <= 128
+    assert 100 < rec["hotSliceRowsUsers"] <= 128
+    assert 30 < rec["hotGatherShare"] < 100
+    assert rec["gatherRowsPerIteration"] >= 2 * 9_000
+    rng = np.random.default_rng(10)
+    rec = attempt(rng.integers(0, 60, 900), rng.integers(0, 40, 900),
+                  np.ones(900, np.float32), 60, 40)
+    assert rec["hotSliceRowsItems"] == rec["hotSliceRowsUsers"] == 0
+    assert rec["hotGatherShare"] == 0 and rec["gatherRowsPerIteration"] > 0

@@ -214,7 +214,15 @@ items = trip[:, 1].astype(np.int64)
 vals = trip[:, 2].astype(np.float32)
 nu, ni = %(nu)d, %(ni)d
 
+if %(split)r:
+    # gather costs that slice tables of test size (tests/helpers.py), so
+    # that the layout's hot parts travel too
+    from predictionio_tpu.ops import neighbors
+    neighbors.GATHER_NS_BY_TABLE_ROWS = ((8, 4.0),)
+    neighbors.COLD_WIDTH_SIGMAS = 0.0
 u_lay, i_lay = build_bilinear_layout(users, items, vals, nu, ni, seed=11)
+split_buckets = sum(b.hot_ids is not None
+                    for lay in (u_lay, i_lay) for b in lay.buckets)
 
 # 3. global block arrays assembled from per-process local slices
 u_bk = put_layout(u_lay, mesh)
@@ -240,17 +248,22 @@ for _ in range(3):
 uf = np.asarray(u)[u_lay.pos]
 vf = np.asarray(v)[i_lay.pos]
 print("RESULT " + json.dumps({
-    "pid": pid, "u": uf.tolist(), "v": vf.tolist()}), flush=True)
+    "pid": pid, "u": uf.tolist(), "v": vf.tolist(),
+    "split_buckets": split_buckets,
+    "shipped": sum("hot_ids" in e for e in u_bk + i_bk)}), flush=True)
 '''
 
 
 @pytest.mark.multihost
-def test_two_process_als_training_parity(tmp_path):
+@pytest.mark.parametrize("split", [False, True])
+def test_two_process_als_training_parity(tmp_path, split):
     """The Spark-executor replacement, end to end: two
     processes each load only their host_shard event slice, assemble the
     global blocked layout via jax.make_array_from_process_local_data, run
     the SHARED make_train_step over the cross-process mesh, and produce
-    factors matching single-process training."""
+    factors matching single-process training. ``split``: the tables are
+    sliced, so `put_layout` ships each split bucket's hot part as it
+    ships ids and vals, each process its own rows of them."""
     import numpy as np
 
     from predictionio_tpu.models.als import ALSConfig, train_als
@@ -263,10 +276,15 @@ def test_two_process_als_training_parity(tmp_path):
 
     nu, ni = 24, 16
     rng = np.random.default_rng(3)
+    density = np.full((nu, ni), 0.6)
+    if split:  # eight items that nearly everyone meets, and a long tail
+        ni = 48
+        density = np.full((nu, ni), 0.2)
+        density[:, :8] = 0.95
     u_true = rng.normal(size=(nu, 3)) + 1
     v_true = rng.normal(size=(ni, 3)) + 1
     full = u_true @ v_true.T
-    mask = rng.random((nu, ni)) < 0.6
+    mask = rng.random((nu, ni)) < density
     rows, cols = np.nonzero(mask)
     vals = np.round(full[rows, cols] * 2) / 2  # half-star: exact in f32
 
@@ -287,11 +305,15 @@ def test_two_process_als_training_parity(tmp_path):
 
     worker = tmp_path / "als_worker.py"
     worker.write_text(ALS_WORKER_SRC % {
-        "repo": str(REPO), "max_local": len(rows), "nu": nu, "ni": ni})
+        "repo": str(REPO), "max_local": len(rows), "nu": nu, "ni": ni,
+        "split": split})
     addr = f"127.0.0.1:{_free_port()}"
     results = _run_workers(worker, lambda pid: [str(pid), "2", addr, db_path],
                            300, "ALS multihost")
 
+    for r in results.values():
+        assert (r["split_buckets"] > 0) == split
+        assert r["shipped"] == r["split_buckets"]
     # both processes computed the same global model...
     u0 = np.asarray(results[0]["u"])
     u1 = np.asarray(results[1]["u"])

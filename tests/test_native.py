@@ -88,6 +88,27 @@ class TestNeighborBlocksParity:
             np.zeros(0, np.float32), 10, block_rows=8)
         np.testing.assert_array_equal(nat.ids, ref.ids)
 
+    @pytest.mark.parametrize("seed,d_hot_room", [(0, "wide"), (1, "tight")])
+    def test_hot_split_identical(self, seed, d_hot_room, monkeypatch):
+        """`_split_hot` through the C++ pass and through numpy: the same
+        widths and the same four arrays, spill and padding included."""
+        rows, cols, vals = _coo(6000, 200, 900, seed=seed)
+        if d_hot_room == "tight":  # most entries inside the slice: spill
+            cols = (cols % 300 + 500).astype(np.int32)
+        b = neighbors.build_neighbor_blocks(rows, cols, vals, 200,
+                                            block_rows=40, pad_id=999)
+        monkeypatch.setattr(neighbors, "COLD_WIDTH_SIGMAS", 0.0)
+        args = (b, 500, 301, 10**6, 999, 1.0 if d_hot_room == "tight" else 0.3)
+        nat = neighbors._split_hot(*args)
+        assert nat.hot_ids is not None
+        monkeypatch.setattr(neighbors.native, "hot_split_native",
+                            lambda *a: None)
+        ref = neighbors._split_hot(*args)
+        for name in ("ids", "vals", "hot_ids", "hot_vals"):
+            np.testing.assert_array_equal(getattr(nat, name),
+                                          getattr(ref, name))
+        assert nat.hot_ids.shape[2] + nat.ids.shape[2] == b.ids.shape[2]
+
     def test_bilinear_layout_uses_native(self):
         rows, cols, vals = _coo(4000, 200, 300, heavy_row=3, heavy_n=200)
         u_lay, i_lay = neighbors.build_bilinear_layout(

@@ -110,6 +110,53 @@ def test_bilinear_layout_invariants_property(nu, ni, n, seed, heavy, tiers,
         assert lay.slots % np.lcm(align, 8) == 0
 
 
+@settings(max_examples=40, deadline=None)
+@given(
+    nu=st.integers(8, 60), ni=st.integers(8, 60),
+    n=st.integers(50, 600), seed=st.integers(0, 999),
+    exponent=st.sampled_from([0.0, 0.9, 1.5]),  # 0.0: uniform popularity
+    chunk_cap=st.sampled_from([16, 64]),
+    slice_rows=st.sampled_from([8, 16, 24]),
+    sigmas=st.sampled_from([0.0, 1.0]),
+)
+def test_hot_split_layout_invariants_property(nu, ni, n, seed, exponent,
+                                              chunk_cap, slice_rows, sigmas):
+    """Whatever the skew and the slice's size: every rating lies in
+    exactly one part of one bucket, hot ids are local to the slice and
+    padded with its zero row, the cold part takes a neighbor of the slice
+    only from a row whose hot part is full, and a bucket's two widths add
+    up to the unsplit bucket's."""
+    from unittest import mock
+
+    from predictionio_tpu.ops import neighbors
+
+    rng = np.random.default_rng(seed)
+    p = 1.0 / np.arange(1, ni + 1) ** exponent
+    cols = rng.choice(ni, n, p=p / p.sum()).astype(np.int64)
+    q = 1.0 / np.arange(1, nu + 1) ** exponent
+    rows = rng.choice(nu, n, p=q / q.sum()).astype(np.int64)
+    vals = rng.random(n).astype(np.float32) + 0.5
+    build = lambda: neighbors.build_bilinear_layout(  # noqa: E731
+        rows, cols, vals, nu, ni, tiers=(8, 64), chunk_cap=chunk_cap)
+    with mock.patch.object(neighbors, "GATHER_NS_BY_TABLE_ROWS",
+                           ((slice_rows, 4.0),)), \
+            mock.patch.object(neighbors, "COLD_WIDTH_SIGMAS", sigmas):
+        u_lay, i_lay = build()
+    with mock.patch.object(neighbors, "GATHER_NS_BY_TABLE_ROWS", ()):
+        u_old, i_old = build()
+    for lay, other, old in ((u_lay, i_lay, u_old), (i_lay, u_lay, i_old)):
+        assert_layout_invariants(lay, other, vals, n)
+        for b, b_old in zip(lay.buckets, old.buckets):
+            hot_width = 0 if b.hot_ids is None else b.hot_ids.shape[2]
+            assert b.ids.shape[2] + hot_width == b_old.ids.shape[2]
+            assert b.ids.shape[1] - b_old.ids.shape[1] in (0, 8)
+        assert 0 <= lay.hot_rows <= slice_rows
+        if not lay.hot_rows:
+            assert all(b.hot_ids is None for b in lay.buckets)
+            assert other.pos.tobytes() == (
+                i_old if lay is u_lay else u_old).pos.tobytes()
+
+
 # ---------------------------------------------------------------------------
 # Event wire codec: to_api_dict ∘ from_api_dict must be the identity on
 # every valid event — searched over unicode ids, nested property values,

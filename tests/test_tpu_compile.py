@@ -134,25 +134,17 @@ def test_topk_kernel_at_the_ranks_a_template_may_train(v5e, rank):
         _compile_kernel(v5e[0], CELL_ITEMS, b_pad, k_pad, rank=rank)
 
 
-@pytest.mark.parametrize("n_dev", [1, 4])
-def test_als_train_step(v5e, n_dev):
-    """`make_train_step` over a small layout on the 1x and the 4x1 data
-    mesh `pio train` builds by default (`make_mesh()` takes every device)."""
-    rng = np.random.default_rng(0)
-    nu, ni, n = 512, 256, 8_000
-    u_lay, i_lay = build_bilinear_layout(
-        rng.integers(0, nu, n), rng.integers(0, ni, n),
-        (np.round(rng.random(n) * 9 + 1) / 2).astype(np.float32), nu, ni)
+def _compile_train_step(v5e, n_dev, u_lay, i_lay):
     mesh = Mesh(np.asarray(v5e[:n_dev]), ("data",))
     blk, rep = _layout_shardings(mesh)
 
     def spec(lay):
         out = []
         for b, m in zip(lay.buckets, lay.metas):
-            e = {"ids": jax.ShapeDtypeStruct(b.ids.shape, b.ids.dtype,
-                                             sharding=blk),
-                 "vals": jax.ShapeDtypeStruct(b.vals.shape, b.vals.dtype,
-                                              sharding=blk)}
+            e = {name: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=blk)
+                 for name, a in (("ids", b.ids), ("vals", b.vals),
+                                 ("hot_ids", b.hot_ids),
+                                 ("hot_vals", b.hot_vals)) if a is not None}
             if m.seg is not None:
                 e["seg"] = jax.ShapeDtypeStruct(m.seg.shape, m.seg.dtype,
                                                 sharding=rep)
@@ -160,11 +152,122 @@ def test_als_train_step(v5e, n_dev):
         return out
 
     fac = NamedSharding(mesh, P(None, None))
-    make_train_step(mesh, u_lay, i_lay, rank=RANK, lambda_=0.01).lower(
+    return make_train_step(mesh, u_lay, i_lay, rank=RANK, lambda_=0.01).lower(
         spec(u_lay), spec(i_lay),
         jax.ShapeDtypeStruct((u_lay.slots, RANK), jnp.float32, sharding=fac),
         jax.ShapeDtypeStruct((i_lay.slots, RANK), jnp.float32, sharding=fac),
     ).compile()
+
+
+def _factor_row_gathers(hlo):
+    """(rows of the gathered table, rows gathered, table kept in VMEM?,
+    the gather's `integer_config`) of every factor-row gather inside a
+    `lax.map` body of a compiled program. A fused gather's operand is a
+    parameter of its computation, declared there with its memory space
+    (`S(1)`: VMEM); the configuration is on the fusion's call."""
+    import re
+
+    comps = {}
+    for comp in re.split(r"\n\n", hlo):
+        head = re.match(r"%?(\S+) \(", comp.lstrip("\n"))
+        if head:
+            comps[head.group(1)] = comp
+    out = []
+    for call in re.finditer(r"fusion\([^\n]*calls=%?([\w.]+)[^\n]*", hlo):
+        comp = comps.get(call.group(1), "")
+        m = re.search(r"= f32\[(\d+),(\d+),\d+\]\S* gather\(%?([\w.-]+), "
+                      r"[^\n]*while/body", comp)
+        if not m:
+            continue
+        table = re.search(r"%?" + re.escape(m.group(3))
+                          + r" = f32\[(\d+),\d+\]\{([^}]*)\}", comp)
+        config = re.search(r'"integer_config":\{"integer":"(\d+)"',
+                           call.group(0))
+        out.append((int(table.group(1)), int(m.group(1)) * int(m.group(2)),
+                    "S(1)" in table.group(2), int(config.group(1))))
+    return out
+
+
+@pytest.mark.parametrize("nb,b,d_cold,d_hot,table_rows,chunked", [
+    (52, 960, 1016, 1032, 1_001_568, True),   # als-kdd11's item side
+    (6, 1584, 560, 584, 1_001_568, False),
+    (6, 8104, 136, 88, 1_001_568, False),
+    (7, 960, 488, 1560, 625_392, True),       # and its user side
+    (9, 6752, 88, 200, 625_392, False),
+    (15, 8176, 16, 16, 625_392, False),
+])
+def test_hot_slice_is_gathered_from_vmem(v5e, nb, b, d_cold, d_hot,
+                                         table_rows, chunked):
+    """What makes a hot row cheap, read off the compiled `_gram_blocks` at
+    the train cell's own bucket shapes (the probe's times are the chip's;
+    the compiler's choices behind them show here): the 147,456-row slice
+    is kept in VMEM across the `lax.map` (the hot part must come first in
+    the body for that), the whole table is not, and a cold block whose
+    count of ids is no multiple of 1,024 gets the wider of the two
+    HBM-gather configurations."""
+    from predictionio_tpu.models.als import _gram_blocks
+    from predictionio_tpu.ops.neighbors import GATHER_NS_BY_TABLE_ROWS
+
+    slice_rows = GATHER_NS_BY_TABLE_ROWS[-1][0]
+    assert (b * d_cold) % 1024
+
+    def run(table, ids, vals, hot_ids, hot_vals):
+        hot = jax.lax.slice_in_dim(table, 1000, 1000 + slice_rows)
+        kw = {} if chunked else dict(out_dtype=jnp.float32, with_diag=True)
+        return _gram_blocks(ids, vals, table, implicit=False, alpha=1.0,
+                            rank=RANK, hot=(hot, hot_ids, hot_vals), **kw)
+
+    one = SingleDeviceSharding(v5e[0])
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    hlo = jax.jit(run).lower(
+        sds((table_rows, RANK), jnp.float32),
+        sds((nb, b, d_cold), jnp.int32), sds((nb, b, d_cold), jnp.float32),
+        sds((nb, b, d_hot), jnp.int32), sds((nb, b, d_hot), jnp.float32),
+    ).compile().as_text()
+    assert sorted(_factor_row_gathers(hlo)) == [
+        (slice_rows, b * d_hot, True, 0), (table_rows, b * d_cold, False, 256)]
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_als_train_step(v5e, n_dev):
+    """`make_train_step` over a small layout on the 1x and the 4x1 data
+    mesh `pio train` builds by default (`make_mesh()` takes every device).
+    Uniform popularity: nothing is split, one gather a tier."""
+    rng = np.random.default_rng(0)
+    nu, ni, n = 512, 256, 8_000
+    u_lay, i_lay = build_bilinear_layout(
+        rng.integers(0, nu, n), rng.integers(0, ni, n),
+        (np.round(rng.random(n) * 9 + 1) / 2).astype(np.float32), nu, ni)
+    hlo = _compile_train_step(v5e, n_dev, u_lay, i_lay).as_text()
+    tables = [g[0] for g in _factor_row_gathers(hlo)]
+    assert sorted(tables) == sorted(
+        [i_lay.slots] * len(u_lay.buckets) + [u_lay.slots] * len(i_lay.buckets))
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_als_train_step_with_hot_slices(v5e, n_dev, monkeypatch):
+    """A Zipf data set whose tables are sliced: every split tier compiles
+    to two gathers, one of them from an operand of the slice's rows (the
+    slice is materialised once a half-step, not fused back into a gather
+    from the whole table, whose size is what makes a row slow)."""
+    from predictionio_tpu.ops import neighbors
+    from tests.helpers import SMALL_HOT_SLICES, zipf_coo
+
+    monkeypatch.setattr(neighbors, "GATHER_NS_BY_TABLE_ROWS",
+                        SMALL_HOT_SLICES)
+    users, items, vals = zipf_coo(np.random.default_rng(0))
+    u_lay, i_lay = build_bilinear_layout(users, items, vals, 600, 400,
+                                         chunk_cap=256)
+    assert 100 < u_lay.hot_rows <= 128 and 100 < i_lay.hot_rows <= 128
+    gathers = _factor_row_gathers(
+        _compile_train_step(v5e, n_dev, u_lay, i_lay).as_text())
+    for lay in (u_lay, i_lay):
+        assert sum(b.hot_ids is not None for b in lay.buckets) >= 3
+    tables = sorted(g[0] for g in gathers)
+    assert tables == sorted(
+        [i_lay.slots] * len(u_lay.buckets) + [u_lay.slots] * len(i_lay.buckets)
+        + [lay.hot_rows for lay in (u_lay, i_lay) for b in lay.buckets
+           if b.hot_ids is not None])
 
 
 def test_sharded_retriever_four_ways(v5e):

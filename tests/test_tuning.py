@@ -73,6 +73,45 @@ class TestTrainAlsGrid:
             assert np.array_equal(packed.item_factors,
                                   serial.item_factors), cfg
 
+    @pytest.mark.parametrize("piecewise", [False, True])
+    def test_parity_with_serial_over_a_split_layout(self, mesh8, monkeypatch,
+                                                    piecewise):
+        """The grid vmaps `_solve_side` over its lanes; where the layout's
+        buckets are split, each lane takes its own hot slices (piece by
+        piece past the equation budget, the slice's start then a lane's
+        own value): the same factors as the serial trainer's."""
+        import predictionio_tpu.models.als as als_mod
+        from predictionio_tpu.ops import neighbors
+        from predictionio_tpu.storage.bimap import BiMap
+        from predictionio_tpu.storage.frame import Ratings
+        from tests.helpers import SMALL_HOT_SLICES, zipf_coo
+
+        monkeypatch.setattr(neighbors, "GATHER_NS_BY_TABLE_ROWS",
+                            SMALL_HOT_SLICES)
+        monkeypatch.setattr(neighbors, "COLD_WIDTH_SIGMAS", 0.0)
+        if piecewise:
+            monkeypatch.setattr(als_mod, "SOLVE_EQ_BUDGET_BYTES", 1)
+        users, items, vals = zipf_coo(np.random.default_rng(3), 300, 200,
+                                      9_000)
+        ratings = Ratings(
+            user_indices=users.astype(np.int32),
+            item_indices=items.astype(np.int32), ratings=vals,
+            user_ids=BiMap({f"u{i}": i for i in range(300)}),
+            item_ids=BiMap({f"i{j}": j for j in range(200)}))
+        configs = [ALSConfig(rank=6, iterations=2, lambda_=lam, seed=7,
+                             chunk_cap=128) for lam in (0.05, 0.2)]
+        grid = train_als_grid(ratings, configs, mesh=mesh8)
+        for cfg, packed in zip(configs, grid):
+            serial = train_als(ratings, cfg, mesh=mesh8)
+            # not bitwise: a lane's gather of its slice is another program
+            # than the serial step's slice, and CG answers the rounding
+            np.testing.assert_allclose(packed.user_factors,
+                                       serial.user_factors, rtol=1e-4,
+                                       atol=1e-4)
+            np.testing.assert_allclose(packed.item_factors,
+                                       serial.item_factors, rtol=1e-4,
+                                       atol=1e-4)
+
     def test_mixed_alpha_implicit_parity(self, mesh8, rng):
         """α is the third sweepable axis (implicit confidence scale).
 
