@@ -67,6 +67,8 @@ from ..faults import FAULTS
 from ..obs.device import LEDGER, device_identity
 from ..obs.flight import FLIGHT
 from ..obs.http import handle_metrics, make_trace_middleware
+from ..obs.ingress_lines import (IngressLines, buffered_access_logger,
+                                 note_serve_ingress)
 from ..obs.metrics import METRICS
 from ..obs.replay import PROVENANCE_HEADER
 from ..obs.training import TRAINING
@@ -511,6 +513,9 @@ class EngineServer:
         self.flight.configure(capacity=flight_capacity,
                               dump_dir=flight_dump_dir)
         self.flight.set_context_provider(self._flight_context)
+        # the query path's two log lines a request (serve.ingress, the
+        # access line), held for a turn of the loop and written in a batch
+        self.ingress = IngressLines()
         self._profiling = False  # one live jax.profiler window at a time
         # ISSUE 13: provenance envelope cache — assembled once per
         # (bundle, patch epoch, mode) and stamped (as a compact-JSON
@@ -1383,6 +1388,7 @@ class EngineServer:
             "waterfall": stage_summary(),
             "slo": self.slo.summary(),
             "flight": self.flight.stats(),
+            "ingress": self.ingress.stats(),
             "batching": self.batcher.stats() if self.batcher else None,
             "execCache": EXEC_CACHE.stats(),
             # ISSUE 7: the active retrieval mode + ANN index facts
@@ -1470,8 +1476,9 @@ async def handle_query(request: web.Request) -> web.Response:
         wf.meta["mode"] = server.mode
         wf.meta["variant"] = server.variant_id
         server.flight.record(wf.to_dict())
-        trace_event("serve.ingress", status=status_label,
-                    http=status, ms=round((time.perf_counter() - t0) * 1e3, 3))
+        # held, and written by the loop in a batch (obs/ingress_lines.py)
+        note_serve_ingress(primary.ingress, rid, status_label, status,
+                           round((time.perf_counter() - t0) * 1e3, 3))
         headers = {TRACE_HEADER: rid}
         # ISSUE 13: every response names exactly what served it — the
         # ROUTED variant's envelope (carries variantId, ISSUE 14)
@@ -2042,6 +2049,14 @@ def create_engine_server_app(server: EngineServer) -> web.Application:
 
     app.on_shutdown.append(_drain_server)
     app.on_cleanup.append(_close_batcher)
+    # the held log lines (a stub server in the tests holds none): the loop
+    # is about to stop and may never run the flush it was asked for
+    lines = getattr(server, "ingress", None)
+    if lines is not None:
+        async def _flush_ingress_lines(app):
+            lines.flush()
+
+        app.on_cleanup.append(_flush_ingress_lines)
     return app
 
 
@@ -2147,7 +2162,9 @@ def run_engine_server(
             # a fresh app per attempt: a failed bind runs the previous
             # app's cleanup hooks
             web.run_app(create_engine_server_app(server), host=ip,
-                        port=port, print=_bound)
+                        port=port, print=_bound,
+                        access_log_class=buffered_access_logger(
+                            server.ingress))
             if prewarm_failed.is_set():
                 raise SystemExit("executable prewarm failed; see the "
                                  "traceback above")
