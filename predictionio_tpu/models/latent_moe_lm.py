@@ -64,6 +64,7 @@ from typing import Any
 import numpy as np
 
 from ..storage.bimap import BiMap
+from .seq_common import rms_norm as _rms, rows_to_stream
 from .seq_serving import SequenceServingMixin
 
 __all__ = [
@@ -351,14 +352,6 @@ def _rotate(x, cos, sin):
 
 # -- the forward --------------------------------------------------------------
 
-def _rms(x, gain, eps):
-    import jax
-    import jax.numpy as jnp
-
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * gain
-
-
 def _grouped_matmul(x, w, sizes, cd):
     """x [rows, in] sorted by group, w [groups, in, out], sizes int32
     [groups] -> float32 [rows, out]: rows of group g times w[g]. On the
@@ -640,21 +633,6 @@ class LatentMoEModel(SequenceServingMixin):
                                     exclude_seen=exclude_seen)[0]
 
 
-def _rows_to_stream(seqs):
-    """Left-padded histories [B, L] as ONE stream of B x L tokens: a
-    segment a row (0 for the pads), position = index among the row's real
-    events."""
-    import jax.numpy as jnp
-
-    B, L = seqs.shape
-    real = seqs > 0
-    pos = jnp.maximum(jnp.cumsum(real, axis=1) - 1, 0)
-    seg = jnp.where(real, jnp.arange(1, B + 1)[:, None], 0)
-    return (seqs.reshape(B * L).astype(jnp.int32),
-            seg.reshape(B * L).astype(jnp.int32),
-            pos.reshape(B * L).astype(jnp.int32))
-
-
 def train_latent_moe(seqs: np.ndarray, user_ids: BiMap, item_ids: BiMap,
                      cfg: LatentMoEConfig, mesh=None) -> LatentMoEModel:
     """Next-item prediction over left-padded histories packed into one
@@ -679,7 +657,7 @@ def train_latent_moe(seqs: np.ndarray, user_ids: BiMap, item_ids: BiMap,
         B, L = inp.shape
         inp = jnp.pad(inp, ((0, 0), (width - L, 0)))
         tgt = jnp.pad(tgt, ((0, 0), (width - L, 0)))
-        toks, seg, pos = _rows_to_stream(inp)
+        toks, seg, pos = rows_to_stream(inp)
         h, _counts = forward_hidden(
             device_tree(p, cfg), cfg, toks, seg, pos, differentiable=True,
             expert_chunk_rows=toks.shape[0] * cfg.num_experts_per_tok)

@@ -56,6 +56,7 @@ from typing import Any
 import numpy as np
 
 from ..storage.bimap import BiMap
+from .seq_common import rms_norm as _rms, rows_as_streams
 from .seq_serving import SequenceServingMixin
 
 __all__ = [
@@ -186,14 +187,6 @@ def head_major(layers: dict, cfg: LoopedLMConfig) -> dict:
     return {name: one(name, w) for name, w in layers.items()}
 
 
-def _rms(x, gain, eps):
-    import jax
-    import jax.numpy as jnp
-
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * gain
-
-
 def _rope(x, pos, theta: float):
     """Rotary embedding, the rotate-half convention: x [R, S, H, hd]
     float32, pos [R, S]."""
@@ -284,17 +277,6 @@ def forward_hidden(params: dict, cfg: LoopedLMConfig, tokens, seg, pos):
                    jnp.zeros((R, S), jnp.int32), jnp.int32(0)),
             jnp.arange(L * T, dtype=jnp.int32))
     return h_exit, half, ran
-
-
-def _rows_to_stream(seqs):
-    """Left-padded histories [B, L] as a stream of B rows: segment 1 for
-    the real events (0 for the pads, which only see each other), position
-    = index among the real events."""
-    import jax.numpy as jnp
-
-    real = seqs > 0
-    pos = jnp.maximum(jnp.cumsum(real, axis=1) - 1, 0)
-    return seqs, real.astype(jnp.int32), pos.astype(jnp.int32)
 
 
 def encoder_program(cfg: LoopedLMConfig):
@@ -389,7 +371,7 @@ def train_looped_lm(seqs: np.ndarray, user_ids: BiMap, item_ids: BiMap,
         inp, tgt = batch[:, :-1], batch[:, 1:]
         h, _half, _ran = forward_hidden(
             {**p, "layers": head_major(p["layers"], cfg)}, cfg,
-            *_rows_to_stream(inp))
+            *rows_as_streams(inp))
         logits = jnp.einsum("bld,vd->blv", h, p["head"].astype(jnp.float32))
         mask = (tgt > 0).astype(jnp.float32)
         ce = optax.softmax_cross_entropy_with_integer_labels(logits, tgt)

@@ -194,6 +194,7 @@ class span:
 _OPERATION = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(")
 _OP_NAME = re.compile(r"op_name=\"([^\"]*)\"")
 _SCOPE = re.compile(r"(?:^|/)(pio\.[\w.]+)(?=/|$)")
+_OPERAND = re.compile(r"%[\w.\-]+")
 #: operations that only contain others: their time is their bodies'
 _CONTAINERS = frozenset({"while", "conditional", "call"})
 
@@ -208,7 +209,11 @@ def operation_key(line: str) -> str | None:
 
 class DeviceScopes:
     """operation -> the innermost ``pio.*`` named scope it was traced
-    under, over the programs recorded so far."""
+    under, over the programs recorded so far. An operation that carries
+    no ``op_name`` at all is the compiler's own (the copy it puts before
+    a reshape that is no bitcast in the layout it chose, an
+    asynchronous copy's two halves): it takes the scope of the
+    operations that read it, where they agree."""
 
     def __init__(self):
         self._map: dict[str, str] = {}
@@ -217,16 +222,40 @@ class DeviceScopes:
         """Read one compiled program's text; returns the operations
         named. The key holds the result's type, which holds the shapes:
         two programs' ``%fusion.7`` differ unless they compute alike."""
-        named = 0
+        scope_of: dict[str, str] = {}      # %name -> scope, this program's
+        nameless: dict[str, str] = {}      # %name -> key, no op_name at all
+        readers: dict[str, list] = {}      # %name -> the %names that read it
         for line in hlo_text.splitlines():
-            if "pio." not in line:
+            m = _OPERATION.match(line)
+            if m is None:
                 continue
-            m, op_name = _OPERATION.match(line), _OP_NAME.search(line)
-            if m is None or op_name is None or m.group(3) in _CONTAINERS:
+            name, opcode = m.group(1), m.group(3)
+            for operand in _OPERAND.findall(
+                    line[m.end():].split(")", 1)[0]):
+                readers.setdefault(operand, []).append(name)
+            if opcode in _CONTAINERS:
+                continue
+            op_name = _OP_NAME.search(line)
+            if op_name is None:
+                if opcode not in ("parameter", "constant"):
+                    nameless[name] = operation_key(line)
                 continue
             scopes = _SCOPE.findall(op_name.group(1))
             if scopes:
-                self._map[operation_key(line)] = scopes[-1]
+                scope_of[name] = self._map[operation_key(line)] = scopes[-1]
+
+        def read_under(name, seen=()):
+            if name in scope_of or name not in nameless or name in seen:
+                return scope_of.get(name)
+            found = {read_under(r, seen + (name,))
+                     for r in readers.get(name, ())}
+            return found.pop() if len(found) == 1 else None
+
+        named = len(scope_of)
+        for name, key in nameless.items():
+            scope = read_under(name)
+            if scope:
+                self._map[key] = scope
                 named += 1
         return named
 

@@ -219,7 +219,7 @@ PLAIN_MAX_L = 1024
 
 
 def attention_kernel_for(L: int, d_qk: int, d_v: int, *, backend: str,
-                         segmented: bool) -> str:
+                         segmented: bool, grouped: bool = False) -> str:
     """Which kernel ``flash_attention`` runs for a shape, by name:
     ``stock`` (the stock Pallas kernel: on the TPU, L a multiple of 128,
     q, k and v sharing a head size of 64 or 128), ``segment_flash``
@@ -228,10 +228,21 @@ def attention_kernel_for(L: int, d_qk: int, d_v: int, *, backend: str,
     / ``blockwise_attention``: off the TPU, or on it up to
     ``PLAIN_MAX_L``). A shape none admits on the TPU raises: the plain
     path at a long L would build gigabytes of scores behind the caller's
-    back."""
+    back.
+
+    ``grouped`` (fewer key/value heads than query heads, each shared by
+    a group of them) is ``segment_flash`` whatever the head size: the
+    stock kernel takes one key/value head a query head, so the shared
+    heads would be written out once a query head first (4 x the keys and
+    values of every layer at 32 over 8), where ``segment_flash``'s index
+    map reads query head ``h``'s keys from head ``h // group`` in place;
+    it also takes the caller's ``scale`` (the stock path fixes
+    ``1 / sqrt(D)``) and counts the pairs its mask let through."""
     if backend != "tpu":
         return "plain"
     if L % 128 == 0:
+        if grouped:
+            return "segment_flash"
         return "stock" if d_qk == d_v and d_v in (64, 128) else "segment_flash"
     if L <= PLAIN_MAX_L and (segmented or d_qk == d_v):
         return "plain"
@@ -406,8 +417,10 @@ def segment_flash_attention(q_parts, k_parts, v, segment_ids, *, scale,
     """Flash attention in which the scores are a SUM of contractions and
     the value's head size is its own: q_parts[i] [B, H, L, d_i] against
     k_parts[i] [B, H or 1, L, d_i] (a part with ONE key head is shared
-    by every query head: latent attention's rotary key), v [B, H, L,
-    d_v], ``segment_ids`` int32 [B, L]. A position sees the positions of
+    by every query head: latent attention's rotary key; a part, and v,
+    with G heads where G divides H is grouped-query attention: query head
+    h reads key/value head h // (H / G)), v [B, H or G, L, d_v],
+    ``segment_ids`` int32 [B, L]. A position sees the positions of
     its own segment (causal: those not after it). Only the block pairs
     whose segments can meet are grid steps at all (``_block_pairs``): a
     stream of packed histories costs its histories' triangles, not the
@@ -424,10 +437,15 @@ def segment_flash_attention(q_parts, k_parts, v, segment_ids, *, scale,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, L, d_v = v.shape
+    B, _, L, d_v = v.shape
+    H = q_parts[0].shape[1]
     if L % 128:
         raise ValueError(f"segment_flash_attention needs L % 128 == 0, "
                          f"got {L}")
+    for x in (*k_parts, v):
+        if H % x.shape[1]:
+            raise ValueError(f"{x.shape[1]} key/value heads do not divide "
+                             f"the {H} query heads")
     block = next(b for b in (block, 256, 128) if b <= block and L % b == 0)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -439,17 +457,19 @@ def segment_flash_attention(q_parts, k_parts, v, segment_ids, *, scale,
     def q_map(b, h, s, q_of, k_of, *_):
         return b, h, q_of[b, s], 0
 
-    def k_map(shared):
+    def k_map(heads):
         def index(b, h, s, q_of, k_of, *_):
-            return b, (0 if shared else h), k_of[b, s], 0
+            if heads in (1, H):
+                return b, (0 if heads == 1 else h), k_of[b, s], 0
+            return b, h // (H // heads), k_of[b, s], 0
         return index
 
     in_specs = [pl.BlockSpec((1, 1, block, x.shape[-1]), q_map)
                 for x in q_parts]
     in_specs += [pl.BlockSpec((1, 1, block, x.shape[-1]),
-                              k_map(x.shape[1] == 1)) for x in k_parts]
+                              k_map(x.shape[1])) for x in k_parts]
     in_specs += [
-        pl.BlockSpec((1, 1, block, d_v), k_map(False)),
+        pl.BlockSpec((1, 1, block, d_v), k_map(v.shape[1])),
         pl.BlockSpec((1, block, 1),
                      lambda b, h, s, q_of, k_of, *_: (b, q_of[b, s], 0)),
         pl.BlockSpec((1, 1, block),

@@ -9,7 +9,7 @@ events become per-user time-ordered item histories; a causal-attention
 model (models/seq_attention.py) predicts the next item; histories longer
 than one chip shard over a ``seq`` mesh axis via ring attention.
 
-Three algorithms, one serving route (models/seq_serving.py: `pio deploy` ->
+Four algorithms, one serving route (models/seq_serving.py: `pio deploy` ->
 micro-batcher -> serving pipeline -> the retriever's fused top-k):
 
 - ``seqrec``: the SASRec-style model above, learned positions, histories
@@ -56,6 +56,30 @@ micro-batcher -> serving pipeline -> the retriever's fused top-k):
           "first_layer": 0, "num_hidden_layers": 5,
           "first_expert": 0, "experts_held": 12, "max_len": 8192}}]
 
+- ``hybrid_ssm``: a hybrid state-space decoder
+  (models/hybrid_ssm_lm.py; granite-4.0-h-micro's block,
+  ``model_type: granitemoehybrid``): Mamba-2 layers (a depthwise causal
+  convolution and a selective scan whose state starts from zero at every
+  history inside a packed step) with grouped-query attention layers
+  between them in the order ``layer_types`` gives, no positional
+  encoding, a shared SwiGLU MLP after every mixer, a tied head. Its
+  params are the published config's keys; ``max_len`` up to the
+  8,192-token step, ``exclude_seen`` as for ``latent_moe``:
+
+      "algorithms": [{"name": "hybrid_ssm", "params": {
+          "hidden_size": 2048, "num_hidden_layers": 40,
+          "layer_types": ["mamba", "mamba", "mamba", "mamba", "mamba",
+                          "attention", "mamba", "mamba", "mamba", "mamba",
+                          "... the 40 of the published config ..."],
+          "num_attention_heads": 32, "num_key_value_heads": 8,
+          "attention_multiplier": 0.015625, "embedding_multiplier": 12,
+          "residual_multiplier": 0.22, "logits_scaling": 8,
+          "shared_intermediate_size": 8192, "mamba_n_heads": 64,
+          "mamba_d_head": 64, "mamba_d_state": 128, "mamba_n_groups": 1,
+          "mamba_d_conv": 4, "mamba_expand": 2, "mamba_chunk_size": 256,
+          "rms_norm_eps": 1e-05, "position_embedding_type": "nope",
+          "tie_word_embeddings": true, "max_len": 8192}}]
+
 Query:  {"user": "u1", "num": 4}
 Result: {"itemScores": [{"item": "i1", "score": 3.2}, ...]}
 """
@@ -75,6 +99,11 @@ from predictionio_tpu.controller import (
     Params,
     Preparator,
     SanityCheck,
+)
+from predictionio_tpu.models.hybrid_ssm_lm import (
+    HybridSSMConfig,
+    HybridSSMModel,
+    train_hybrid_ssm,
 )
 from predictionio_tpu.models.latent_moe_lm import (
     LatentMoEConfig,
@@ -172,6 +201,13 @@ class LatentMoEParams(Params):
     batch_size: int = 16
     lr: float = 1e-3
     seed: int = 0
+
+
+@dataclass(frozen=True)
+class HybridSSMParams(HybridSSMConfig, Params):
+    """models/hybrid_ssm_lm.py's ``HybridSSMConfig`` as it is: the
+    published config's keys (defaults granite-4.0-h-micro's), then
+    serving and training."""
 
 
 @dataclass(frozen=True)
@@ -294,12 +330,27 @@ class LatentMoEAlgorithm(SeqRecAlgorithm):
         return train_latent_moe(seqs, uids, iids, cfg, mesh=ctx.mesh)
 
 
+class HybridSSMAlgorithm(SeqRecAlgorithm):
+    """The hybrid state-space decoder behind the same queries and the
+    same route."""
+
+    params_class = HybridSSMParams
+
+    def train(self, ctx, td: TrainingData) -> HybridSSMModel:
+        cfg = HybridSSMConfig(**dataclasses.asdict(self.params))
+        seqs, uids, iids = build_sequences(
+            td.users, td.items, td.times, max_len=cfg.max_len
+        )
+        return train_hybrid_ssm(seqs, uids, iids, cfg, mesh=ctx.mesh)
+
+
 def engine_factory() -> Engine:
     return Engine(
         data_source_classes=SeqDataSource,
         preparator_classes=SeqPreparator,
         algorithm_classes={"seqrec": SeqRecAlgorithm,
                            "looped": LoopedAlgorithm,
-                           "latent_moe": LatentMoEAlgorithm},
+                           "latent_moe": LatentMoEAlgorithm,
+                           "hybrid_ssm": HybridSSMAlgorithm},
         serving_classes=FirstServing,
     )

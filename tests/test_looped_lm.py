@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 from predictionio_tpu.models import looped_lm as lm
+from predictionio_tpu.models.seq_common import rows_as_streams
 from predictionio_tpu.models.seq_serving import k_lattice
 from predictionio_tpu.storage.bimap import BiMap
 from predictionio_tpu.testing import looped_lm_reference as ref
@@ -124,7 +125,7 @@ def test_left_padded_rows_and_the_packed_stream_agree():
     params = device_tree(model)
     rows = model.seqs[[3, 5, 8, 13]]
     h_rows, _half, _ran = lm.forward_hidden(params, CFG,
-                                      *lm._rows_to_stream(jnp.asarray(rows)))
+                                      *rows_as_streams(jnp.asarray(rows)))
     lens = (rows > 0).sum(axis=1)
     tokens = np.concatenate([r[r > 0] for r in rows])
     seg = np.repeat(np.arange(1, 5), lens)

@@ -14,6 +14,7 @@ time. `python chip_smoke.py` on the chip does that.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -334,16 +335,11 @@ def test_topk_kernel_at_the_looped_decoders_head(v5e, b_pad):
     _assert_one_kernel_and_the_packed_result(exe, b_pad, 528)
 
 
-@pytest.fixture(scope="module")
-def looped_step(v5e):
-    """t_pad -> the compiled encoder executable of `pio deploy` at
-    Ouro-2.6B's published widths, from the tree `LoopedEncoder` puts on
-    the device (the public shapes through `head_major`); each compiled
-    once for the tests below."""
+def _looped_params(one):
+    """The tree `LoopedEncoder` puts on the device at Ouro-2.6B's
+    published widths, as shapes: the public ones through `head_major`."""
     from predictionio_tpu.models import looped_lm as lm
-    from predictionio_tpu.ops.pipeline import _encoder_fn
 
-    one = SingleDeviceSharding(v5e[0])
     cfg = lm.LoopedLMConfig()
     shapes = lm.param_shapes(cfg, SEQ_ITEMS + 1)
 
@@ -355,12 +351,26 @@ def looped_step(v5e):
         lambda t: lm.head_major(t, cfg),
         {k: arg(v, k in lm._LAYER_SHAPES)
          for k, v in shapes["layers"].items()})
-    params = {"embed": arg(shapes["embed"], True),
-              "norm_f": arg(shapes["norm_f"], False),
-              "gate_w": arg(shapes["gate_w"], False),
-              "gate_b": arg((), False),
-              "layers": {k: arg(v.shape, k in lm._LAYER_SHAPES)
-                         for k, v in layers.items()}}
+    return {"embed": arg(shapes["embed"], True),
+            "norm_f": arg(shapes["norm_f"], False),
+            "gate_w": arg(shapes["gate_w"], False),
+            "gate_b": arg((), False),
+            "layers": {k: arg(v.shape, k in lm._LAYER_SHAPES)
+                       for k, v in layers.items()}}
+
+
+@pytest.fixture(scope="module")
+def looped_step(v5e):
+    """t_pad -> the compiled encoder executable of `pio deploy` at
+    Ouro-2.6B's published widths, from the tree `LoopedEncoder` puts on
+    the device (the public shapes through `head_major`); each compiled
+    once for the tests below."""
+    from predictionio_tpu.models import looped_lm as lm
+    from predictionio_tpu.ops.pipeline import _encoder_fn
+
+    one = SingleDeviceSharding(v5e[0])
+    cfg = lm.LoopedLMConfig()
+    params = _looped_params(one)
     cap = lm.STEP_TOKEN_BUDGET + 8
 
     @functools.lru_cache(maxsize=None)
@@ -549,6 +559,28 @@ def test_topk_kernel_at_the_latent_decoders_head(v5e, b_pad):
     _assert_one_kernel_and_the_packed_result(exe, b_pad, 16)
 
 
+def _latent_params(one):
+    """The tree `LatentMoEEncoder` puts on the device at A.X-K1's
+    published widths, as shapes."""
+    from predictionio_tpu.models import latent_moe_lm as lm
+
+    cfg = lm.LatentMoEConfig()
+    shapes = lm.param_shapes(cfg, AXK1_ITEMS + 1)
+
+    def arg(name, shape):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.float32 if lm._is_float32_leaf(name) else jnp.bfloat16,
+            sharding=one)
+
+    public = {"embed": arg("embed", shapes["embed"]),
+              "norm_f": arg("norm_f", shapes["norm_f"]),
+              "layers": {i: {k: arg(k, s) for k, s in layer.items()}
+                         for i, layer in shapes["layers"].items()}}
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        jax.eval_shape(lambda p: lm.device_tree(p, cfg), public))
+
+
 def test_latent_moe_serving_step_at_its_budget(v5e):
     """The encoder executable of `pio deploy` at A.X-K1's published
     widths for a full step of 8,192 tokens, from the tree
@@ -566,20 +598,7 @@ def test_latent_moe_serving_step_at_its_budget(v5e):
 
     one = SingleDeviceSharding(v5e[0])
     cfg = lm.LatentMoEConfig()
-    shapes = lm.param_shapes(cfg, AXK1_ITEMS + 1)
-
-    def arg(name, shape):
-        return jax.ShapeDtypeStruct(
-            shape, jnp.float32 if lm._is_float32_leaf(name) else jnp.bfloat16,
-            sharding=one)
-
-    public = {"embed": arg("embed", shapes["embed"]),
-              "norm_f": arg("norm_f", shapes["norm_f"]),
-              "layers": {i: {k: arg(k, s) for k, s in layer.items()}
-                         for i, layer in shapes["layers"].items()}}
-    params = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
-        jax.eval_shape(lambda p: lm.device_tree(p, cfg), public))
+    params = _latent_params(one)
     t_pad = lm.STEP_TOKEN_BUDGET
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
@@ -606,3 +625,267 @@ def test_latent_moe_serving_step_at_its_budget(v5e):
     # a container's time is its body's: none is named
     assert not any(k.endswith((" while", " conditional", " call"))
                    for k in scopes.snapshot())
+
+
+# ---------------------------------------------------------------------------
+# the hybrid state-space cell
+# (benchmarks/configs/granite-4.0-h-micro-seqrec.json): the attention
+# kernel at 32 query heads over 8 key/value heads of 64, the head at rank
+# 2048 over 100,351 rows, and the serving step over its token lattice,
+# shapes only; and what the two other decoders lower to, pinned
+
+GRANITE_ITEMS = 100_351
+
+
+def test_segment_flash_kernel_at_the_grouped_heads(v5e):
+    """32 query heads of 64 over 8 key/value heads, a stream of 8,192
+    packed tokens, the published scale 1/64: one Mosaic kernel whose
+    index map reads query head h's keys and values from head h // 4 in
+    place (nothing repeated in memory), and the count of unmasked pairs
+    beside the output."""
+    from predictionio_tpu.parallel.ring_attention import (
+        segment_flash_attention)
+
+    one = SingleDeviceSharding(v5e[0])
+    H, KV, L = 32, 8, 8192
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def fn(q, k, v, seg):
+        return segment_flash_attention((q,), (k,), v, seg, scale=1.0 / 64,
+                                       interpret=False)
+
+    exe = jax.jit(fn).lower(arg(1, H, L, 64), arg(1, KV, L, 64),
+                            arg(1, KV, L, 64), arg(1, L, dtype=jnp.int32)
+                            ).compile()
+    hlo = exe.as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert exe.memory_analysis().output_size_in_bytes >= H * L * 64 * 2
+    assert "bf16[1,32,8192,64]" in hlo       # the queries' and the output's
+    # no key or value array at the query heads' count was made
+    assert not re.search(r"= bf16\[1,32,8192,64\][^ ]* (broadcast|concatenate"
+                         r"|gather)\(", hlo)
+
+
+@pytest.mark.parametrize("b_pad", [8, 128])
+def test_topk_kernel_at_the_hybrid_decoders_head(v5e, b_pad):
+    """[B, 2048] x [2048, 100,352] + top-k at k 16 over the TIED table:
+    the looped cell's program at twice its rows."""
+    from predictionio_tpu.models.hybrid_ssm_lm import STEP_TOKEN_BUDGET
+
+    one = SingleDeviceSharding(v5e[0])
+    d_pad, n_pad = _padded_shape(GRANITE_ITEMS, 2048)
+    assert d_pad == 2048
+    raw = _raw_call(b_pad, d_pad, n_pad, GRANITE_ITEMS, 16, False)
+    exe = jax.jit(_fused_fn(raw, True), donate_argnums=(0,)).lower(
+        jax.ShapeDtypeStruct((b_pad,), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((STEP_TOKEN_BUDGET + 8, 2048), jnp.float32,
+                             sharding=one),
+        jax.ShapeDtypeStruct((d_pad, n_pad), jnp.float32, sharding=one),
+    ).compile()
+    _assert_one_kernel_and_the_packed_result(exe, b_pad, 16)
+
+
+def _hybrid_params(one):
+    """The tree `HybridSSMEncoder` puts on the device at
+    granite-4.0-h-micro's published widths, as shapes: the public ones."""
+    from predictionio_tpu.models import hybrid_ssm_lm as lm
+
+    shapes = lm.param_shapes(lm.HybridSSMConfig(), GRANITE_ITEMS + 1)
+
+    def arg(name, shape):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.float32 if name in lm._FLOAT32 else jnp.bfloat16,
+            sharding=one)
+
+    return {k: ({n: arg(n, s) for n, s in v.items()}
+                if isinstance(v, dict) else arg(k, v))
+            for k, v in shapes.items()}
+
+
+@pytest.fixture(scope="module")
+def hybrid_step(v5e):
+    """t_pad -> the compiled encoder executable of `pio deploy` at
+    granite-4.0-h-micro's published widths, from the tree
+    `HybridSSMEncoder` puts on the device (the public shapes)."""
+    from predictionio_tpu.models import hybrid_ssm_lm as lm
+    from predictionio_tpu.ops.pipeline import _encoder_fn
+
+    one = SingleDeviceSharding(v5e[0])
+    cfg = lm.HybridSSMConfig()
+    params = _hybrid_params(one)
+
+    @functools.lru_cache(maxsize=None)
+    def compiled(t_pad):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            return jax.jit(_encoder_fn(
+                lm.encoder_program(cfg), lm.STEP_TOKEN_BUDGET + 8, 2048)
+            ).lower(jax.ShapeDtypeStruct((3, t_pad), jnp.int32,
+                                         sharding=one), params).compile()
+
+    return compiled
+
+
+@pytest.mark.parametrize("t_pad", [1024, 2048, 4096, 8192])
+def test_hybrid_serving_step_at_its_token_lattice(hybrid_step, t_pad):
+    """Every lattice point of `HybridSSMEncoder`: one `while` a run of
+    Mamba layers in the published order (5, 9, 9, 9, 4: five) and one
+    more inside each body (the states carried between chunks), no
+    conditional, the FOUR attention kernels at the program's top level,
+    6.4 GB of bfloat16 weights as arguments and no copy of a Mamba or MLP
+    stack (each layer is sliced from its stack where it lies), a step's
+    query table and the three counters out, and temporaries that
+    leave the chip room."""
+    from predictionio_tpu.obs.trace import DeviceScopes
+
+    exe = hybrid_step(t_pad)
+    hlo = exe.as_text()
+    assert hlo.count(" while(") == 10 and hlo.count(" conditional(") == 0
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 4
+    assert "segment_flash_attention" in hlo
+    assert not re.search(r"= bf16\[(36|40),\d+,\d+\][^ ]* copy\(", hlo)
+    mem = exe.memory_analysis()
+    assert 6.35e9 < mem.argument_size_in_bytes < 6.45e9
+    assert mem.output_size_in_bytes >= (8192 + 8) * 2048 * 4
+    assert mem.temp_size_in_bytes < 1.2e9 * t_pad / 8192 + 0.1e9
+    scopes = DeviceScopes()
+    assert scopes.record(hlo) > 100
+    assert {"pio.seq.embed", "pio.seq.ssm_in_proj", "pio.seq.ssm_conv",
+            "pio.seq.ssm_scan", "pio.seq.ssm_gate_out", "pio.seq.attn_proj",
+            "pio.seq.gqa_attn", "pio.seq.mlp"} <= set(
+                scopes.snapshot().values())
+    assert not any(k.endswith((" while", " conditional", " call"))
+                   for k in scopes.snapshot())
+    # The map CONTAINS the mixer: of what the operations in the bodies
+    # of the five Mamba runs write, under 1% stands under no scope or
+    # under `pio.seq.layers` alone (where the convolution's fusion,
+    # named after the run's call by a convert the compiler hoists, and
+    # the two nameless relayouts of [t_pad, 4096] a layer before the
+    # scan's reshapes would read, a third of the mixer's device time),
+    # and what reads `pio.seq.ssm_run` alone is the convolution's fusion
+    # and crumbs of the cumulative sums.
+    written = _bytes_written_by_scope(hlo, scopes.snapshot())
+    assert sum(written.values()) > 2e9 * t_pad / 8192
+    stray = written.get("", 0) + written.get("pio.seq.layers", 0)
+    assert stray < 0.01 * sum(written.values()), written
+    assert {"pio.seq.ssm_in_proj", "pio.seq.ssm_conv", "pio.seq.ssm_scan",
+            "pio.seq.ssm_run", "pio.seq.ssm_gate_out", "pio.seq.mlp"
+            } >= set(written) - {"", "pio.seq.layers"}
+    conv = t_pad * 4352 * (4 + 2)      # float32 for x, bfloat16 for B and C
+    assert conv <= written["pio.seq.ssm_run"] < 1.1 * conv
+    assert written["pio.seq.ssm_scan"] > 4 * t_pad * 4096 * 4
+
+
+_ARRAY = re.compile(r"(pred|s8|u8|bf16|f16|s32|u32|f32)\[([\d,]*)\]")
+_WIDTH = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+          "u32": 4, "f32": 4}
+
+
+def _bytes_written_by_scope(hlo: str, scope_of: dict) -> dict:
+    """{scope: bytes of the results} over the operations of ONE body of a
+    run of Mamba layers (a `while` body that holds the chunk states'
+    `while`), by the capture's operation-to-scope map; what moves no
+    data of its own is left out."""
+    from predictionio_tpu.obs.trace import operation_key
+
+    bodies, at = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            at = bodies.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            at = None
+        elif at is not None:
+            at.append(line)
+    runs = [bodies[b] for b in re.findall(r" while\(.*?body=(%[\w.\-]+)", hlo)
+            if any(" while(" in line for line in bodies[b])]
+    assert len(runs) == 5
+    out: dict = {}
+    for line in runs[0]:
+        m = re.match(r"^\s*(?:ROOT )?%[\w.\-]+ = (.+?) ([\w\-]+)\(", line)
+        if m is None or m.group(2) in (
+                "parameter", "get-tuple-element", "tuple", "constant",
+                "bitcast", "while"):
+            continue
+        size = sum(_WIDTH[t] * int(np.prod([int(d) for d in dims.split(",")
+                                            if d] or [1]))
+                   for t, dims in _ARRAY.findall(m.group(1)))
+        scope = scope_of.get(operation_key(line), "")
+        out[scope] = out.get(scope, 0) + size
+    return out
+
+
+def _without_kernel_bodies(lowered_text: str) -> str:
+    """A lowered program's text with every Mosaic kernel's serialized
+    body taken out: the body holds the checkout's path and the source
+    lines of every frame, which differ between two checkouts of one
+    program."""
+    return re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", lowered_text)
+
+
+def _digest(text: str) -> str:
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def test_the_looped_encoders_lowered_program_is_what_it_was(v5e):
+    """PR 44 moved `_rms` and the stream layouts into models/seq_common.py
+    and gave `attention_kernel_for` a `grouped` argument: the looped
+    decoder's serving step at 1,024 tokens lowers to the text it lowered
+    to at the parent commit (kernel bodies apart, which hold source
+    lines). A PR that changes this program on purpose brings the new
+    digest and says so."""
+    from predictionio_tpu.models import looped_lm as lm
+    from predictionio_tpu.ops.pipeline import _encoder_fn
+
+    one = SingleDeviceSharding(v5e[0])
+    cfg = lm.LoopedLMConfig()
+    params = _looped_params(one)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        text = jax.jit(_encoder_fn(
+            lm.encoder_program(cfg), lm.STEP_TOKEN_BUDGET + 8, 2048)).lower(
+            jax.ShapeDtypeStruct((3, 1024), jnp.int32, sharding=one),
+            params).as_text()
+    assert _digest(_without_kernel_bodies(text)) == "fbc17a4fe9535752"
+
+
+def test_the_latent_encoders_lowered_program_is_what_it_was(v5e):
+    """The latent decoder's serving step at 8,192 tokens, as above; and
+    the segment kernel itself at the latent heads, as the jaxpr its
+    `pallas_call` holds (source lines apart): the grouped heads added a
+    branch to an index map that the shared and the per-head layouts do
+    not take."""
+    from predictionio_tpu.models import latent_moe_lm as lm
+    from predictionio_tpu.ops.pipeline import _encoder_fn
+    from predictionio_tpu.parallel.ring_attention import (
+        segment_flash_attention)
+
+    one = SingleDeviceSharding(v5e[0])
+    cfg = lm.LatentMoEConfig()
+    params = _latent_params(one)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        text = jax.jit(_encoder_fn(lm.encoder_program(cfg), 8192 + 8, 7168)
+                       ).lower(jax.ShapeDtypeStruct((3, 8192), jnp.int32,
+                                                    sharding=one),
+                               params).as_text()
+    assert _digest(_without_kernel_bodies(text)) == "0ce4dbb19d2cdfd4"
+
+    H, L = 64, 8192
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def kernel(q_nope, q_rope, k_nope, k_rope, v, seg):
+        return segment_flash_attention((q_nope, q_rope), (k_nope, k_rope), v,
+                                       seg, scale=0.13, interpret=False)
+
+    jaxpr = str(jax.make_jaxpr(kernel)(
+        shaped(1, H, L, 128), shaped(1, H, L, 64), shaped(1, H, L, 128),
+        shaped(1, 1, L, 64), shaped(1, H, L, 128),
+        shaped(1, L, dtype=jnp.int32)))
+    assert _digest(re.sub(r" at [^\s]+:\d+", "", jaxpr)) == "3ca78feab50cd58c"
