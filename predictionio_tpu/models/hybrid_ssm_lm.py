@@ -44,7 +44,14 @@ inside the stream:
   token behind a start; the convolution reads no tap from the history
   before. The boundary is wherever the segment id CHANGES (``runs``), so
   a left-padded or right-padded layout (the trainer's, ``pio eval``'s)
-  goes through the same function as the packed one;
+  goes through the same function as the packed one. Two forms, one
+  result: ``ssd_scan`` (XLA's: the trainer's, the CPU's, odd shapes)
+  and ``ssd_scan_kernel`` (one Pallas TPU kernel: a chunk's decay tiles
+  built in VMEM and fed to the MXU from there, the states carried
+  between chunks never leaving the chip; serving on the TPU at the
+  published widths); ``scan_kernel_for`` names the choice from the
+  backend, the shapes and whether a gradient is asked, and is the one
+  place it is made;
 - the weights are stacked by kind (``mamba`` [36, ...], ``attention``
   [4, ...], ``mlp`` [40, ...]) and every run of Mamba layers in the
   published order is ONE ``lax.scan`` (five at the published order: 5, 9,
@@ -91,8 +98,10 @@ __all__ = [
     "init_params",
     "param_count",
     "param_shapes",
+    "scan_kernel_for",
     "segment_runs",
     "ssd_scan",
+    "ssd_scan_kernel",
     "train_hybrid_ssm",
 ]
 
@@ -117,8 +126,11 @@ STEP_TOKEN_MIN = 1024
 #: layers together; history starts that fell INSIDE such a chunk (not on
 #: its first token: the cut is then made by the chunk's masks and not by
 #: dropping the carried state), layers together; the attention layers'
-#: unmasked (query, key) pairs.
-COUNTERS = ("ssmChunks", "ssmResetsInChunk", "pairsCausal")
+#: unmasked (query, key) pairs; the live chunks, layers together, whose
+#: scan ran as ``ssd_scan_kernel``: all of them where ``scan_kernel_for``
+#: names the kernel (serving on the TPU at aligned widths), none where
+#: ``ssd_scan`` ran (the CPU, training, odd shapes).
+COUNTERS = ("ssmChunks", "ssmResetsInChunk", "pairsCausal", "ssmKernelChunks")
 
 #: granite-4.0-h-micro's published order: attention at 5, 15, 25, 35.
 GRANITE_LAYER_TYPES = tuple(
@@ -323,11 +335,36 @@ def causal_conv(u, w, b, runs):
     return out
 
 
+def _chunk_decays(dt, A, runs):
+    """What a chunk's tokens carry of the decay, all float32, from dt
+    [n, Q, H], A [H] and runs [n, Q] (chunk by position): ``cs`` [n, Q,
+    H], the inclusive cumulative sum of dt * A inside the chunk; ``tail``
+    [n, Q, H], exp(cs_end - cs_j) for the tokens of the chunk's LAST run
+    (their part of the outgoing state), else 0; ``keep`` [n, H],
+    exp(cs_end) where the whole chunk is the run the incoming state came
+    from, else 0; ``reach`` [n, Q, H], exp(cs_i) for the tokens of that
+    run (what they read of the incoming state), else 0."""
+    import jax.numpy as jnp
+
+    cs = jnp.cumsum(dt * A, axis=1)
+    last_run = runs[:, -1]
+    tail = jnp.where((runs == last_run[:, None])[..., None],
+                     jnp.exp(cs[:, -1:, :] - cs), 0.0)
+    came_from = jnp.concatenate([jnp.full((1,), -2, runs.dtype),
+                                 last_run[:-1]])                # [n]
+    whole = (runs[:, 0] == came_from) & (last_run == came_from)
+    keep = jnp.where(whole[:, None], jnp.exp(cs[:, -1, :]), 0.0)
+    reach = jnp.where((runs == came_from[:, None])[..., None],
+                      jnp.exp(cs), 0.0)
+    return cs, tail, keep, reach
+
+
 def ssd_scan(x, dt, A, B, C, runs, chunk: int, cd):
     """The selective scan of the module's head, chunked: x [T, H, P], dt
     [T, H] (after the softplus), A [H] (negative), B, C [T, N], all
     float32; runs [T] int32 (``segment_runs``) -> y [T, H, P] float32
-    WITHOUT the skip term.
+    WITHOUT the skip term. XLA's form: the trainer's (it has a
+    gradient), the CPU's, and what ``ssd_scan_kernel`` is held to.
 
     With a = dt * A and cs its inclusive cumulative sum inside a chunk:
 
@@ -364,7 +401,7 @@ def ssd_scan(x, dt, A, B, C, runs, chunk: int, cd):
         return jnp.einsum(spec, a.astype(cd), b.astype(cd), precision=prec,
                           preferred_element_type=f32)
 
-    cs = jnp.cumsum(dt * A, axis=1)                             # [n, Q, H]
+    cs, tail, keep, reach = _chunk_decays(dt, A, runs)
     xdt = x * dt[..., None]                                     # [n, Q, H, P]
     # -- inside the chunks
     i, j = jnp.arange(Q)[:, None], jnp.arange(Q)[None, :]
@@ -374,14 +411,7 @@ def ssd_scan(x, dt, A, B, C, runs, chunk: int, cd):
         - cs.transpose(0, 2, 1)[:, :, None, :], -jnp.inf))      # [n, H, Q, Q]
     y = mm("chij,cjhp->cihp", mm("cin,cjn->cij", C, B)[:, None] * decay, xdt)
     # -- the chunks' own states, then the states carried between them
-    last_run = runs[:, -1]
-    tail = jnp.where((runs == last_run[:, None])[..., None],
-                     jnp.exp(cs[:, -1:, :] - cs), 0.0)          # [n, Q, H]
     own = mm("cjhp,cjn->chpn", xdt * tail[..., None], B)        # [n, H, P, N]
-    came_from = jnp.concatenate([jnp.full((1,), -2, runs.dtype),
-                                 last_run[:-1]])                # [n]
-    whole = (runs[:, 0] == came_from) & (last_run == came_from)
-    keep = jnp.where(whole[:, None], jnp.exp(cs[:, -1, :]), 0.0)  # [n, H]
 
     def carry(state, c):
         own_c, keep_c = c
@@ -389,17 +419,232 @@ def ssd_scan(x, dt, A, B, C, runs, chunk: int, cd):
 
     _end, incoming = jax.lax.scan(carry, jnp.zeros((H, P, N), f32),
                                   (own, keep))                  # [n, H, P, N]
-    reach = jnp.where((runs == came_from[:, None])[..., None],
-                      jnp.exp(cs), 0.0)                         # [n, Q, H]
     y = y + mm("cin,chpn->cihp", C, incoming) * reach[..., None]
     return y.reshape(n * Q, H, P)[:T]
+
+
+#: Most heads a grid step of ``ssd_scan_kernel`` (at the published head
+#: size of 64 a block of x^T and of y^T is [1024, 256] float32, 1 MB).
+#: Probed on the chip at the published widths, 8,192 tokens, the kernel
+#: alone (PERF.md section 5): 0.725 / 0.605 / 0.537 ms at 4 / 8 / 16; a
+#: grid step's fixed part (the chunk's pairs, the pipeline's hand-over) is
+#: some 250 of its 1,820 bundles at 8.
+SCAN_HEADS_BLOCK = 16
+
+#: Rows and columns of the blocks a chunk's decay tile is built in: a
+#: block with j > i throughout is all masked and is never built.
+_SCAN_SUB = 128
+
+#: What ``ssd_scan_kernel`` may hold in VMEM: the v5e's scoped limit of
+#: 16 MB a kernel, less a tenth for what the body keeps on its stack.
+_SCAN_VMEM_BUDGET = int(0.9 * 16 * 2 ** 20)
+
+
+def _scan_heads_block(H: int) -> int:
+    """Heads a grid step: the most, up to ``SCAN_HEADS_BLOCK``, that
+    divide H."""
+    return max(hb for hb in range(1, SCAN_HEADS_BLOCK + 1) if H % hb == 0)
+
+
+def _scan_vmem_bytes(Q: int, H: int, P: int, N: int) -> int:
+    """What ``ssd_scan_kernel`` holds in VMEM, counted for float32
+    operands (the larger): the blocks of x^T, y^T, B^T, C^T, the
+    per-token rows and columns (a column pads to 128 lanes) twice, for
+    the pipeline; once the chunk's pairs, B, the block's tails and EVERY
+    head's state (at the published widths 8.9 MB, 2 of them the states;
+    a chunk of 1,024 tokens, a state of 512 or a head of 256 would not
+    fit, and Mosaic refuses them)."""
+    hb = _scan_heads_block(H)
+    blocks = (2 * hb * P * Q + 2 * N * Q + 4 * max(hb, 8) * Q + 2 * Q * 128
+              + 8 * Q)
+    scratch = Q * Q + Q * N + hb * P * Q + H * P * N
+    return 4 * (2 * blocks + scratch)
+
+
+def scan_kernel_for(T: int, Q: int, H: int, P: int, N: int, *, backend: str,
+                    differentiable: bool) -> str:
+    """Which form of the selective scan ``forward_hidden`` runs for a
+    shape, by name, and the one place the choice is made: ``kernel``
+    (``ssd_scan_kernel``) on the TPU where the stream is whole chunks, a
+    chunk whole blocks of 128 tokens (the lanes), the state size a
+    multiple of 128 (B^T is transposed back in VMEM), B^T and C^T start
+    on a multiple of their height under x^T, a head is whole sublane
+    tiles of the compute type (16 rows), and the kernel's blocks with
+    every head's state fit its VMEM; ``xla`` (``ssd_scan``) everywhere
+    else: off the TPU, in training (the kernel has no gradient), at odd
+    or outsize shapes."""
+    aligned = (T % Q == 0 and Q % _SCAN_SUB == 0 and N % 128 == 0
+               and (H * P) % N == 0 and P % 16 == 0)
+    return ("kernel" if backend == "tpu" and not differentiable and aligned
+            and _scan_vmem_bytes(Q, H, P, N) <= _SCAN_VMEM_BUDGET else "xla")
+
+
+def _ssd_scan_body(keep_ref, skip_ref, x_ref, b_ref, c_ref, rows_ref,
+                   cs_col_ref, runs_col_ref, runs_row_ref, y_ref,
+                   cb_s, bt_s, tail_s, state_s, *, H, hb, P, Q, sub, cd, prec):
+    """One grid step (chunk c, head block h), TOKENS ON THE LANES: x_ref,
+    y_ref [hb P, Q]; b_ref, c_ref [N, Q]; rows_ref [4, 1, hb, Q] (cs, dt,
+    tail, reach, a row a head); cs_col_ref [1, Q, hb] (cs again, a column
+    a head); runs as a column and as a row; in SMEM keep_ref [chunks x
+    H] and skip_ref [H]. Scratch: ``cb_s`` [Q, Q] the chunk's (B_j . C_i),
+    zero where j and i do not meet, and ``bt_s`` [Q, N] B with the tokens
+    on the sublanes, both made at the chunk's first head block and read
+    by all; ``tail_s`` [hb P, Q] what the block's tokens add to the state;
+    ``state_s`` [H / hb, hb P, N] every head's state as the chunk before
+    left it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    c, h = pl.program_id(0), pl.program_id(1)
+
+    def dot(a, b):
+        return jnp.dot(a, b, precision=prec, preferred_element_type=f32)
+
+    @pl.when(c == 0)
+    def _no_state_before_the_stream():
+        state_s[h] = jnp.zeros(state_s.shape[1:], f32)
+
+    @pl.when(h == 0)
+    def _the_chunks_pairs():
+        bt_s[...] = b_ref[...].T.astype(cd)
+        j = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+        i = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+        meet = (j <= i) & (runs_col_ref[...] == runs_row_ref[...])
+        cb_s[...] = jnp.where(meet, dot(bt_s[...], c_ref[...].astype(cd)),
+                              0.0)
+
+    c_cd = c_ref[...].astype(cd)
+    # what every token of the run the state came from reads of it, the
+    # block's heads in one matmul; a head's `reach` multiplies it below
+    y_ref[...] = dot(state_s[h].astype(cd), c_cd)
+    for k in range(hb):
+        head = slice(k * P, (k + 1) * P)
+        dt, tail = rows_ref[1, 0, k:k + 1, :], rows_ref[2, 0, k:k + 1, :]
+        cs_col = cs_col_ref[0, :, k:k + 1]                      # [Q, 1]
+        xdt = x_ref[head, :] * dt                               # [P, Q]
+        xdt_cd = xdt.astype(cd)
+        tail_s[head, :] = (xdt * tail).astype(cd)
+        skip = skip_ref[h * hb + k]
+        for r in range(Q // sub):
+            at, upto = slice(r * sub, (r + 1) * sub), (r + 1) * sub
+            cs, reach = rows_ref[0, 0, k:k + 1, at], rows_ref[3, 0, k:k + 1, at]
+            # j <= i of one run: cs_i - cs_j <= 0; elsewhere `cb_s` is 0
+            # and the minimum keeps the exponential finite beside it
+            decay = jnp.exp(jnp.minimum(cs - cs_col[:upto], 0.0))
+            inside = dot(xdt_cd[:, :upto],
+                         (cb_s[:upto, at] * decay).astype(cd))  # [P, sub]
+            y_ref[head, at] = (inside + y_ref[head, at] * reach
+                               + x_ref[head, at] * skip)
+    own = dot(tail_s[...], bt_s[...])                           # [hb P, N]
+    for k in range(hb):
+        head = slice(k * P, (k + 1) * P)
+        state_s[h, head, :] = (state_s[h, head, :]
+                               * keep_ref[c * H + h * hb + k] + own[head])
+
+
+def ssd_scan_kernel(uT, dt, A, runs, *, d_state: int, chunk: int, cd, skip,
+                    heads_block: int | None = None,
+                    interpret: bool | None = None):
+    """``ssd_scan`` as ONE Pallas TPU kernel, TOKENS ON THE LANES: uT [H
+    P + 2 N, T] float32, the TRANSPOSE of the convolution's [x | B | C];
+    dt [T, H], A [H], runs [T], ``skip`` [H] (the mixer's D) -> y^T [H P,
+    T] float32 WITH the skip term, the transpose of ``ssd_scan(...) + x *
+    D``.
+
+    Why transposes: XLA lays the input projection's [T, 8,512] out
+    tokens-minor (8,512 is no multiple of 128 lanes, 8,192 is), the
+    convolution and the gate follow it, so u^T and y^T in row-major
+    order ARE those arrays as they lie and no copy stands before or
+    after the kernel (row-major [T, H P] cost one of 143 MB before and
+    one of 134 MB behind, a layer); every per-token factor is then a row
+    that broadcasts along sublanes, and every elementwise operation
+    fills its lanes at a head size of 64.
+
+    Grid (chunk, head block), both in order. x^T, B^T and C^T are block
+    views of u^T ([hb P, Q] and [N, Q] at row offsets that are multiples
+    of their heights) and y^T is written as [hb P, Q] blocks: no [chunk,
+    position, head, d_head] layout on either side. A chunk's (B_j .
+    C_i), masked to the pairs j <= i of one run, are made once in VMEM
+    and shared by the heads; a head's decay tile exp(cs_i - cs_j) is
+    built there in float32 from the DIFFERENCE of the cumulative sums,
+    block by block of 128 (the blocks with j > i not at all),
+    multiplied in, cast to ``cd`` and fed to the MXU; every head's state
+    stays in VMEM from chunk to chunk: a chunk first reads the incoming
+    state out (for the tokens of the run it came from), then replaces
+    it (decayed only where the whole chunk is that run) plus its own:
+    ``ssd_scan``'s three rules. What is one number a token a head (the
+    cumulative sums and the three factors of ``_chunk_decays``) stays
+    XLA's. Precision as ``ssd_scan``'s, cast for cast.
+
+    T must be whole chunks; ``scan_kernel_for`` names the shapes the
+    compiled kernel takes (the interpreter takes any). ``interpret``
+    defaults to "not on a TPU"."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    cd = jnp.dtype(cd)
+    (T, H), N, Q = dt.shape, int(d_state), int(chunk)
+    di = uT.shape[0] - 2 * N
+    P = di // H
+    hb = heads_block or _scan_heads_block(H)
+    if T % Q or di % N or H % hb:
+        raise ValueError(
+            f"ssd_scan_kernel takes whole chunks ({T} tokens, chunk {Q}), "
+            f"B^T and C^T a multiple of their height under x^T ({di} rows, "
+            f"d_state {N}) and whole head blocks ({H} heads, {hb} a block)")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    n, sub = T // Q, (_SCAN_SUB if Q % _SCAN_SUB == 0 else Q)
+    cs, tail, keep, reach = _chunk_decays(
+        dt.reshape(n, Q, H), A, runs.reshape(n, Q))
+    cs = cs.reshape(T, H)
+    rows = jnp.stack([cs, dt, tail.reshape(T, H), reach.reshape(T, H)]
+                     ).transpose(0, 2, 1).reshape(4, H // hb, hb, T)
+    in_specs = [
+        pl.BlockSpec((hb * P, Q), lambda c, h, *_: (h, c)),          # x^T
+        pl.BlockSpec((N, Q), lambda c, h, *_: (di // N, c)),         # B^T
+        pl.BlockSpec((N, Q), lambda c, h, *_: (di // N + 1, c)),     # C^T
+        pl.BlockSpec((4, 1, hb, Q), lambda c, h, *_: (0, h, 0, c)),
+        pl.BlockSpec((1, Q, hb), lambda c, h, *_: (h, c, 0)),        # cs
+        pl.BlockSpec((Q, 1), lambda c, h, *_: (c, 0)),               # runs
+        pl.BlockSpec((1, Q), lambda c, h, *_: (0, c)),
+    ]
+    return pl.pallas_call(
+        functools.partial(
+            _ssd_scan_body, H=H, hb=hb, P=P, Q=Q, sub=sub, cd=cd,
+            prec=jax.lax.Precision.HIGHEST if cd == f32 else None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((hb * P, Q), lambda c, h, *_: (h, c)),
+            grid=(n, H // hb),
+            scratch_shapes=[pltpu.VMEM((Q, Q), f32),
+                            pltpu.VMEM((Q, N), cd),
+                            pltpu.VMEM((hb * P, Q), cd),
+                            pltpu.VMEM((H // hb, hb * P, N), f32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((di, T), f32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="ssd_scan_kernel",
+    )(keep.reshape(n * H), skip.astype(f32), uT, uT, uT, rows,
+      cs.reshape(T, H // hb, hb).transpose(1, 0, 2), runs[:, None],
+      runs[None, :])
 
 
 # -- the forward --------------------------------------------------------------
 
 def forward_hidden(params: dict, cfg: HybridSSMConfig, tokens, seg, pos, *,
                    differentiable: bool = False):
-    """(states [T, D] float32 after the final norm, counters int32[3] in
+    """(states [T, D] float32 after the final norm, counters int32[4] in
     ``COUNTERS``' order) of one token stream. tokens, seg, pos: int32
     [T]; the events of one history share a segment id (1..; 0 is
     padding) and lie one after another; ``pos`` counts them from 0 and is
@@ -432,6 +677,9 @@ def forward_hidden(params: dict, cfg: HybridSSMConfig, tokens, seg, pos, *,
     kernel = "plain" if differentiable else attention_kernel_for(
         T, hd, hd, backend=jax.default_backend(), segmented=True,
         grouped=KV != H)
+    Q = min(cfg.mamba_chunk_size, T)
+    scan = scan_kernel_for(T, Q, Hm, P, N, backend=jax.default_backend(),
+                           differentiable=differentiable)
 
     def mm(x, w):
         return jnp.dot(x.astype(cd), w.astype(cd), precision=prec,
@@ -451,12 +699,19 @@ def forward_hidden(params: dict, cfg: HybridSSMConfig, tokens, seg, pos, *,
         with jax.named_scope("pio.seq.ssm_conv"):
             u = jax.nn.silu(causal_conv(zudt[:, di:di + cfg.conv_dim],
                                         w["conv_w"], w["conv_b"], runs))
+            if scan == "kernel":    # as XLA lays it: no data moves
+                u = u.T
         with jax.named_scope("pio.seq.ssm_scan"):
-            xs = u[:, :di].reshape(T, Hm, P)
-            y = ssd_scan(xs, jax.nn.softplus(zudt[:, -Hm:] + w["dt_bias"]),
-                         -jnp.exp(w["A_log"]), u[:, di:di + N], u[:, di + N:],
-                         runs, cfg.mamba_chunk_size, cd)
-            y = (y + xs * w["D"][:, None]).reshape(T, di)
+            dt = jax.nn.softplus(zudt[:, -Hm:] + w["dt_bias"])
+            A = -jnp.exp(w["A_log"])
+            if scan == "kernel":
+                y = ssd_scan_kernel(u, dt, A, runs, d_state=N, chunk=Q,
+                                    cd=cd, skip=w["D"]).T
+            else:
+                xs = u[:, :di].reshape(T, Hm, P)
+                y = ssd_scan(xs, dt, A, u[:, di:di + N], u[:, di + N:], runs,
+                             Q, cd)
+                y = (y + xs * w["D"][:, None]).reshape(T, di)
         with jax.named_scope("pio.seq.ssm_gate_out"):
             y = _rms(y * jax.nn.silu(zudt[:, :di]), w["norm"], eps)
             return x + res * mm(y, w["out_proj"])
@@ -528,7 +783,6 @@ def forward_hidden(params: dict, cfg: HybridSSMConfig, tokens, seg, pos, *,
     # the padding's own triangle is no history's: the mask let it
     # through, the count leaves it out
     pairs = pairs - cfg.count("attention") * (n_pad * (n_pad + 1) // 2)
-    Q = min(cfg.mamba_chunk_size, T)
     at = jnp.arange(T)
     starts = real & jnp.concatenate([jnp.ones(1, bool),
                                      runs[1:] != runs[:-1]])
@@ -537,13 +791,15 @@ def forward_hidden(params: dict, cfg: HybridSSMConfig, tokens, seg, pos, *,
     counters = jnp.stack([
         live_chunks * cfg.count("mamba"),
         jnp.sum(starts & (at % Q != 0)) * cfg.count("mamba"),
-        pairs]).astype(jnp.int32)
+        pairs,
+        live_chunks * (cfg.count("mamba") if scan == "kernel" else 0),
+    ]).astype(jnp.int32)
     return _rms(x, params["norm_f"], eps), counters
 
 
 def encoder_program(cfg: HybridSSMConfig):
     """stream int32 [3, t_pad] (tokens, segments, positions), params ->
-    (states [t_pad, D] float32, None, None, counters int32[3]): the
+    (states [t_pad, D] float32, None, None, counters int32[4]): the
     function a serving step's encoder executable is compiled from."""
 
     def fn(stream, params):

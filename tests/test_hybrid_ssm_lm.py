@@ -11,6 +11,7 @@ import dataclasses
 import importlib.util
 import json
 import shutil
+import subprocess
 import sys
 from pathlib import Path
 
@@ -220,7 +221,9 @@ def test_history_boundaries_inside_one_chunk():
         assert rel_err(got, want) < 2e-5
         at += len(h)
     need = ref.expected_counts([lengths], ref_cfg())
-    assert dict(zip(lm.COUNTERS, np.asarray(counters).tolist())) == need
+    # the fourth counter: no chunk goes through the kernel off the TPU
+    assert dict(zip(lm.COUNTERS, np.asarray(counters).tolist())) == {
+        **need, "ssmKernelChunks": 0}
     assert need["ssmChunks"] == 3 * -(-sum(lengths) // CHUNK)
     assert need["ssmResetsInChunk"] == 3 * 6    # all but the stream's first
 
@@ -297,6 +300,135 @@ def test_the_chunked_scan_against_the_recurrence_a_history(chunk):
         np.testing.assert_allclose(np.asarray(got[s]), np.asarray(want),
                                    rtol=2e-5, atol=2e-6)
         at += n
+
+
+def _segments(lengths, T):
+    return pack(draw_histories(lengths), T)[1]
+
+
+def _padded_rows(side):
+    """Four rows of 192 (a chunk and a half), flattened: the trainer's
+    layout, one row's pads between two histories."""
+    rows = np.zeros((4, 192), np.int32)
+    for r, n in enumerate([5, 192, 130, 1]):
+        if side == "left":
+            rows[r, 192 - n:] = 1
+        else:
+            rows[r, :n] = 1
+    return np.asarray(rows_to_stream(jnp.asarray(rows))[1])
+
+
+#: segment ids of a stream of three chunks of 256, or of one (two blocks
+#: of 128 a chunk, so that the block above the diagonal is skipped)
+SCAN_LAYOUTS = {
+    "a start inside a chunk": _segments([300, 200], 768),
+    "a start on a chunk's first token": _segments([256, 300], 768),
+    "several starts in one chunk": _segments(
+        [10, 50, 3, 1, 100, 60, 30, 2, 200], 768),
+    "one history over many chunks": _segments([768], 768),
+    "chunks of padding alone": _segments([100], 768),
+    "a stream of one chunk": _segments([100, 120], 256),
+    "padded on the left": _padded_rows("left"),
+    "padded on the right": _padded_rows("right"),
+}
+
+
+@pytest.mark.parametrize("dtype,limit", [("float32", 1e-5),
+                                         ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("layout", sorted(SCAN_LAYOUTS))
+def test_the_scan_kernel_is_the_chunked_scan_and_the_recurrence(
+        layout, dtype, limit):
+    """`ssd_scan_kernel` (interpreted here) at the published head size,
+    state size and chunk, two heads a block: against `ssd_scan` with the
+    skip term, to rounding in either dtype (the casts stand where
+    `ssd_scan`'s do, so bfloat16 inputs round alike), and against the
+    reference's recurrence over every history alone (`limit` of the
+    spread: float32 to rounding, bfloat16 matmul inputs to theirs)."""
+    seg = SCAN_LAYOUTS[layout]
+    T, H, P, N, Q = len(seg), 4, 64, 128, 256
+    rng = np.random.default_rng(len(layout))
+    x = jnp.asarray(rng.standard_normal((T, H, P)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.001, 0.1, (T, H)), jnp.float32)
+    A = -jnp.asarray(rng.uniform(1, 16, H), jnp.float32)
+    B, C = (jnp.asarray(rng.standard_normal((T, N)), jnp.float32)
+            for _ in range(2))
+    D = jnp.asarray(rng.standard_normal(H), jnp.float32)
+    runs = lm.segment_runs(jnp.asarray(seg))
+    cd = jnp.dtype(dtype)
+    got = np.asarray(lm.ssd_scan_kernel(
+        jnp.concatenate([x.reshape(T, H * P), B, C], axis=1).T, dt, A, runs,
+        d_state=N, chunk=Q, cd=cd, skip=D, heads_block=2, interpret=True)
+    ).T.reshape(T, H, P)
+    skip = np.asarray(x * D[:, None])
+    want = np.asarray(lm.ssd_scan(x, dt, A, B, C, runs, Q, cd)) + skip
+    spread = float(want.max() - want.min())
+    assert np.abs(got - want).max() < 1e-5 * spread
+    np_runs = np.asarray(runs)
+    for run in np.unique(np_runs[seg > 0]):
+        s = np.flatnonzero(np_runs == run)
+        s = slice(s[0], s[-1] + 1)
+        alone = np.asarray(ref.scan_recurrence(x[s], dt[s], A, B[s], C[s]))
+        assert np.abs(got[s] - alone - skip[s]).max() < limit * spread
+
+
+@pytest.mark.parametrize("T,Q,H,P,N,backend,differentiable,want", [
+    (8192, 256, 64, 64, 128, "tpu", False, "kernel"),   # the published
+    (1024, 256, 64, 64, 128, "tpu", False, "kernel"),   # widths, served
+    (256, 128, 2, 64, 128, "tpu", False, "kernel"),     # two heads a block
+    (8192, 256, 64, 64, 128, "cpu", False, "xla"),
+    (8192, 256, 64, 64, 128, "tpu", True, "xla"),       # no gradient
+    (8000, 256, 64, 64, 128, "tpu", False, "xla"),      # a part chunk
+    (8192, 64, 64, 64, 128, "tpu", False, "xla"),       # chunk under 128
+    (8192, 256, 64, 64, 16, "tpu", False, "xla"),       # a state under 128
+    (8192, 256, 3, 64, 128, "tpu", False, "xla"),       # B^T 192 rows down
+    (8192, 256, 64, 8, 128, "tpu", False, "xla"),       # a head under a tile
+    (8192, 1024, 64, 64, 128, "tpu", False, "xla"),     # blocks over VMEM
+    (8192, 256, 64, 64, 512, "tpu", False, "xla"),      # states over VMEM
+    (512, 256, 224, 64, 128, "tpu", False, "kernel"),   # 14 MB: the most
+    (128, 16, 8, 16, 8, "tpu", False, "xla"),           # these tests' widths
+])
+def test_the_scan_kernel_is_chosen_from_backend_shape_and_gradient(
+        T, Q, H, P, N, backend, differentiable, want):
+    assert lm.scan_kernel_for(T, Q, H, P, N, backend=backend,
+                              differentiable=differentiable) == want
+
+
+def test_the_forward_runs_the_scan_kernel_where_the_tpu_would(monkeypatch):
+    """With the backend said to be the TPU and widths the kernel takes
+    (heads of 64, a state of 128, chunks of 128) the forward runs every
+    Mamba layer's scan as `ssd_scan_kernel` (interpreted here, like the
+    attention kernel beside it) on the convolution's output as it lies:
+    the hidden states of the CPU path, its three counters, and the
+    fourth saying that every live chunk went through the kernel."""
+    cfg = dataclasses.replace(CFG, mamba_n_heads=2, mamba_d_head=64,
+                              mamba_d_state=128, mamba_chunk_size=128)
+    params = lm.init_params(cfg, N_ITEMS + 1, seed=25)
+    stream = pack(draw_histories([130, 3, 61, 40], seed=9), 384)
+    plain, c_plain = forward(params, cfg, stream)
+    tree = jax.tree_util.tree_map(jnp.asarray, params)
+    real_scan, real_attention = lm.ssd_scan_kernel, segment_flash_attention
+    taken = []
+
+    def interpreted_scan(uT, *a, **kw):
+        taken.append(uT.shape)
+        return real_scan(uT, *a, **kw, interpret=True)
+
+    from predictionio_tpu.parallel import ring_attention
+    monkeypatch.setattr(lm, "ssd_scan_kernel", interpreted_scan)
+    monkeypatch.setattr(
+        ring_attention, "segment_flash_attention",
+        lambda *a, **kw: real_attention(*a, **kw, interpret=True))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kernel, c_kernel = lm.forward_hidden(tree, cfg, *stream)
+    # on [x | B | C] as the convolution leaves it (its transpose: the
+    # tokens on the lanes), nothing sliced out
+    assert taken and set(taken) == {(cfg.conv_dim, 384)}
+    np.testing.assert_allclose(np.asarray(kernel[:234]),
+                               np.asarray(plain[:234]), rtol=1e-4, atol=1e-5)
+    counts = dict(zip(lm.COUNTERS, np.asarray(c_kernel).tolist()))
+    assert counts["ssmKernelChunks"] == counts["ssmChunks"] == 3 * 2
+    assert counts == {**dict(zip(lm.COUNTERS, np.asarray(c_plain).tolist())),
+                      "ssmKernelChunks": 6}
 
 
 def test_the_convolution_reads_no_tap_across_a_boundary():
@@ -650,6 +782,27 @@ def test_a_nameless_operation_takes_the_scope_of_what_reads_it(case,
     scopes.record("\n".join([copy] + readers))
     assert scopes.snapshot().get(
         "%copy.9 = f32[512,8,32,256]{3,2,1,0:T(8,128)} copy") == expected
+
+
+@pytest.mark.parametrize("engine", ["templates/recommendation/engine.py",
+                                    "benchmarks/engine/engine.py"])
+def test_the_als_engines_load_nothing_of_the_hybrid_decoder(engine):
+    """The ALS cells' processes (`pio train` / `pio deploy` of
+    benchmarks/engine, which is templates/recommendation behind a drawn
+    DataSource) never load models/hybrid_ssm_lm.py or the template that
+    imports it, so no change to the decoder can reach those cells."""
+    code = (
+        "import importlib.util, sys\n"
+        "import predictionio_tpu.tools.cli\n"
+        "import predictionio_tpu.workflow.create_server\n"
+        f"spec = importlib.util.spec_from_file_location('e', {str(REPO / engine)!r})\n"
+        "mod = importlib.util.module_from_spec(spec); sys.modules['e'] = mod\n"
+        "spec.loader.exec_module(mod)\n"
+        "print([m for m in sys.modules if 'hybrid_ssm' in m or 'seqrec' in m])\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO, text=True,
+                         capture_output=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "[]"
 
 
 def test_pio_train_then_deploy_of_hybrid_ssm_answers_through_the_batcher(

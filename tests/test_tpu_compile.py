@@ -731,25 +731,43 @@ def hybrid_step(v5e):
 @pytest.mark.parametrize("t_pad", [1024, 2048, 4096, 8192])
 def test_hybrid_serving_step_at_its_token_lattice(hybrid_step, t_pad):
     """Every lattice point of `HybridSSMEncoder`: one `while` a run of
-    Mamba layers in the published order (5, 9, 9, 9, 4: five) and one
-    more inside each body (the states carried between chunks), no
+    Mamba layers in the published order (5, 9, 9, 9, 4: five), no
     conditional, the FOUR attention kernels at the program's top level,
     6.4 GB of bfloat16 weights as arguments and no copy of a Mamba or MLP
     stack (each layer is sliced from its stack where it lies), a step's
-    query table and the three counters out, and temporaries that
-    leave the chip room."""
+    query table and the four counters out, and temporaries that
+    leave the chip room.
+
+    Re-pinned by PR 46, whose `ssd_scan_kernel` runs every Mamba layer's
+    scan: `tpu_custom_call` 4 -> 9 (one more a run's body, carrying the
+    scope `pio.seq.ssm_scan`); ` while(` 10 -> 5 (the chunk states'
+    carry is the kernel's VMEM scratch, no inner loop); no copy of the
+    convolution's [t_pad, 4352] or of a [t_pad, 4096] before or behind
+    the kernel, in either orientation (it reads and writes the
+    transposes, which is how XLA lays these arrays: a row-major kernel
+    got both copies, 277 MB a layer at 8,192 tokens); temporaries 0.88 ->
+    0.61 GB at 8,192 tokens (the decay matrices, the two relayouts and
+    the chunk states went), held under 0.6 GB a full step + 0.1."""
     from predictionio_tpu.obs.trace import DeviceScopes
 
     exe = hybrid_step(t_pad)
     hlo = exe.as_text()
-    assert hlo.count(" while(") == 10 and hlo.count(" conditional(") == 0
-    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 4
+    assert hlo.count(" while(") == 5 and hlo.count(" conditional(") == 0
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 9
     assert "segment_flash_attention" in hlo
+    kernels = [line for line in hlo.splitlines()
+               if " custom-call(" in line and "ssd_scan_kernel" in line]
+    assert len(kernels) == 5
+    assert all("pio.seq.ssm_scan/ssd_scan_kernel" in line
+               and f"= f32[4096,{t_pad}]" in line for line in kernels)
     assert not re.search(r"= bf16\[(36|40),\d+,\d+\][^ ]* copy\(", hlo)
+    assert not re.search(
+        rf"= f32\[({t_pad},(4096|4352)|(4096|4352),{t_pad})\][^ ]* "
+        r"(copy|transpose)\(", hlo)
     mem = exe.memory_analysis()
     assert 6.35e9 < mem.argument_size_in_bytes < 6.45e9
     assert mem.output_size_in_bytes >= (8192 + 8) * 2048 * 4
-    assert mem.temp_size_in_bytes < 1.2e9 * t_pad / 8192 + 0.1e9
+    assert mem.temp_size_in_bytes < 0.6e9 * t_pad / 8192 + 0.1e9
     scopes = DeviceScopes()
     assert scopes.record(hlo) > 100
     assert {"pio.seq.embed", "pio.seq.ssm_in_proj", "pio.seq.ssm_conv",
@@ -760,22 +778,56 @@ def test_hybrid_serving_step_at_its_token_lattice(hybrid_step, t_pad):
                    for k in scopes.snapshot())
     # The map CONTAINS the mixer: of what the operations in the bodies
     # of the five Mamba runs write, under 1% stands under no scope or
-    # under `pio.seq.layers` alone (where the convolution's fusion,
-    # named after the run's call by a convert the compiler hoists, and
-    # the two nameless relayouts of [t_pad, 4096] a layer before the
-    # scan's reshapes would read, a third of the mixer's device time),
-    # and what reads `pio.seq.ssm_run` alone is the convolution's fusion
-    # and crumbs of the cumulative sums.
+    # under `pio.seq.layers` alone. The convolution's fusion, whose root
+    # is now the transpose that hands it to the kernel (a bitcast: the
+    # fusion writes [4352, t_pad] as it wrote [t_pad, 4352] tokens-minor),
+    # reads `pio.seq.ssm_conv` beside the slice that feeds it; what
+    # reads `pio.seq.ssm_run` alone is crumbs of the cumulative sums;
+    # `pio.seq.ssm_scan` is the kernel's y^T and the per-token factors.
     written = _bytes_written_by_scope(hlo, scopes.snapshot())
-    assert sum(written.values()) > 2e9 * t_pad / 8192
+    assert sum(written.values()) > 1.3e9 * t_pad / 8192
     stray = written.get("", 0) + written.get("pio.seq.layers", 0)
     assert stray < 0.01 * sum(written.values()), written
     assert {"pio.seq.ssm_in_proj", "pio.seq.ssm_conv", "pio.seq.ssm_scan",
             "pio.seq.ssm_run", "pio.seq.ssm_gate_out", "pio.seq.mlp"
             } >= set(written) - {"", "pio.seq.layers"}
-    conv = t_pad * 4352 * (4 + 2)      # float32 for x, bfloat16 for B and C
-    assert conv <= written["pio.seq.ssm_run"] < 1.1 * conv
-    assert written["pio.seq.ssm_scan"] > 4 * t_pad * 4096 * 4
+    conv = 2 * t_pad * 4352 * 4         # the slice of zudt, then u^T
+    assert conv <= written["pio.seq.ssm_conv"] < 1.05 * conv
+    assert written["pio.seq.ssm_run"] < 0.1 * conv
+    y = t_pad * 4096 * 4
+    assert y <= written["pio.seq.ssm_scan"] < 1.3 * y
+
+
+@pytest.mark.parametrize("T,Q,H,P,N,dtype", [
+    (256, 128, 2, 64, 128, "bfloat16"),     # two heads, all in one block
+    (256, 128, 8, 16, 128, "bfloat16"),     # a head of one bfloat16 tile
+    (512, 256, 6, 64, 128, "bfloat16"),     # blocks of 6 heads
+    (512, 256, 224, 64, 128, "float32"),    # 14 MB of VMEM: the most
+    (768, 384, 64, 64, 128, "float32"),     # chunks of three blocks of 128
+])
+def test_scan_kernel_lowers_at_the_edges_of_what_the_choice_admits(
+        v5e, T, Q, H, P, N, dtype):
+    """`scan_kernel_for` promises the serving path a kernel that Mosaic
+    takes: the smallest head block and head, a head count that 16 does
+    not divide, and the largest VMEM need it admits, in the wider compute
+    type."""
+    from predictionio_tpu.models import hybrid_ssm_lm as lm
+
+    assert lm.scan_kernel_for(T, Q, H, P, N, backend="tpu",
+                              differentiable=False) == "kernel"
+    one = SingleDeviceSharding(v5e[0])
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def fn(uT, dt, A, runs, D):
+        return lm.ssd_scan_kernel(uT, dt, A, runs, d_state=N, chunk=Q,
+                                  cd=jnp.dtype(dtype), skip=D,
+                                  interpret=False)
+
+    exe = jax.jit(fn).lower(arg(H * P + 2 * N, T), arg(T, H), arg(H),
+                            arg(T, dtype=jnp.int32), arg(H)).compile()
+    assert exe.as_text().count("custom_call_target=\"tpu_custom_call\"") == 1
 
 
 _ARRAY = re.compile(r"(pred|s8|u8|bf16|f16|s32|u32|f32)\[([\d,]*)\]")
@@ -785,9 +837,9 @@ _WIDTH = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
 
 def _bytes_written_by_scope(hlo: str, scope_of: dict) -> dict:
     """{scope: bytes of the results} over the operations of ONE body of a
-    run of Mamba layers (a `while` body that holds the chunk states'
-    `while`), by the capture's operation-to-scope map; what moves no
-    data of its own is left out."""
+    run of Mamba layers (a `while` body that holds the scan kernel's
+    call), by the capture's operation-to-scope map; what moves no data
+    of its own is left out."""
     from predictionio_tpu.obs.trace import operation_key
 
     bodies, at = {}, None
@@ -800,12 +852,14 @@ def _bytes_written_by_scope(hlo: str, scope_of: dict) -> dict:
         elif at is not None:
             at.append(line)
     runs = [bodies[b] for b in re.findall(r" while\(.*?body=(%[\w.\-]+)", hlo)
-            if any(" while(" in line for line in bodies[b])]
+            if any("ssd_scan_kernel" in line for line in bodies[b])]
     assert len(runs) == 5
     out: dict = {}
     for line in runs[0]:
         m = re.match(r"^\s*(?:ROOT )?%[\w.\-]+ = (.+?) ([\w\-]+)\(", line)
-        if m is None or m.group(2) in (
+        # an asynchronous pair's result is its `-done`; the `-start`
+        # carries the operand along in its tuple
+        if m is None or m.group(2).endswith("-start") or m.group(2) in (
                 "parameter", "get-tuple-element", "tuple", "constant",
                 "bitcast", "while"):
             continue
